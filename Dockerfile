@@ -35,9 +35,13 @@ COPY --from=build /usr/local/lib/python3.11/site-packages /usr/local/lib/python3
 COPY --from=build /app/native ./native
 COPY config ./config
 
+# The compile cache is placed from outside (utils.enable_compile_cache
+# honours JAX_COMPILATION_CACHE_DIR and names no other directory); mount
+# a volume here to keep compiled kernels across container restarts.
 ENV FLUID_HOST=0.0.0.0 \
     FLUID_PORT=7070 \
-    FLUID_NATIVE_DIR=/app/native
+    FLUID_NATIVE_DIR=/app/native \
+    JAX_COMPILATION_CACHE_DIR=/var/cache/fluid-jax
 
 EXPOSE 7070
 

@@ -1,12 +1,14 @@
 """Pallas TPU compaction kernel — the zamboni equivalent, scatter-free.
 
-The XLA :func:`merge_kernel.compact` costs ~150ms at service scale because
-its squeeze is a general scatter, which TPUs execute serially. This kernel
-reformulates compaction as a *permutation matmul on the MXU*: the squeeze
-``out[t] = lane[j]`` (``t = dest[j]``) is ``P @ lane`` with the 0/1 matrix
-``P[t, j] = keep[j] & (dest[j] == t)`` — each row of ``P`` has at most one
-1, so there is no accumulation, and int32 lanes transported as two exact
-15-bit halves (both < 2^24, exact in f32) reassemble losslessly.
+The XLA :func:`merge_kernel.compact` squeezes with a general scatter,
+which TPUs execute serially. This kernel squeezes with the apply kernel's
+own primitives instead: the row ``j`` that belongs at column ``dest[j]``
+walks left by ``j - dest[j]`` in log2(capacity) static shift-and-select
+steps (:func:`_squeeze`). No gather, no scatter, no matmul, nothing of
+size capacity^2 — so it runs at every capacity tier with the apply
+kernel's block rule, and the v5e compiler takes it in about a second at
+the base tiers (the unrolled steps make compile time grow with capacity;
+see ``fleet._PALLAS_COMPACT_MAX_CAP``).
 
 Semantics are identical to the XLA compact (pinned by parity tests):
 
@@ -25,22 +27,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# compact kernels trace on CI images as well as the TPU driver image.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 from fluidframework_tpu.ops.pallas_kernel import (
     N_LANES,
     N_SCALARS,
+    OP_WIDTH,
     SC_COUNT,
     SC_MIN_SEQ,
+    _apply_values,
     _excl_cumsum,
     _on_tpu,
+    _shift_left,
     _shift_right,
+    block_params,
+    doc_block,
     pack_state,
     unpack_state,
 )
@@ -53,7 +53,6 @@ from fluidframework_tpu.protocol.constants import (
 )
 
 _I32 = jnp.int32
-_F32 = jnp.float32
 
 L_KIND = SEGMENT_LANES.index("kind")
 L_ORIG = SEGMENT_LANES.index("orig")
@@ -71,28 +70,28 @@ L_AVAL = SEGMENT_LANES.index("aval")
 _FILLS = {L_KIND: KIND_FREE, L_RSEQ: RSEQ_NONE}
 
 
-def _permute(dest, do, x, b, s):
-    """out[d, t, :] = x[d, j, :] where dest[d, j] == t and do[d, j].
-
-    ``x``: [B, S, C] int32. Batched MXU matmul; zeros in unwritten rows.
-    """
-    row_t = jax.lax.broadcasted_iota(_I32, (b, s, s), 1)
-    p = ((dest[:, None, :] == row_t) & do[:, None, :]).astype(_F32)
-    hi = (x >> 15).astype(_F32)
-    lo = (x & 0x7FFF).astype(_F32)
-    both = jnp.concatenate([hi, lo], axis=2)  # [B, S, 2C]
-    # HIGHEST precision is load-bearing: the default TPU f32 matmul runs on
-    # the MXU as bf16 passes, which rounds 15-bit halves and silently
-    # corrupts reassembled int32 lanes.
-    out = jax.lax.dot_general(
-        p,
-        both,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=_F32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    c = x.shape[2]
-    return out[:, :, :c].astype(_I32) * 32768 + out[:, :, c:].astype(_I32)
+def _squeeze(keep, dest, lanes):
+    """Order-preserving squeeze: row j with ``keep[j]`` lands at column
+    ``dest[j]`` (its exclusive keep-count), every lane alike. Each kept
+    row moves left by ``j - dest[j]``, one binary digit of that distance
+    per step, lowest first: two kept rows i < j differ in distance by at
+    most j - i - 1, so no step ever lands two rows on one column, and a
+    step is a static shift and a select per lane — the primitives of the
+    apply kernel, at any capacity. Columns at and past the keep-count
+    hold leftovers; callers mask them."""
+    s = keep.shape[1]
+    col = jax.lax.broadcasted_iota(_I32, keep.shape, 1)
+    dist = jnp.where(keep, col - dest, 0)
+    valid = keep
+    d = 1
+    while d < s:
+        move = valid & ((dist & d) != 0)
+        arrive = _shift_left(move.astype(_I32), d) != 0
+        lanes = [jnp.where(arrive, _shift_left(x, d), x) for x in lanes]
+        dist = jnp.where(arrive, _shift_left(dist, d), dist)
+        valid = arrive | (valid & ~move)
+        d *= 2
+    return lanes
 
 
 def compact_values(lanes, min_seq):
@@ -115,10 +114,10 @@ def compact_values(lanes, min_seq):
     dest = _excl_cumsum(keep.astype(_I32))
     n = jnp.sum(keep.astype(_I32), axis=1, keepdims=True)
 
-    sq = _permute(dest, keep, jnp.stack(lanes, axis=2), b, s)
+    sq = _squeeze(keep, dest, lanes)
     valid = col < n
     sq_lanes = [
-        jnp.where(valid, sq[:, :, i], _FILLS.get(i, 0)) for i in range(N_LANES)
+        jnp.where(valid, sq[i], _FILLS.get(i, 0)) for i in range(N_LANES)
     ]
 
     # -- sibling re-merge (packParent subset) --------------------------------
@@ -150,13 +149,13 @@ def compact_values(lanes, min_seq):
     total = jnp.sum(vlen, axis=1, keepdims=True)
     plen = _excl_cumsum(vlen)
 
-    hq = _permute(dest_h, head, jnp.stack(sq_lanes + [plen], axis=2), b, s)
+    hq = _squeeze(head, dest_h, sq_lanes + [plen])
     valid_h = col < n_heads
     out_lanes = [
-        jnp.where(valid_h, hq[:, :, i], _FILLS.get(i, 0)) for i in range(N_LANES)
+        jnp.where(valid_h, hq[i], _FILLS.get(i, 0)) for i in range(N_LANES)
     ]
     # Merged length of head t = (next head's prefix length, or total) - own.
-    pl_sq = jnp.where(valid_h, hq[:, :, N_LANES], 0)
+    pl_sq = jnp.where(valid_h, hq[N_LANES], 0)
     pl_next = jnp.concatenate([pl_sq[:, 1:], jnp.zeros((b, 1), _I32)], axis=1)
     nxt = jnp.where(col + 1 < n_heads, pl_next, total)
     out_lanes[L_LEN] = jnp.where(valid_h, nxt - pl_sq, 0)
@@ -179,10 +178,7 @@ def _kernel(tables_ref, scalars_ref, otables_ref, oscalars_ref):
 )
 def compact_packed(tables, scalars, *, block_docs=8, interpret=False):
     n_docs, cap = tables.shape[1], tables.shape[2]
-    # The permutation matrix is [blk, cap, cap] f32 — bound its VMEM share.
-    blk = min(block_docs, n_docs, max(1, (4 << 20) // (cap * cap * 4)))
-    while n_docs % blk != 0:
-        blk -= 1
+    blk = doc_block(block_docs, n_docs, cap)
     out = pl.pallas_call(
         _kernel,
         grid=(n_docs // blk,),
@@ -199,11 +195,7 @@ def compact_packed(tables, scalars, *, block_docs=8, interpret=False):
             jax.ShapeDtypeStruct(scalars.shape, _I32),
         ],
         input_output_aliases={0: 0, 1: 1},
-        # 14 lanes of permutation transport sit marginally past Mosaic's
-        # default 16MB scoped stack at cap 256 — grant headroom.
-        compiler_params=_CompilerParams(
-            vmem_limit_bytes=64 * 1024 * 1024
-        ),
+        compiler_params=block_params(blk, cap),
         interpret=interpret,
     )(tables, scalars)
     return out[0], out[1]
@@ -226,8 +218,6 @@ def _fused_kernel(ops_ref, tables_ref, scalars_ref, otables_ref, oscalars_ref):
     """Apply the op batch AND compact in ONE Pallas dispatch (VERDICT r1
     #10: the service step previously cost two device calls; fusing halves
     dispatches and keeps the intermediate table in VMEM)."""
-    from fluidframework_tpu.ops.pallas_kernel import _apply_values
-
     lanes, count, min_seq, cur_seq, self_client, err = _apply_values(
         ops_ref, tables_ref, scalars_ref
     )
@@ -248,27 +238,9 @@ def apply_compact_packed(tables, scalars, ops, *, block_docs=8, interpret=False)
     """Fused service step: ops [D, K, OP_WIDTH] applied and the tables
     compacted, one dispatch. Bit-identical to apply_ops_packed followed by
     compact_packed (parity-tested)."""
-    from fluidframework_tpu.ops.pallas_kernel import OP_WIDTH
-
     n_docs, cap = tables.shape[1], tables.shape[2]
     k = ops.shape[1]
-    # Tighter VMEM budget than standalone compact: the fused body holds the
-    # apply loop's live lanes AND the permutation matmuls on one scoped
-    # stack (16MB limit; [blk,cap,cap] f32 x the hi/lo transport).
-    # Pallas TPU blockspecs need the doc-block dim to be a multiple of 8
-    # (sublanes) or the whole dim; pick the largest multiple-of-8 divisor
-    # within the VMEM budget, else fall back to one block.
-    cand = min(block_docs, n_docs, max(8, (8 << 20) // (cap * cap * 4)))
-    blk = max(
-        (b for b in range(8, cand + 1, 8) if n_docs % b == 0),
-        default=n_docs,
-    )
-    if blk == n_docs and blk * cap * cap * 4 > (64 << 20):
-        raise ValueError(
-            f"no multiple-of-8 block divides n_docs={n_docs}; the single-"
-            f"block fallback would need {blk * cap * cap * 4 >> 20}MB VMEM "
-            "— pad the doc dimension to a multiple of 8"
-        )
+    blk = doc_block(block_docs, n_docs, cap)
     ops_t = jnp.transpose(ops.astype(_I32), (1, 0, 2))  # [K, D, W]
     out = pl.pallas_call(
         _fused_kernel,
@@ -287,12 +259,7 @@ def apply_compact_packed(tables, scalars, ops, *, block_docs=8, interpret=False)
             jax.ShapeDtypeStruct(scalars.shape, _I32),
         ],
         input_output_aliases={1: 0, 2: 1},
-        # The fused body carries the apply loop's lanes plus both
-        # permutation matmuls on one scoped stack — far past Mosaic's
-        # default 16MB; grant most of the chip's VMEM.
-        compiler_params=_CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024
-        ),
+        compiler_params=block_params(blk, cap),
         interpret=interpret,
     )(ops_t, tables, scalars)
     return out[0], out[1]

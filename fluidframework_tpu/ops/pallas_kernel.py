@@ -36,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from fluidframework_tpu.ops.segment_state import (
     SEGMENT_LANES,
@@ -84,6 +85,12 @@ def _shift_right(x: jnp.ndarray, d: int) -> jnp.ndarray:
     """Shift columns right by static d along the last axis, zero-fill."""
     b, s = x.shape
     return jnp.concatenate([jnp.zeros((b, d), x.dtype), x[:, : s - d]], axis=1)
+
+
+def _shift_left(x: jnp.ndarray, d: int) -> jnp.ndarray:
+    """Shift columns left by static d along the last axis, zero-fill."""
+    b, s = x.shape
+    return jnp.concatenate([x[:, d:], jnp.zeros((b, d), x.dtype)], axis=1)
 
 
 def _excl_cumsum(x: jnp.ndarray) -> jnp.ndarray:
@@ -369,7 +376,42 @@ def unpack_state(tables, scalars) -> SegmentState:
 
 
 def _on_tpu() -> bool:
-    return jax.default_backend() not in ("cpu", "gpu")
+    return jax.default_backend() == "tpu"
+
+
+# What the v5e compiler charges a kernel of this family: its scoped VMEM
+# stack (in/out blocks double-buffered, the loop-carried lanes, the body's
+# temporaries) came to 346-411 bytes per (doc, row) cell of the block.
+# A block of 2^15 cells sits inside Mosaic's default 16 MB; bigger tiers
+# cannot go under 8 docs (the sublane tile), so they ask for more VMEM,
+# up to what the chip's 128 MiB leaves the compiler. Eight docs of 65,536
+# rows need 156 MB and do not fit: the fleet's top tier is 32,768.
+_BLOCK_CELLS = 1 << 15
+_VMEM_BYTES_PER_CELL = 448
+_VMEM_CEILING = 120 << 20
+
+
+def doc_block(block_docs: int, n_docs: int, cap: int) -> int:
+    """Docs per grid step for a ``[N_LANES, n_docs, cap]`` table: the
+    largest multiple of 8 (Mosaic's sublane tile) that divides ``n_docs``,
+    is at most ``block_docs`` and keeps ``blk * cap`` within the default
+    VMEM budget — but never under 8. A doc dim no such block divides is
+    one whole-dim block (the other shape Mosaic accepts)."""
+    top = max(8, min(block_docs, _BLOCK_CELLS // cap))
+    return max(
+        (b for b in range(8, min(top, n_docs) + 1, 8) if n_docs % b == 0),
+        default=n_docs,
+    )
+
+
+def block_params(blk: int, cap: int) -> pltpu.CompilerParams:
+    """The scoped-VMEM grant that goes with :func:`doc_block`'s choice."""
+    cells = max(blk, 8) * cap
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(
+            _VMEM_CEILING, max(32 << 20, cells * _VMEM_BYTES_PER_CELL)
+        )
+    )
 
 
 @functools.partial(
@@ -378,12 +420,12 @@ def _on_tpu() -> bool:
     donate_argnums=(0, 1),
 )
 def apply_ops_packed(tables, scalars, ops, *, block_docs=64, interpret=False):
-    """Apply ops [D, K, OP_WIDTH] to a packed state; D % block_docs == 0."""
+    """Apply ops [D, K, OP_WIDTH] to a packed state. ``block_docs`` is an
+    upper bound: the block that runs is :func:`doc_block`'s."""
     n_docs = tables.shape[1]
     cap = tables.shape[2]
     k = ops.shape[1]
-    blk = min(block_docs, n_docs)
-    assert n_docs % blk == 0, "pad n_docs to a multiple of block_docs"
+    blk = doc_block(block_docs, n_docs, cap)
     ops_t = jnp.transpose(ops.astype(_I32), (1, 0, 2))  # [K, D, W]
     grid = (n_docs // blk,)
     out = pl.pallas_call(
@@ -403,6 +445,7 @@ def apply_ops_packed(tables, scalars, ops, *, block_docs=64, interpret=False):
             jax.ShapeDtypeStruct(scalars.shape, _I32),
         ],
         input_output_aliases={1: 0, 2: 1},
+        compiler_params=block_params(blk, cap),
         interpret=interpret,
     )(ops_t, tables, scalars)
     return out[0], out[1]
@@ -416,12 +459,8 @@ def pallas_batched_apply_ops(
     mode off-TPU (CPU tests)."""
     if interpret is None:
         interpret = not _on_tpu()
-    n_docs = state.kind.shape[0]
-    blk = block_docs
-    while n_docs % blk != 0:
-        blk //= 2
     tables, scalars = pack_state(state)
     tables, scalars = apply_ops_packed(
-        tables, scalars, ops, block_docs=blk, interpret=interpret
+        tables, scalars, ops, block_docs=block_docs, interpret=interpret
     )
     return unpack_state(tables, scalars)

@@ -83,8 +83,8 @@ def interactive_device():
 
     A single client editing one document applies one small op at a time —
     latency-bound, not throughput-bound — so the XLA:CPU backend is the
-    right executor (an accelerator round-trip per keystroke, possibly over
-    a network tunnel, costs orders of magnitude more than the op). The
+    right executor (an accelerator round-trip per keystroke costs more
+    than the op). The
     service-scale paths (``make_batched_state`` + ``batched_apply_ops``,
     ``parallel.mesh.DocShard``) keep the default device: there the work is
     thousands of documents per dispatch and belongs on the TPU mesh.

@@ -28,25 +28,14 @@ from fluidframework_tpu.parallel import aot
 from fluidframework_tpu.protocol.constants import NO_CLIENT
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the top-level export (with
-    ``check_vma`` — pallas_call outputs carry no vma info) where present,
-    else the experimental module (whose flag is ``check_rep``)."""
-    try:
-        from jax import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def make_mesh(n_devices: Optional[int] = None, axis: str = "docs") -> Mesh:
     devs = jax.devices()
     if n_devices is not None:
+        if len(devs) < n_devices:
+            raise ValueError(
+                f"make_mesh({n_devices}): only {len(devs)} "
+                f"{devs[0].platform} device(s) present"
+            )
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis,))
 
@@ -88,10 +77,10 @@ _jit_apply_and_stats = jax.jit(apply_and_stats, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
-def _mesh_pallas_step(mesh: Mesh, axis: str, blk: int, interpret: bool):
+def _mesh_pallas_step(mesh: Mesh, axis: str, interpret: bool):
     """The Pallas apply + telemetry reduction under shard_map, cached per
-    (mesh, axis, block, interpret) so every DocShard of one deployment
-    shape shares one compiled executable (the fleet.py builder pattern)."""
+    (mesh, axis, interpret) so every DocShard of one deployment shape
+    shares one compiled executable (the fleet.py builder pattern)."""
     from fluidframework_tpu.ops.pallas_kernel import (
         SC_COUNT,
         SC_CUR_SEQ,
@@ -102,7 +91,7 @@ def _mesh_pallas_step(mesh: Mesh, axis: str, blk: int, interpret: bool):
 
     def per_shard(tables, scalars, ops):
         tables, scalars = apply_ops_packed(
-            tables, scalars, ops, block_docs=blk, interpret=interpret
+            tables, scalars, ops, block_docs=32, interpret=interpret
         )
         stats = {
             "rows_in_use": jax.lax.psum(
@@ -121,12 +110,13 @@ def _mesh_pallas_step(mesh: Mesh, axis: str, blk: int, interpret: bool):
         return tables, scalars, stats
 
     return jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(P(None, axis, None), P(axis, None),
                       P(axis, None, None)),
             out_specs=(P(None, axis, None), P(axis, None), P()),
+            check_vma=False,  # pallas_call outputs carry no vma info
         ),
         donate_argnums=(0, 1),
     )
@@ -140,11 +130,12 @@ def _mesh_pallas_compact(mesh: Mesh, axis: str, interpret: bool):
         return compact_packed(tables, scalars, interpret=interpret)
 
     return jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(P(None, axis, None), P(axis, None)),
             out_specs=(P(None, axis, None), P(axis, None)),
+            check_vma=False,
         ),
         donate_argnums=(0, 1),
     )
@@ -233,11 +224,8 @@ class DocShard:
             ss = NamedSharding(self.mesh, P(axis, None))
             self._tables = jax.device_put(tables, ts)
             self._scalars = jax.device_put(scalars, ss)
-            blk = min(32, self._docs_per_dev)
-            while self._docs_per_dev % blk != 0:
-                blk //= 2
             self._pallas_step = _mesh_pallas_step(
-                self.mesh, axis, blk, self._interpret
+                self.mesh, axis, self._interpret
             )
             self._pallas_compact = _mesh_pallas_compact(
                 self.mesh, axis, self._interpret

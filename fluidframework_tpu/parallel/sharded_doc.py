@@ -175,8 +175,6 @@ def sharded_apply_ops(state: SegmentState, ops: jnp.ndarray, axis: str,
 # programs for every promoted document.
 @functools.lru_cache(maxsize=None)
 def _sharded_fns(mesh: Mesh, axis: str):
-    from fluidframework_tpu.parallel.mesh import compat_shard_map
-
     n = mesh.devices.size
     n_lanes = len(SegmentState._fields)
     state_spec = SegmentState(*([P(axis)] * n_lanes))
@@ -196,16 +194,16 @@ def _sharded_fns(mesh: Mesh, axis: str):
         return SegmentState(*[x[None] for x in out])
 
     step_fn = jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=(state_spec, P()),
-            out_specs=state_spec,
+            out_specs=state_spec, check_vma=False,
         ),
         donate_argnums=(0,),
     )
     compact_fn = jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             compact_shard, mesh=mesh, in_specs=(state_spec,),
-            out_specs=state_spec,
+            out_specs=state_spec, check_vma=False,
         ),
         donate_argnums=(0,),
     )

@@ -136,7 +136,7 @@ class PipelineFluidService:
         messages_per_trace: int = 0,
         device_backend: bool = True,
         device_capacity: int = 128,
-        device_max_capacity: int = 1 << 16,
+        device_max_capacity: int = 1 << 15,
         device_sharded_overflow: bool = False,
         device_max_batch: int = 512,
         device_flush_min_rows: int = 1,

@@ -69,6 +69,18 @@ def _load_lib(so_name: str) -> Optional[ctypes.CDLL]:
     return _libs[so_name]
 
 
+def native_status() -> dict:
+    """so name -> loaded? for every native library (building from the
+    committed ``native/*.cpp`` on demand) — the serving entry points
+    print this, so a failed ``make`` is seen, not swallowed."""
+    return {
+        so: _load_lib(so) is not None
+        for so in (
+            "libticket.so", "libplog.so", "libcastore.so", "libcoord.so"
+        )
+    }
+
+
 _castore_registered = False
 
 

@@ -142,19 +142,39 @@ def test_size_trigger_fires_mid_stream():
     assert len(be.text("d0", "s")) == 2 * k
 
 
-def test_deadline_trigger_fires_without_further_traffic():
+class _SteppedClock:
+    """Stands in for the ``time`` module inside device_backend: the feed
+    edge and the deadline check read ``perf_counter`` from here, so the
+    test — not the machine's load — decides how much time has passed."""
+
+    def __init__(self):
+        self.now = time.perf_counter()
+
+    def perf_counter(self) -> float:
+        return self.now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_deadline_trigger_fires_without_further_traffic(monkeypatch):
     """Sub-threshold rows dispatch once feed_deadline_ms elapses even if
     no further row ever arrives — the trigger needs no future traffic,
     only a tick (the network server's ticker supplies those)."""
+    from fluidframework_tpu.service import device_backend
+
     n_ch, k = 2, 4
     be = DeviceFleetBackend(
         capacity=64, pump_mode=True, feed_deadline_ms=20.0
     )
+    clock = _SteppedClock()
+    monkeypatch.setattr(device_backend, "time", clock)
     _feed(be, n_ch, k, 0)
+    clock.now += 0.019
     assert be.pump_feed() == []
     assert be.ops_applied == 0, "deadline not expired: rows must wait"
     assert be.needs_flush()
-    time.sleep(0.025)
+    clock.now += 0.002
     be.pump_feed()  # the next tick after the deadline stages + dispatches
     assert be.ops_applied == n_ch * k
     assert be.feed_triggers == {"size": 0, "deadline": 1}

@@ -773,6 +773,9 @@ class TestWriterLoopOffload:
             # The drainer actually wrote: its thread set is non-empty
             # and disjoint from the socket loop's thread.
             dr = srv._push_drainer
+            # The bytes arrive before the drainer counts the batch: wait
+            # for its queue to drain, not for luck under a loaded run.
+            assert dr.join(5.0)
             assert dr.batches >= 1
             assert dr.threads, "no write ran on the drainer"
             assert srv._thread.ident not in dr.threads
