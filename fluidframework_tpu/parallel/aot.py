@@ -24,9 +24,12 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, Dict, Tuple
 
+from fluidframework_tpu.telemetry import profiler
+
 _ENTRIES: Dict[Tuple, Any] = {}
 _LOCK = threading.Lock()
 _BUILDS = 0
+_BUILD_S = 0.0
 _CALLS = 0
 
 
@@ -41,34 +44,41 @@ def call(key: Tuple, build: Callable[[], Any], *args, **static_kwargs):
     jitted callable carries through to the executable, so the hot call
     updates buffers in place with no defensive copy.
     """
-    global _BUILDS, _CALLS
+    global _BUILDS, _BUILD_S, _CALLS
     exe = _ENTRIES.get(key)
     if exe is None:
         with _LOCK:
             exe = _ENTRIES.get(key)
             if exe is None:
-                # graftlint: recompile(built ONCE per shape-bucket key — the dict probe above IS the cache; a steady-state flush never reaches this branch, and the entry-count/build counters are test-pinned)
-                exe = _ENTRIES[key] = (
-                    build().lower(*args, **static_kwargs).compile()
-                )
+                with profiler.span("aot_build") as built:
+                    # graftlint: recompile(built ONCE per shape-bucket key — the dict probe above IS the cache; a steady-state flush never reaches this branch, and the entry-count/build counters are test-pinned)
+                    exe = _ENTRIES[key] = (
+                        build().lower(*args, **static_kwargs).compile()
+                    )
                 _BUILDS += 1
+                _BUILD_S += built.t1 - built.t0
     _CALLS += 1
     return exe(*args)
 
 
-def stats() -> Dict[str, int]:
+def stats() -> Dict[str, float]:
     """Monotone counters: ``entries`` (live cache size), ``builds``
-    (executables compiled — one per shape bucket ever seen), ``calls``
-    (dispatches served). The zero-per-flush-tracing contract is
-    ``builds`` flat while ``calls`` grows."""
-    return {"entries": len(_ENTRIES), "builds": _BUILDS, "calls": _CALLS}
+    (executables compiled — one per shape bucket ever seen), ``build_s``
+    (seconds those lowerings and compiles took, the ``aot_build`` lane's
+    own floats), ``calls`` (dispatches served). The zero-per-flush-
+    tracing contract is ``builds`` flat while ``calls`` grows."""
+    return {
+        "entries": len(_ENTRIES), "builds": _BUILDS, "build_s": _BUILD_S,
+        "calls": _CALLS,
+    }
 
 
 def clear() -> None:
     """Drop every entry (test isolation; production never calls this —
     entries are valid for the life of the process)."""
-    global _BUILDS, _CALLS
+    global _BUILDS, _BUILD_S, _CALLS
     with _LOCK:
         _ENTRIES.clear()
         _BUILDS = 0
+        _BUILD_S = 0.0
         _CALLS = 0

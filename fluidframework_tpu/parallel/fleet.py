@@ -56,10 +56,25 @@ from fluidframework_tpu.utils import pow2_at_least as _pow2_at_least
 
 _SCALARS = ("count", "min_seq", "cur_seq", "self_client", "err")
 
+
+def _program(name: str, fn):
+    """``fn`` under the name its program carries on a device trace
+    (``jit_<name>`` on the module line), whichever engine it wraps: the
+    benchmark's trace reduction finds the serving programs by these."""
+
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 # One jitted step shared by every pool: jax caches compilations per shape,
 # so pools of equal (D, S) reuse each other's executables across fleets.
 _jit_step = jax.jit(batched_apply_ops, donate_argnums=(0,))
-_jit_compact = jax.jit(batched_compact, donate_argnums=(0,))
+_jit_compact = jax.jit(
+    _program("fluid_compact", batched_compact), donate_argnums=(0,)
+)
 
 # Upper bound on the Pallas doc block; the kernels derive the block that
 # runs from each pool's (slots, capacity) — pallas_kernel.doc_block.
@@ -133,18 +148,20 @@ def _fused_sparse_step(n_slots: int, kernel: str, sharding):
     else:
         engine = _pallas_apply
 
-    def fused(state, rows_b, slots):
-        k = rows_b.shape[1]
-        dense = jnp.zeros((n_slots, k, rows_b.shape[2]), jnp.int32)
-        dense = dense.at[slots].set(rows_b)
-        if sharding is not None:
-            # Land the dense batch pre-sharded over the pool's mesh (the
-            # _scatter_fn out_shardings rule, expressed as a constraint
-            # inside the fused program).
-            dense = jax.lax.with_sharding_constraint(dense, sharding)
-        return engine(state, dense)
+    def fluid_step(state, rows_b, slots):
+        with jax.named_scope("scatter"):
+            k = rows_b.shape[1]
+            dense = jnp.zeros((n_slots, k, rows_b.shape[2]), jnp.int32)
+            dense = dense.at[slots].set(rows_b)
+            if sharding is not None:
+                # Land the dense batch pre-sharded over the pool's mesh
+                # (the _scatter_fn out_shardings rule, expressed as a
+                # constraint inside the fused program).
+                dense = jax.lax.with_sharding_constraint(dense, sharding)
+        with jax.named_scope("apply"):
+            return engine(state, dense)
 
-    return jax.jit(fused, donate_argnums=(0,))
+    return jax.jit(fluid_step, donate_argnums=(0,))
 
 
 # The Pallas compact unrolls log2(capacity) shift steps over every vreg of
@@ -165,10 +182,11 @@ def _compact_entry(capacity: int, kernel: str, sharding):
         fn = _per_shard(_pallas_compact, sharding, 1)
     else:
         fn = _pallas_compact
-    return jax.jit(fn, donate_argnums=(0,))
+    return jax.jit(_program("fluid_compact", fn), donate_argnums=(0,))
 
 
 @jax.jit
+@functools.partial(_program, "fluid_scan")
 def _pool_scan(state: SegmentState):
     """One [2, n_slots] (count, err) scan per pool — the fused health
     readback the serving path consumes asynchronously (one transfer per
@@ -298,6 +316,7 @@ def _doc_gather(state: SegmentState, slot):
 
 
 @jax.jit
+@functools.partial(_program, "fluid_read_gather")
 def _docs_gather(state: SegmentState, slots):
     """N documents' lanes + scalars gathered ON DEVICE as one flat
     ``[n, L*S + 5]``-row vector (r15, the read-path fan-out): the

@@ -223,7 +223,8 @@ class DeviceFleetBackend:
         # regression-tested).
         self.flush_totals: Dict[str, float] = {
             "staging_s": 0.0, "dispatch_s": 0.0, "routing_s": 0.0,
-            "staged_rows": 0,
+            "staged_rows": 0,  # padded to the [B, K] bucket
+            "real_rows": 0,  # op rows, before padding
         }
         # The continuous device pump (r10): double-buffered ingest ring +
         # AOT donated dispatch. pump_mode routes flush() through the
@@ -802,7 +803,7 @@ class DeviceFleetBackend:
         """The pre-pump serving loop (the pump's parity reference)."""
         newly_errored: List[ChannelKey] = []
         staging_s = dispatch_s = routing_s = 0.0
-        staged_rows = 0
+        staged_rows = real_rows = 0
         while self._buffers:
             # Consume the PREVIOUS dispatch's health scan before routing
             # this round: promotion (tier moves, sharded-overflow
@@ -814,67 +815,58 @@ class DeviceFleetBackend:
             # and the boxcar assembles with one np.stack when every
             # channel shipped the same row count (the round-shaped frame
             # wire's common case).
-            t0 = time.perf_counter()
-            idxs, rows_list, lens, jspans, bid = self._stage_host()
-            n = len(idxs)
-            if self._sharded:
-                shard_sel = np.fromiter(
-                    (int(i) in self._sharded for i in idxs), bool, n
-                )
-                fleet_sel = np.flatnonzero(~shard_sel)
-                sharded_rows = {
-                    int(idxs[i]): rows_list[i]
-                    for i in np.flatnonzero(shard_sel)
-                }
-            else:
-                fleet_sel = np.arange(n)
-                sharded_rows = {}
-            k = _pow2_at_least(max(int(lens.max()), 8))
-            if fleet_sel.size:
-                fleet_docs = idxs[fleet_sel]
-                fl = (
-                    rows_list
-                    if fleet_sel.size == n
-                    else [rows_list[i] for i in fleet_sel]
-                )
-                flens = lens[fleet_sel]
-                lmax = int(flens.max())
-                if int(flens.min()) == lmax:
-                    ops_b = np.zeros((len(fl), k, OP_WIDTH), np.int32)
-                    ops_b[:, :lmax] = np.stack(fl)
-                else:
-                    ops_b = np.zeros((len(fl), k, OP_WIDTH), np.int32)
-                    for j, rows in enumerate(fl):
-                        ops_b[j, : rows.shape[0]] = rows
-                t1 = time.perf_counter()
-                self.fleet.apply_sparse(fleet_docs, ops_b)
-                t2 = time.perf_counter()
-                if profiler._ON:
-                    # One clock: the SAME t0/t1/t2 reads feed the
-                    # profiler lanes and the legacy staging/dispatch
-                    # split below (derived view, not a second clock).
-                    profiler.record(
-                        "host_stage", t0, t1, boxcar=bid,
-                        rows=int(lens.sum()),
+            # One clock: the spans' own reads feed the profiler lanes
+            # and the legacy staging/dispatch split below (derived
+            # view, not a second clock).
+            bid = self._boxcar_seq + 1  # what _stage_host will stamp
+            with profiler.span("host_stage", boxcar=bid) as staged:
+                idxs, rows_list, lens, jspans, bid = self._stage_host()
+                staged.rows = int(lens.sum())
+                n = len(idxs)
+                if self._sharded:
+                    shard_sel = np.fromiter(
+                        (int(i) in self._sharded for i in idxs), bool, n
                     )
-                    profiler.record("dispatch", t1, t2, boxcar=bid)
-                staging_s += t1 - t0
+                    fleet_sel = np.flatnonzero(~shard_sel)
+                    sharded_rows = {
+                        int(idxs[i]): rows_list[i]
+                        for i in np.flatnonzero(shard_sel)
+                    }
+                else:
+                    fleet_sel = np.arange(n)
+                    sharded_rows = {}
+                k = _pow2_at_least(max(int(lens.max()), 8))
+                if fleet_sel.size:
+                    fleet_docs = idxs[fleet_sel]
+                    fl = (
+                        rows_list
+                        if fleet_sel.size == n
+                        else [rows_list[i] for i in fleet_sel]
+                    )
+                    flens = lens[fleet_sel]
+                    lmax = int(flens.max())
+                    if int(flens.min()) == lmax:
+                        ops_b = np.zeros((len(fl), k, OP_WIDTH), np.int32)
+                        ops_b[:, :lmax] = np.stack(fl)
+                    else:
+                        ops_b = np.zeros((len(fl), k, OP_WIDTH), np.int32)
+                        for j, rows in enumerate(fl):
+                            ops_b[j, : rows.shape[0]] = rows
+            staging_s += staged.t1 - staged.t0
+            real_rows += staged.rows
+            if fleet_sel.size:
+                with profiler.span("dispatch", boxcar=bid) as sent:
+                    self.fleet.apply_sparse(fleet_docs, ops_b)
                 routing_s += self.fleet.last_routing_s
-                dispatch_s += (t2 - t1) - self.fleet.last_routing_s
+                dispatch_s += (
+                    (sent.t1 - sent.t0) - self.fleet.last_routing_s
+                )
                 staged_rows += ops_b.shape[0] * k
                 self._scan_token = self.fleet.begin_scan()
                 self._scan_bid = bid
                 if jspans:
                     journal.record("device.dispatch", spans=jspans)
                     self._journal_inflight.append(jspans)
-            else:
-                t1 = time.perf_counter()
-                if profiler._ON:
-                    profiler.record(
-                        "host_stage", t0, t1, boxcar=bid,
-                        rows=int(lens.sum()),
-                    )
-                staging_s += t1 - t0
             self._flushes += 1
             compact_now = self._flushes % self.compact_every == 0
             for idx, rows in sharded_rows.items():
@@ -898,11 +890,10 @@ class DeviceFleetBackend:
             "dispatch_s": dispatch_s,
             "routing_s": routing_s,
             "staged_rows": staged_rows,
+            "real_rows": real_rows,
         }
-        self.flush_totals["staging_s"] += staging_s
-        self.flush_totals["dispatch_s"] += dispatch_s
-        self.flush_totals["routing_s"] += routing_s
-        self.flush_totals["staged_rows"] += staged_rows
+        for key, value in self.last_flush_breakdown.items():
+            self.flush_totals[key] += value
         self._unreported.extend(newly_errored)
         return newly_errored
 
@@ -1008,49 +999,46 @@ class DeviceFleetBackend:
             self.pump_backpressure += 1
             self._dispatch_one()
         feed_edge = self._feed_edge  # _stage_host re-arms it
-        t0 = time.perf_counter()
-        traces = self._trace_pending
-        self._trace_pending = []
-        for t in traces:
-            tracing.stamp(t, tracing.STAGE_FEED_WAIT, "end")
-            tracing.stamp(t, tracing.STAGE_RING_STAGE, "start")
-        idxs, rows_list, lens, jspans, bid = self._stage_host()
-        n = len(idxs)
-        k = _pow2_at_least(max(int(lens.max()), 8))
-        b = _pow2_at_least(n)
-        rows_b = np.zeros((b, k, OP_WIDTH), np.int32)
-        lmax = int(lens.max())
-        if int(lens.min()) == lmax:
-            rows_b[:n, :lmax] = np.stack(rows_list)
-        else:
-            for j, rows in enumerate(rows_list):
-                rows_b[j, : rows.shape[0]] = rows
-        t_host = time.perf_counter()
-        dev_rows = jax.device_put(rows_b)  # async upload into the slot
-        t_put = time.perf_counter()
-        if profiler._ON:
-            # One clock, one record site (r16): the SAME perf_counter
-            # reads feed the timeline lanes and the legacy staging_s
-            # accumulation below — the counter is a derived view of the
-            # intervals (equivalence regression-tested).
-            rows_n = int(lens.sum())
-            if feed_edge is not None:
-                profiler.record("feed_wait", feed_edge, t0, boxcar=bid,
-                                rows=rows_n)
-            profiler.record("host_stage", t0, t_host, boxcar=bid,
+        # One clock, one record site (r16): the spans' own perf_counter
+        # reads feed the lanes and the legacy staging_s accumulation
+        # below — the counter is a derived view of the intervals
+        # (equivalence regression-tested).
+        bid = self._boxcar_seq + 1  # what _stage_host will stamp
+        with profiler.span("host_stage", boxcar=bid) as staged:
+            traces = self._trace_pending
+            self._trace_pending = []
+            for t in traces:
+                tracing.stamp(t, tracing.STAGE_FEED_WAIT, "end")
+                tracing.stamp(t, tracing.STAGE_RING_STAGE, "start")
+            idxs, rows_list, lens, jspans, bid = self._stage_host()
+            staged.rows = rows_n = int(lens.sum())
+            n = len(idxs)
+            k = _pow2_at_least(max(int(lens.max()), 8))
+            b = _pow2_at_least(n)
+            rows_b = np.zeros((b, k, OP_WIDTH), np.int32)
+            lmax = int(lens.max())
+            if int(lens.min()) == lmax:
+                rows_b[:n, :lmax] = np.stack(rows_list)
+            else:
+                for j, rows in enumerate(rows_list):
+                    rows_b[j, : rows.shape[0]] = rows
+        if feed_edge is not None:
+            profiler.record("feed_wait", feed_edge, staged.t0, boxcar=bid,
                             rows=rows_n)
-            profiler.record("ring_put", t_host, t_put, boxcar=bid,
-                            rows=rows_n)
+        with profiler.span("ring_put", boxcar=bid, rows=rows_n) as put:
+            dev_rows = jax.device_put(rows_b)  # async upload into the slot
         for t in traces:
             tracing.stamp(t, tracing.STAGE_RING_STAGE, "end")
         self._ring.push(
             _RingSlot(
-                dev_rows, rows_b, idxs, lens, int(lens.sum()), traces,
-                jspans, bid,
+                dev_rows, rows_b, idxs, lens, rows_n, traces, jspans, bid,
             )
         )
-        self.flush_totals["staging_s"] += (t_host - t0) + (t_put - t_host)
+        self.flush_totals["staging_s"] += (
+            (staged.t1 - staged.t0) + (put.t1 - put.t0)
+        )
         self.flush_totals["staged_rows"] += b * k
+        self.flush_totals["real_rows"] += rows_n
         return True
 
     def pump_dispatch(self) -> List[ChannelKey]:
@@ -1090,6 +1078,55 @@ class DeviceFleetBackend:
         sel = np.flatnonzero(in_fleet)
         self.fleet.apply_sparse(slot.docs[sel], slot.host_rows[:n][sel])
 
+    def _dispatch_guarded(self, slot: _RingSlot, in_fleet: np.ndarray) -> None:
+        """:meth:`_dispatch_device` with the ``pump.dispatch`` site's
+        recovery: requeue, fallback or surface, each counted."""
+        try:
+            self._dispatch_device(slot.docs, slot.dev_rows)
+        except faults.InjectedCrash as e:
+            # Crash mid-dispatch: if the dispatch never executed the
+            # staged slot must survive to the drain (pump_drain
+            # replays it; watermarks advanced at stage time, so the
+            # replay applies exactly once). A crash AFTER the
+            # dispatch leaves the applied state authoritative —
+            # requeueing then would double-apply.
+            if not e.completed:
+                self._ring.staged.appendleft(slot)
+                retry.retry_counter().inc(
+                    site="pump.dispatch", outcome="requeue"
+                )
+                if journal._ON:
+                    journal.record(
+                        "retry.outcome", site="pump.dispatch",
+                        outcome="requeue",
+                    )
+            else:
+                # The dispatch landed; the crash only cost the ack.
+                # Nothing to recover — surfaced to the supervisor.
+                retry.retry_counter().inc(
+                    site="pump.dispatch", outcome="fatal"
+                )
+                journal.retry_outcome("pump.dispatch", "fatal")
+            raise
+        except faults.InjectedFault:
+            # Injected dispatch failure: the wrapper fires BEFORE any
+            # device work, so the fallback can re-apply the slot from
+            # its host copy with no double-apply risk.
+            self._dispatch_fallback(slot, in_fleet)
+        except Exception:
+            # A REAL dispatch failure may have applied a PREFIX of
+            # the slot's pools (dispatch_staged loops per pool), so
+            # neither an in-place fallback nor a requeue can avoid
+            # double-applying what landed. Surface it: the device
+            # stage's documented recovery is the cold restart +
+            # deltas-log replay (crash_device), which rebuilds every
+            # channel replica exactly.
+            retry.retry_counter().inc(
+                site="pump.dispatch", outcome="fatal"
+            )
+            journal.retry_outcome("pump.dispatch", "fatal")
+            raise
+
     def _dispatch_one(self) -> List[ChannelKey]:
         """Dispatch the oldest staged ring slot. Order per dispatch:
         (1) consume the PREVIOUS dispatch's health scan — one boxcar
@@ -1108,64 +1145,16 @@ class DeviceFleetBackend:
             tracing.stamp(t, tracing.STAGE_DEVICE_STEP, "start")
         in_fleet = self.fleet.doc_caps(slot.docs) > 0
         if in_fleet.any():
-            t_d0 = time.perf_counter()
-            try:
-                self._dispatch_device(slot.docs, slot.dev_rows)
-            except faults.InjectedCrash as e:
-                # Crash mid-dispatch: if the dispatch never executed the
-                # staged slot must survive to the drain (pump_drain
-                # replays it; watermarks advanced at stage time, so the
-                # replay applies exactly once). A crash AFTER the
-                # dispatch leaves the applied state authoritative —
-                # requeueing then would double-apply.
-                if not e.completed:
-                    self._ring.staged.appendleft(slot)
-                    retry.retry_counter().inc(
-                        site="pump.dispatch", outcome="requeue"
-                    )
-                    if journal._ON:
-                        journal.record(
-                            "retry.outcome", site="pump.dispatch",
-                            outcome="requeue",
-                        )
-                else:
-                    # The dispatch landed; the crash only cost the ack.
-                    # Nothing to recover — surfaced to the supervisor.
-                    retry.retry_counter().inc(
-                        site="pump.dispatch", outcome="fatal"
-                    )
-                    journal.retry_outcome("pump.dispatch", "fatal")
-                raise
-            except faults.InjectedFault:
-                # Injected dispatch failure: the wrapper fires BEFORE any
-                # device work, so the fallback can re-apply the slot from
-                # its host copy with no double-apply risk.
-                self._dispatch_fallback(slot, in_fleet)
-            except Exception:
-                # A REAL dispatch failure may have applied a PREFIX of
-                # the slot's pools (dispatch_staged loops per pool), so
-                # neither an in-place fallback nor a requeue can avoid
-                # double-applying what landed. Surface it: the device
-                # stage's documented recovery is the cold restart +
-                # deltas-log replay (crash_device), which rebuilds every
-                # channel replica exactly.
-                retry.retry_counter().inc(
-                    site="pump.dispatch", outcome="fatal"
-                )
-                journal.retry_outcome("pump.dispatch", "fatal")
-                raise
-            self._scan_token = self.fleet.begin_scan()
-            # One perf_counter read closes the dispatch interval AND
-            # arms the busy-union edge — the device_step interval this
-            # round later produces starts from the same float.
-            t_d1 = time.perf_counter()
-            self._scan_dispatch_t = t_d1
+            with profiler.span(
+                "dispatch", boxcar=slot.bid, rows=slot.rows
+            ) as sent:
+                self._dispatch_guarded(slot, in_fleet)
+                self._scan_token = self.fleet.begin_scan()
+            # The span's closing read ALSO arms the busy-union edge —
+            # the device_step interval this round later produces starts
+            # from the same float.
+            self._scan_dispatch_t = sent.t1
             self._scan_bid = slot.bid
-            if profiler._ON:
-                profiler.record(
-                    "dispatch", t_d0, t_d1, boxcar=slot.bid,
-                    rows=slot.rows,
-                )
         if slot.jspans:
             journal.record("device.dispatch", spans=slot.jspans)
             self._journal_inflight.append(slot.jspans)
@@ -1413,21 +1402,20 @@ class DeviceFleetBackend:
             return
         for t in self._trace_inflight:
             tracing.stamp(t, tracing.STAGE_SCAN_CONSUME, "start")
-        t_c0 = time.perf_counter()
-        host = None
-        if self._scan_prefetch is not None:
-            tok, pre = self._scan_prefetch
-            self._scan_prefetch = None
-            if tok is self._scan_token:
-                # The ticker already ran this token's blocking transfer
-                # off-loop; only the slot-generation masking runs here.
-                host = pre
-        scans = self.fleet.finish_scan(self._scan_token, host=host)
-        self._scan_token = None
-        now = time.perf_counter()
         scan_bid, self._scan_bid = self._scan_bid, -1
-        if profiler._ON:
-            profiler.record("scan_consume", t_c0, now, boxcar=scan_bid)
+        with profiler.span("scan_consume", boxcar=scan_bid) as consumed:
+            host = None
+            if self._scan_prefetch is not None:
+                tok, pre = self._scan_prefetch
+                self._scan_prefetch = None
+                if tok is self._scan_token:
+                    # The ticker already ran this token's blocking
+                    # transfer off-loop; only the slot-generation
+                    # masking runs here.
+                    host = pre
+            scans = self.fleet.finish_scan(self._scan_token, host=host)
+            self._scan_token = None
+        now = consumed.t1
         if self._scan_dispatch_t is not None:
             # Union of dispatch->readback intervals (ordered, so a
             # running edge suffices): busy wall the device provably had
@@ -1438,10 +1426,7 @@ class DeviceFleetBackend:
             start = max(self._scan_dispatch_t, self._busy_edge)
             if now > start:
                 self.pump_busy_s += now - start
-                if profiler._ON:
-                    profiler.record(
-                        "device_step", start, now, boxcar=scan_bid
-                    )
+                profiler.record("device_step", start, now, boxcar=scan_bid)
             self._busy_edge = now
             self._scan_dispatch_t = None
         for t in self._trace_inflight:

@@ -41,7 +41,7 @@ from urllib.parse import parse_qs, urlparse
 from fluidframework_tpu.service import admission, retry, wsproto
 from fluidframework_tpu.service.codec import from_jsonable, to_jsonable
 from fluidframework_tpu.service.local_server import LocalFluidService
-from fluidframework_tpu.telemetry import metrics
+from fluidframework_tpu.telemetry import metrics, profiler
 from fluidframework_tpu.testing import faults
 from fluidframework_tpu.testing.faults import inject_fault
 
@@ -348,13 +348,14 @@ class FluidNetworkServer:
         # socket loop's expected-vs-actual tick delta every period and
         # exports it as the event_loop_lag_ms gauge; past the threshold
         # it journals a loop.stall event (a blocking readback regression
-        # on the loop is caught BY NAME) and, while a /profilez capture
-        # is armed, records a loop_lag timeline interval. lag_ticks
-        # counts sentinel wakeups (tests wait on it); stalls_seen counts
-        # threshold crossings.
+        # on the loop is caught BY NAME) and records a loop_lag
+        # interval. lag_ticks counts sentinel wakeups (tests wait on
+        # it), lag_sum_ms adds up every tick's overshoot; stalls_seen
+        # counts threshold crossings.
         self._lag_task: Optional[asyncio.Task] = None
         self.loop_lag_threshold_ms = 50.0
         self.lag_ticks = 0
+        self.lag_sum_ms = 0.0
         self.stalls_seen = 0
         # The overload envelope (r13): the REFUSE_CONNECTIONS tier gates
         # the accept path (a refused socket gets a 503 + Retry-After
@@ -417,8 +418,6 @@ class FluidNetworkServer:
             # device-less service can still block its loop), and the gc
             # pause hooks install once per process (idempotent).
             self._lag_task = asyncio.ensure_future(self._lag_sentinel())
-            from fluidframework_tpu.telemetry import profiler
-
             profiler.install_gc_hooks()
             self._push_drainer.start()
             self._started.set()
@@ -611,8 +610,6 @@ class FluidNetworkServer:
             # /debugz, whose exemption exists because they allocate
             # nothing the envelope needs to protect.
             import math
-
-            from fluidframework_tpu.telemetry import profiler
 
             try:
                 duration_ms = float(query.get("duration_ms", 250.0))
@@ -817,7 +814,9 @@ class FluidNetworkServer:
         rides the same device gather."""
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
-        self._pending_reads.append((doc_id, channel_id, view, fut))
+        self._pending_reads.append(
+            (doc_id, channel_id, view, fut, time.perf_counter())
+        )
         if not self._reads_scheduled:
             self._reads_scheduled = True
             asyncio.ensure_future(self._serve_reads())
@@ -842,16 +841,20 @@ class FluidNetworkServer:
         pending, self._pending_reads = self._pending_reads, []
         if not pending:
             return
+        taken = time.perf_counter()
+        for *_req, queued in pending:
+            profiler.record("read_wait", queued, taken)
         try:
-            svc_pump = getattr(self.service, "pump", None)
-            if svc_pump is not None:
-                svc_pump()  # settle so fresh channels are visible
-            # Re-fetch: crash_device() replaces the backend.
-            dev = getattr(self.service, "device", None)
-            if dev.needs_flush():
-                dev.flush()
+            with profiler.span("read_settle"):
+                svc_pump = getattr(self.service, "pump", None)
+                if svc_pump is not None:
+                    svc_pump()  # settle so fresh channels are visible
+                # Re-fetch: crash_device() replaces the backend.
+                dev = getattr(self.service, "device", None)
+                if dev.needs_flush():
+                    dev.flush()
             reqs = []
-            for doc_id, channel_id, view, fut in pending:
+            for doc_id, channel_id, view, fut, _queued in pending:
                 if not dev.has_channel(doc_id, channel_id):
                     if not fut.done():
                         fut.set_result(
@@ -862,46 +865,65 @@ class FluidNetworkServer:
             if not reqs:
                 return
             keys = list(dict.fromkeys((d, c) for d, c, _v, _f in reqs))
-            token = dev.read_start(keys)
+            with profiler.span("read_gather", rows=len(keys)):
+                token = dev.read_start(keys)
             host = None
             if token["dev"] is not None:
-                host = await asyncio.get_running_loop().run_in_executor(
-                    None, dev.read_transfer, token["dev"]
-                )
-            states = dev.read_finish(token, host)
-            # Duplicate-key requests (N readers of one hot doc) were
-            # deduped out of the gather but ARE reads served by this
-            # dispatch — the amortization counter must see them.
-            dev.reads_served += len(reqs) - len(keys)
-            self.read_batches += 1
-            for doc_id, channel_id, view, fut in reqs:
-                key = (doc_id, channel_id)
-                try:
-                    # Per-request isolation: one bad channel must fail
-                    # ITS reader, not every future in the batch.
-                    if view == "summary":
-                        payload = json.dumps(
-                            dev.summary_from_state(key, states[key])
-                        ).encode()
-                    else:
-                        payload = json.dumps({
-                            "text": dev.text_from_state(key, states[key])
-                        }).encode()
-                    result = (200, payload)
-                except Exception as e:
-                    result = (
-                        500,
-                        json.dumps({"error": repr(e)[:200]}).encode(),
+                host, t0, t1 = (
+                    await asyncio.get_running_loop().run_in_executor(
+                        None, self._read_transfer, dev, token["dev"]
                     )
-                if not fut.done():
-                    fut.set_result(result)
+                )
+                profiler.record("read_transfer", t0, t1)
+            with profiler.span("read_finish", rows=len(reqs)):
+                self._read_finish(dev, token, host, reqs, len(keys))
         except Exception as e:
-            for _d, _c, _v, fut in pending:
+            for _d, _c, _v, fut, _queued in pending:
                 if not fut.done():
                     fut.set_result((
                         500,
                         json.dumps({"error": repr(e)[:200]}).encode(),
                     ))
+
+    @staticmethod
+    def _read_transfer(dev, dev_vec):
+        """Executor thread: the blocking device→host wait of one read
+        batch under its trace annotation. The two clock reads go back to
+        the loop, which owns the lane's totals (``span(commit=False)``)."""
+        with profiler.span("read_transfer", commit=False) as waited:
+            host = dev.read_transfer(dev_vec)
+        return host, waited.t0, waited.t1
+
+    def _read_finish(self, dev, token, host, reqs, n_keys: int) -> None:
+        """The loop's last half of one read batch: split the gathered
+        states, materialize and encode each reply, resolve the futures."""
+        states = dev.read_finish(token, host)
+        # Duplicate-key requests (N readers of one hot doc) were
+        # deduped out of the gather but ARE reads served by this
+        # dispatch — the amortization counter must see them.
+        dev.reads_served += len(reqs) - n_keys
+        self.read_batches += 1
+        for doc_id, channel_id, view, fut in reqs:
+            key = (doc_id, channel_id)
+            try:
+                # Per-request isolation: one bad channel must fail
+                # ITS reader, not every future in the batch.
+                if view == "summary":
+                    payload = json.dumps(
+                        dev.summary_from_state(key, states[key])
+                    ).encode()
+                else:
+                    payload = json.dumps({
+                        "text": dev.text_from_state(key, states[key])
+                    }).encode()
+                result = (200, payload)
+            except Exception as e:
+                result = (
+                    500,
+                    json.dumps({"error": repr(e)[:200]}).encode(),
+                )
+            if not fut.done():
+                fut.set_result(result)
 
     #: Loop-lag sentinel period (s): the expected tick delta the stall
     #: watchdog measures against. Small enough to catch a blocked loop
@@ -916,9 +938,10 @@ class FluidNetworkServer:
         compile, or a long Python pass overshoots by the blocked wall —
         which this task measures BY CONSTRUCTION (its wakeup queues
         behind the blocking call), exports as ``event_loop_lag_ms``,
-        journals past the threshold (``loop.stall``), and records on the
-        ``loop_lag`` timeline lane while a /profilez capture is armed."""
-        from fluidframework_tpu.telemetry import journal, profiler
+        journals past the threshold (``loop.stall``) and records on the
+        ``loop_lag`` lane; every tick adds its overshoot to
+        ``lag_sum_ms``, so a window's mean lag can be read back."""
+        from fluidframework_tpu.telemetry import journal
 
         period = self.LOOP_LAG_PERIOD_S
         while True:
@@ -927,6 +950,7 @@ class FluidNetworkServer:
             t1 = time.perf_counter()
             self.lag_ticks += 1
             lag_ms = max(0.0, (t1 - t0 - period) * 1e3)
+            self.lag_sum_ms += lag_ms
             # Re-resolved per tick (one dict probe): the registry idiom
             # that survives a test-isolation REGISTRY.reset().
             profiler.loop_lag_gauge().set(round(lag_ms, 3))
@@ -941,10 +965,9 @@ class FluidNetworkServer:
                         "loop.stall", lag_ms=round(lag_ms, 3),
                         threshold_ms=self.loop_lag_threshold_ms,
                     )
-                if profiler._ON:
-                    # The stall interval is the overshoot itself: the
-                    # expected wake instant to the actual one.
-                    profiler.record("loop_lag", t0 + period, t1)
+                # The stall interval is the overshoot itself: the
+                # expected wake instant to the actual one.
+                profiler.record("loop_lag", t0 + period, t1)
 
     async def _pump_ticker(self) -> None:
         """The r12 deadline ticker (the continuous-feed analog of the
@@ -1469,7 +1492,8 @@ class FluidNetworkServer:
         if session.conn is None:
             return
         self.frames_received += 1
-        frame = OpFrame.decode(payload)
+        with profiler.span("front_door"):
+            frame = OpFrame.decode(payload)
         submit = getattr(session.conn, "submit_frame", None)
         if submit is not None:
             submit(frame)
@@ -1633,57 +1657,62 @@ class FluidNetworkServer:
         # past their watermark. Per-subscriber state is a watermark + a
         # requeue tail — the r11 exactly-once crash-after semantics per
         # socket are unchanged.
-        self._push_sweep()
-        for s in self._sessions:
-            if s.conn is None:
-                continue
-            nopump = getattr(s.conn, "supports_nopump", False)
-            take_raw = (
-                getattr(s.conn, "take_inbox_raw", None)
-                if s.frames_ok else None
-            )
-            if take_raw is not None:
-                msgs = take_raw(pump=False) if nopump else take_raw()
-            else:
-                msgs = (
-                    s.conn.take_inbox(pump=False)
-                    if nopump else s.conn.take_inbox()
+        with profiler.span("socket_out"):
+            self._push_sweep()
+            for s in self._sessions:
+                if s.conn is None:
+                    continue
+                nopump = getattr(s.conn, "supports_nopump", False)
+                take_raw = (
+                    getattr(s.conn, "take_inbox_raw", None)
+                    if s.frames_ok else None
                 )
-            for j, m in enumerate(msgs):
-                try:
-                    if hasattr(m, "sequence_number"):
-                        self._deliver_obj(
-                            s, {"type": "op", "msg": to_jsonable(m)}
+                if take_raw is not None:
+                    msgs = take_raw(pump=False) if nopump else take_raw()
+                else:
+                    msgs = (
+                        s.conn.take_inbox(pump=False)
+                        if nopump else s.conn.take_inbox()
+                    )
+                for j, m in enumerate(msgs):
+                    try:
+                        if hasattr(m, "sequence_number"):
+                            self._deliver_obj(
+                                s, {"type": "op", "msg": to_jsonable(m)}
+                            )
+                        else:
+                            # SeqFrame: n sequenced ops in ONE binary frame.
+                            self._deliver(s, wsproto.encode_frame(
+                                wsproto.OP_BINARY, m.encode()
+                            ))
+                            self.frames_delivered += 1
+                    except Exception as e:
+                        self._requeue(
+                            s.conn.inbox, self._unsent_tail(msgs, j, e)
                         )
-                    else:
-                        # SeqFrame: n sequenced ops in ONE binary frame.
-                        self._deliver(s, wsproto.encode_frame(
-                            wsproto.OP_BINARY, m.encode()
-                        ))
-                        self.frames_delivered += 1
-                except Exception as e:
-                    self._requeue(s.conn.inbox, self._unsent_tail(msgs, j, e))
-                    break
-            sigs, s.conn.signals[:] = list(s.conn.signals), []
-            for j, sig in enumerate(sigs):
-                try:
-                    self._deliver_obj(s, {
-                        "type": "signal",
-                        "client_id": sig.client_id,
-                        "num": sig.client_connection_number,
-                        "content": sig.content,
-                    })
-                except Exception as e:
-                    self._requeue(
-                        s.conn.signals, self._unsent_tail(sigs, j, e)
-                    )
-                    break
-            nacks, s.conn.nacks[:] = list(s.conn.nacks), []
-            for j, nk in enumerate(nacks):
-                try:
-                    self._deliver_obj(
-                        s, {"type": "nack", "nack": to_jsonable(nk)}
-                    )
-                except Exception as e:
-                    self._requeue(s.conn.nacks, self._unsent_tail(nacks, j, e))
-                    break
+                        break
+                sigs, s.conn.signals[:] = list(s.conn.signals), []
+                for j, sig in enumerate(sigs):
+                    try:
+                        self._deliver_obj(s, {
+                            "type": "signal",
+                            "client_id": sig.client_id,
+                            "num": sig.client_connection_number,
+                            "content": sig.content,
+                        })
+                    except Exception as e:
+                        self._requeue(
+                            s.conn.signals, self._unsent_tail(sigs, j, e)
+                        )
+                        break
+                nacks, s.conn.nacks[:] = list(s.conn.nacks), []
+                for j, nk in enumerate(nacks):
+                    try:
+                        self._deliver_obj(
+                            s, {"type": "nack", "nack": to_jsonable(nk)}
+                        )
+                    except Exception as e:
+                        self._requeue(
+                            s.conn.nacks, self._unsent_tail(nacks, j, e)
+                        )
+                        break

@@ -48,7 +48,7 @@ from fluidframework_tpu.service.admission import (
 )
 from fluidframework_tpu.service.queue import PartitionedLog
 from fluidframework_tpu.service.summary_store import SummaryStore
-from fluidframework_tpu.telemetry import journal, tracing
+from fluidframework_tpu.telemetry import journal, profiler, tracing
 from fluidframework_tpu.testing.faults import inject_fault
 
 
@@ -387,15 +387,19 @@ class PipelineFluidService:
         (feeds ride the same stage/dispatch machinery as flush)."""
         total = 0
         while True:
-            n = (
-                self._deli.pump()
-                + self._scribe.pump()
-                + self._scriptorium.pump()
-                + self._broadcaster.pump()
-                + self._signals.pump()
-            )
+            # One span per stage per sweep: the stage's lane on the
+            # device trace's clock and in the always-on lane totals.
+            with profiler.span("deli"):
+                n = self._deli.pump()
+            with profiler.span("scribe"):
+                n += self._scribe.pump()
+            with profiler.span("scriptorium"):
+                n += self._scriptorium.pump()
+            with profiler.span("broadcast"):
+                n += self._broadcaster.pump() + self._signals.pump()
             if self._device_runner is not None:
-                nd = self._device_runner.pump()
+                with profiler.span("device_stage"):
+                    nd = self._device_runner.pump()
                 n += nd
                 if nd and self.device is not None and self.device.pump_mode:
                     # One continuous-feed tick WHILE the other stages
@@ -770,16 +774,20 @@ class PipelineFluidService:
         wire) carry a trace list on the RECORD envelope — the binary
         frame wire itself never changes — stamped at every stage
         boundary downstream."""
-        if not self._admit_write(
-            doc_id, client_id, frame.n, csn=frame.csn0
-        ):
-            return
-        rec = {"t": "opframe", "client": client_id, "frame": frame}
-        if self.trace_sampler is not None and self.trace_sampler.should_trace():
-            traces = self.trace_book.open()
-            tracing.stamp(traces, tracing.STAGE_ALFRED, "start")
-            rec["traces"] = traces
-        self._send_raw(doc_id, rec)
+        with profiler.span("front_door"):
+            if not self._admit_write(
+                doc_id, client_id, frame.n, csn=frame.csn0
+            ):
+                return
+            rec = {"t": "opframe", "client": client_id, "frame": frame}
+            if (
+                self.trace_sampler is not None
+                and self.trace_sampler.should_trace()
+            ):
+                traces = self.trace_book.open()
+                tracing.stamp(traces, tracing.STAGE_ALFRED, "start")
+                rec["traces"] = traces
+            self._send_raw(doc_id, rec)
         self.pump()
 
     def submit_frames_bulk(self, items, pump: bool = True) -> None:
@@ -790,55 +798,56 @@ class PipelineFluidService:
         was a measurable share of the serving path (the reference batches
         the same way: socket submits boxcar into one Kafka produce,
         ``pendingBoxcar.ts``)."""
-        sampler = self.trace_sampler
-        # Admission gates the BULK front door too (r13): frames admit
-        # or nack per-doc-budget — an admitted NEIGHBOR (different
-        # client) is unaffected by a throttled one — but a denial is
-        # STICKY per (doc, client) for the rest of the batch: admitting
-        # a later frame from the same client after denying an earlier
-        # one would hand the sequencer a csn gap (a 400 nack the client
-        # cannot pace on). The caller can't react mid-batch, so the
-        # server enforces the ordering the client contract (resubmit
-        # from the denied csn) otherwise provides across calls.
-        entries = []
-        denied: Dict[tuple, float] = {}
-        for doc_id, client_id, frame in items:
-            key = (doc_id, client_id)
-            if key in denied:
-                conn = self._room_conn(doc_id, client_id)
-                if conn is not None:
-                    self._deliver_throttle_nack(
-                        conn, frame.csn0, denied[key], "csn_order"
+        with profiler.span("front_door"):
+            sampler = self.trace_sampler
+            # Admission gates the BULK front door too (r13): frames admit
+            # or nack per-doc-budget — an admitted NEIGHBOR (different
+            # client) is unaffected by a throttled one — but a denial is
+            # STICKY per (doc, client) for the rest of the batch: admitting
+            # a later frame from the same client after denying an earlier
+            # one would hand the sequencer a csn gap (a 400 nack the client
+            # cannot pace on). The caller can't react mid-batch, so the
+            # server enforces the ordering the client contract (resubmit
+            # from the denied csn) otherwise provides across calls.
+            entries = []
+            denied: Dict[tuple, float] = {}
+            for doc_id, client_id, frame in items:
+                key = (doc_id, client_id)
+                if key in denied:
+                    conn = self._room_conn(doc_id, client_id)
+                    if conn is not None:
+                        self._deliver_throttle_nack(
+                            conn, frame.csn0, denied[key], "csn_order"
+                        )
+                    continue
+                if not self._admit_write(
+                    doc_id, client_id, frame.n, csn=frame.csn0
+                ):
+                    conn = self._room_conn(doc_id, client_id)
+                    denied[key] = (
+                        conn.nacks[-1].retry_after_s * 1e3
+                        if conn is not None and conn.nacks else 25.0
                     )
-                continue
-            if not self._admit_write(
-                doc_id, client_id, frame.n, csn=frame.csn0
-            ):
-                conn = self._room_conn(doc_id, client_id)
-                denied[key] = (
-                    conn.nacks[-1].retry_after_s * 1e3
-                    if conn is not None and conn.nacks else 25.0
-                )
-                continue
-            rec = {"t": "opframe", "client": client_id, "frame": frame}
-            if sampler is not None and sampler.should_trace():
-                traces = self.trace_book.open()
-                tracing.stamp(traces, tracing.STAGE_ALFRED, "start")
-                rec["traces"] = traces
-            entries.append((doc_id, rec))
-        if entries:  # a fully-throttled round produces nothing: the
-            # queue.send boundary (and any chaos policy armed on it)
-            # must not fire for an empty batch.
-            send_batch = getattr(self.log, "send_batch", None)
-            if send_batch is not None:
-                retry.call_with_retry(
-                    "queue.send", send_batch, RAW_TOPIC, entries
-                )
-            else:  # minimal log impls only expose send
-                for key, value in entries:
+                    continue
+                rec = {"t": "opframe", "client": client_id, "frame": frame}
+                if sampler is not None and sampler.should_trace():
+                    traces = self.trace_book.open()
+                    tracing.stamp(traces, tracing.STAGE_ALFRED, "start")
+                    rec["traces"] = traces
+                entries.append((doc_id, rec))
+            if entries:  # a fully-throttled round produces nothing: the
+                # queue.send boundary (and any chaos policy armed on it)
+                # must not fire for an empty batch.
+                send_batch = getattr(self.log, "send_batch", None)
+                if send_batch is not None:
                     retry.call_with_retry(
-                        "queue.send", self.log.send, RAW_TOPIC, key, value
+                        "queue.send", send_batch, RAW_TOPIC, entries
                     )
+                else:  # minimal log impls only expose send
+                    for key, value in entries:
+                        retry.call_with_retry(
+                            "queue.send", self.log.send, RAW_TOPIC, key, value
+                        )
         if pump:
             self.pump()
 

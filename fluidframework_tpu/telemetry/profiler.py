@@ -27,16 +27,24 @@ to timing lanes:
 - **Bounded, on-demand**: the profiler is DISARMED by default and arms
   for a bounded window (:func:`arm`); the ring is a ``deque(maxlen)``
   so even a pathological window cannot grow the process.
-- **Near-zero disabled**: every producer site is gated on the
-  module-global :data:`_ON` predicate (the ``journal._ON`` discipline);
-  disarmed, a site costs one attribute read and allocates NOTHING
-  (counting-shim-tested).
-- **One clock, one record site**: producers take their
-  ``time.perf_counter()`` stamps ONCE and feed both the interval ring
-  and the legacy counters (``pump_busy_s``,
-  ``flush_totals["staging_s"]``) from the same floats — the legacy
-  counters are derived views, not parallel instrumentation
-  (equivalence regression-tested).
+- **Cheap disarmed**: the ring records only while :data:`_ON`;
+  disarmed, a :func:`span` costs two clock reads, one
+  ``TraceAnnotation`` and two adds, allocates no :class:`Interval` and
+  takes no lock (counting-shim-tested). Per-frame sites (``ticket``)
+  stay gated on :data:`_ON` altogether.
+- **One clock, one record site**: a seam is bracketed ONCE — by
+  :func:`span` where it is a ``with`` block, by :func:`record` where it
+  starts in one call and ends in another — and the same two floats feed
+  the always-on lane totals (:func:`totals`), the interval ring while
+  armed, and the legacy counters (``pump_busy_s``,
+  ``flush_totals["staging_s"]``): derived views, not parallel
+  instrumentation (equivalence regression-tested).
+- **On the device trace's clock**: a :func:`span` is also a
+  ``jax.profiler.TraceAnnotation`` named ``fluid.<lane>`` (boxcar id and
+  row count as event stats), so while a JAX profiler trace runs the
+  program's seams sit in the same ``.xplane.pb`` as the device's
+  operations with no offset to apply. ``record()`` intervals are waits
+  and emit none: the synchronous span that ends them brackets them.
 - **Zero device readbacks**: the profiler consumes host timestamps
   only; ``device_step`` closes on the pump's EXISTING one-boxcar-stale
   scan consume. A profiler producer running its own device→host
@@ -110,6 +118,30 @@ LANES: Dict[str, str] = {
     "loop_lag": "asyncio loop-lag sentinel: measured tick overshoot past "
                 "the expected period",
     "gc_pause": "a gc.callbacks-bracketed collector pause",
+    # -- the pipeline sweep (one span per stage per sweep) ------------------
+    "front_door": "admission + raw-log append of submit_frame / "
+                  "submit_frames_bulk (up to, not including, the pump) "
+                  "and the socket front door's frame decode",
+    "deli": "the deli runner's pump inside a pipeline sweep (ticketing)",
+    "scribe": "the scribe runner's pump inside a pipeline sweep",
+    "scriptorium": "the scriptorium runner's pump (durable append)",
+    "broadcast": "the broadcaster and signal runners' pumps (room "
+                 "fan-out into per-connection queues)",
+    "device_stage": "the tpu-deli runner's pump: enqueue_frame into the "
+                    "device backend's channel buffers",
+    "socket_out": "the socket layer's delivery sweep: encode + write of "
+                  "sequenced frames, signals and nacks to every session",
+    # -- the read path ------------------------------------------------------
+    "read_wait": "a REST read queued -> its batch taken by _serve_reads "
+                 "(aggregation window + loop wait), per read",
+    "read_settle": "the pipeline pump + device flush a read batch forces",
+    "read_gather": "key resolution + the batched device gather dispatch",
+    "read_transfer": "the blocking device->host wait of one read batch "
+                     "(executor thread)",
+    "read_finish": "host split of the gathered states + text + JSON",
+    # -- start-up -----------------------------------------------------------
+    "aot_build": "one AOT entry lowered and compiled (parallel/aot.call "
+                 "miss), at start or inside a window",
 }
 
 #: Deterministic Perfetto thread id per lane (tid = declaration order).
@@ -175,6 +207,20 @@ def _union_s(spans: List[Any]) -> float:
     return total
 
 
+def _check_lane(lane: str) -> None:
+    if lane not in LANES:
+        raise ValueError(
+            f"unknown profiler lane {lane!r} "
+            f"(vocabulary: {', '.join(sorted(LANES))})"
+        )
+    if lane == "loop_other":
+        raise ValueError(
+            "loop_other is DERIVED (the uncovered gap inside a boxcar "
+            "round) — summarize()/chrome_trace() synthesize it; "
+            "recording it directly would double-count the tax"
+        )
+
+
 class Profiler:
     """A bounded ring of :class:`Interval`. All mutation is lock-guarded
     (the socket loop records from its thread while a bench/test thread
@@ -193,17 +239,7 @@ class Profiler:
         self, lane: str, t0: float, t1: float, boxcar: int = -1,
         rows: int = 0,
     ) -> None:
-        if lane not in LANES:
-            raise ValueError(
-                f"unknown profiler lane {lane!r} "
-                f"(vocabulary: {', '.join(sorted(LANES))})"
-            )
-        if lane == "loop_other":
-            raise ValueError(
-                "loop_other is DERIVED (the uncovered gap inside a boxcar "
-                "round) — summarize()/chrome_trace() synthesize it; "
-                "recording it directly would double-count the tax"
-            )
+        _check_lane(lane)
         iv = Interval(0, lane, t0, t1, boxcar, rows)
         with self._lock:
             iv.iid = self._next
@@ -394,10 +430,10 @@ class Profiler:
 # explicit reset for tests).
 PROFILER = Profiler()
 
-# Hot-path gate: a plain module global read by every producer site. False
-# short-circuits before any timestamp pairing or Interval allocation —
-# the counting-shim test pins zero-alloc. Disarmed by default: the
-# profiler is an on-demand instrument, not standing instrumentation.
+# The ring's gate: a plain module global. False short-circuits before any
+# Interval allocation or lock — the counting-shim test pins it. Disarmed
+# by default: the timeline is an on-demand instrument; the lane totals
+# and the trace annotations are the standing ones.
 _ON = False
 
 
@@ -455,15 +491,110 @@ def disarm() -> None:
     _ON = False
 
 
+# ---------------------------------------------------------------------------
+# Lane totals + spans: the always-on half of the one record site.
+
+#: lane -> [count, seconds, own seconds]. Always on; plain adds, no lock:
+#: a lane is written by one thread (the serving loop's lanes by the
+#: loop; an off-loop span hands its floats back — ``span(commit=False)``).
+_TOTALS: Dict[str, List[float]] = {
+    lane: [0, 0.0, 0.0] for lane in LANES if lane != "loop_other"
+}
+_TRACE_NAMES: Dict[str, str] = {lane: f"fluid.{lane}" for lane in _TOTALS}
+_OPEN = threading.local()  # .span: the innermost open span of this thread
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _commit(
+    lane: str, t0: float, t1: float, own: float, boxcar: int, rows: int,
+) -> None:
+    tot = _TOTALS.get(lane)
+    if tot is None:
+        _check_lane(lane)  # raises: unknown, or the derived loop_other
+    tot[0] += 1
+    tot[1] += t1 - t0
+    tot[2] += own
+    if _ON:
+        PROFILER.record(lane, t0, t1, boxcar=boxcar, rows=rows)
+
+
 def record(
     lane: str, t0: float, t1: float, boxcar: int = -1, rows: int = 0,
 ) -> None:
-    """Record one interval on the process profiler (producers gate on
-    :data:`_ON` BEFORE taking any extra work; this re-check makes direct
-    calls safe too)."""
-    if not _ON:
-        return
-    PROFILER.record(lane, t0, t1, boxcar=boxcar, rows=rows)
+    """Record one interval that is not a ``with`` block (it starts in
+    one call and ends in another: ``feed_wait``, ``device_step``,
+    ``loop_lag``, ``gc_pause``, ``read_wait``): the lane totals always,
+    the ring while armed, no trace annotation."""
+    _commit(lane, t0, t1, t1 - t0, boxcar, rows)
+
+
+class _Span:
+    """One bracketed seam (see :func:`span`). ``t0``/``t1`` are the two
+    clock reads — callers derive their own counters from them instead
+    of reading the clock again; ``rows`` may be set inside the block
+    when the count is only known there (the ring's interval carries the
+    final value, the trace annotation the one given on entry)."""
+
+    __slots__ = ("lane", "boxcar", "rows", "t0", "t1", "inner", "_hold",
+                 "_ann", "_outer")
+
+    def __init__(self, lane: str, boxcar: int, rows: int, commit: bool):
+        name = _TRACE_NAMES.get(lane)
+        if name is None:
+            _check_lane(lane)  # raises, as record() does
+        self.lane, self.boxcar, self.rows = lane, boxcar, rows
+        self.inner = 0.0  # seconds covered by spans opened inside
+        self._hold = not commit
+        self._ann = (
+            _ANNOTATION(name, boxcar=boxcar, rows=rows) if boxcar >= 0
+            else _ANNOTATION(name)
+        )
+
+    def __enter__(self) -> "_Span":
+        self._outer = getattr(_OPEN, "span", None)
+        _OPEN.span = self
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        _OPEN.span = outer = self._outer
+        dur = t1 - self.t0
+        if outer is not None:
+            outer.inner += dur
+        if not self._hold:
+            _commit(self.lane, self.t0, t1, dur - self.inner, self.boxcar,
+                    self.rows)
+
+
+def span(
+    lane: str, boxcar: int = -1, rows: int = 0, commit: bool = True,
+) -> _Span:
+    """THE record site of a seam that is a ``with`` block: two
+    ``perf_counter()`` reads, a ``jax.profiler.TraceAnnotation`` named
+    ``fluid.<lane>`` (the device trace's clock), the lane's totals
+    (count, seconds, and OWN seconds — less what spans opened inside it
+    covered, so a stage that triggers a device feed is not charged the
+    feed) and, only while armed, the same two floats as an
+    :class:`Interval`. ``commit=False`` is for a span on another thread
+    than its lane's owner: it annotates and reads the clock, and the
+    owner passes ``t0``/``t1`` to :func:`record`. An unknown lane
+    raises."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _Span(lane, boxcar, rows, commit)
+
+
+def totals() -> Dict[str, tuple]:
+    """``{lane: (count, seconds, own_seconds)}`` since the process
+    started (or :func:`reset`): monotone, always on — what a benchmark
+    reader takes before and after a window."""
+    return {lane: tuple(tot) for lane, tot in _TOTALS.items()}
 
 
 def intervals() -> List[Interval]:
@@ -489,6 +620,8 @@ def render() -> str:
 def reset() -> None:
     PROFILER.reset()
     disarm()
+    for tot in _TOTALS.values():
+        tot[:] = (0, 0.0, 0.0)
     _GC_T0.clear()
     del _GC_PENDING[:]
 
@@ -567,8 +700,8 @@ def _gc_callback(phase: str, info: dict) -> None:
 
 
 def drain_gc_events() -> int:
-    """Fold buffered collector pauses into the metric families (and the
-    ``gc_pause`` timeline lane while a capture is armed). Runs in
+    """Fold buffered collector pauses into the metric families and the
+    ``gc_pause`` lane (its totals; the timeline while a capture is armed). Runs in
     NORMAL code — a collection triggering mid-drain just appends to the
     pending list again. Returns how many pauses drained."""
     n = 0
@@ -579,8 +712,7 @@ def drain_gc_events() -> int:
             break
         gc_pause_histogram().observe((t1 - t0) * 1e3)
         gc_pause_counter().inc(gen=str(gen))
-        if _ON:
-            PROFILER.record("gc_pause", t0, t1)
+        record("gc_pause", t0, t1)
         n += 1
     return n
 

@@ -137,6 +137,13 @@ def test_fused_sparse_step_compiles_at_base_tier(
         _i32((b,), one_chip),
     ).compile()
     _assert_mosaic(compiled)
+    # The names the benchmark's trace reduction finds the step by: the
+    # program on the module line, the kernel's instruction, the scopes.
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_fluid_step")
+    assert "%apply_ops_packed" in text
+    assert "jit(fluid_step)/scatter/" in text
+    assert "jit(fluid_step)/apply/" in text
 
 
 @pytest.mark.parametrize("cap", [128, 256])
@@ -145,7 +152,10 @@ def test_pallas_compact_compiles_at_its_tiers(
 ):
     assert cap <= fleet._PALLAS_COMPACT_MAX_CAP
     entry = fleet._compact_entry.__wrapped__(cap, "pallas", None)
-    _assert_mosaic(entry.lower(_state(1024, cap, one_chip)).compile())
+    compiled = entry.lower(_state(1024, cap, one_chip)).compile()
+    _assert_mosaic(compiled)
+    assert compiled.as_text().startswith("HloModule jit_fluid_compact")
+    assert "%compact_packed" in compiled.as_text()
 
 
 def test_fused_apply_compact_compiles_at_headline_shape(

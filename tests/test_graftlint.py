@@ -11,6 +11,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -1354,6 +1356,30 @@ def test_vocab_drift_flags_undeclared_profiler_lane(tmp_path):
     )
     assert len(findings) == 1
     assert "undeclared profiler lane 'device_wait'" in findings[0].message
+
+
+@pytest.mark.parametrize(
+    "lane,flagged", [("device_stage", False), ("device_wait", True)]
+)
+def test_vocab_drift_checks_the_lane_of_a_span(tmp_path, lane, flagged):
+    """``profiler.span("<lane>")`` is a producer site like
+    ``profiler.record``: its lane must be declared, and counts as used."""
+    findings = _run_pass(
+        _vocab_pass(),
+        f"""
+        from fluidframework_tpu.telemetry import profiler
+
+        def sweep(runner):
+            with profiler.span("{lane}"):
+                return runner.pump()
+        """,
+        tmp_path,
+    )
+    assert [f.message for f in findings if "profiler lane" in f.message] == (
+        [f"undeclared profiler lane '{lane}' — declare it in "
+         "telemetry/profiler.py LANES (unknown names raise at runtime, "
+         "but only when the branch runs)"] if flagged else []
+    )
 
 
 def test_vocab_drift_flags_non_literal_kind(tmp_path):

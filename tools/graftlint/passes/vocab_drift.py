@@ -15,7 +15,8 @@ drift fails lint in either direction:
 
 - ``journal.record("<kind>", …)`` / ``JOURNAL.record`` — kind must be a
   string literal in ``journal.EVENTS``;
-- ``profiler.record("<lane>", …)`` / ``PROFILER.record`` — lane must be
+- ``profiler.record("<lane>", …)`` / ``PROFILER.record`` /
+  ``profiler.span("<lane>", …)`` — lane must be
   a string literal in ``profiler.LANES`` (``config.DERIVED_LANES`` are
   synthesized by read surfaces and exempt from the dead-entry check);
 - ``tracing.stamp(traces, <stage>, …)`` — a literal stage must be in
@@ -209,13 +210,16 @@ class VocabDriftPass:
                     v.used_sites.add(node.args[0].value)
                 continue
             # journal.record("<kind>", …) / profiler.record("<lane>", …)
-            if fname == "record":
+            # / profiler.span("<lane>", …)
+            if fname in ("record", "span"):
                 table = None
                 used = None
                 what = where = ""
-                if recv in ("journal", "JOURNAL") or (
-                    isinstance(f, ast.Name) and is_journal_mod
-                ) or (recv == "self" and is_journal_mod):
+                if fname == "record" and (
+                    recv in ("journal", "JOURNAL") or (
+                        isinstance(f, ast.Name) and is_journal_mod
+                    ) or (recv == "self" and is_journal_mod)
+                ):
                     table, used = v.events, v.used_events
                     what, where = "journal event kind", "telemetry/journal.py EVENTS"
                 elif recv in ("profiler", "PROFILER") or (
