@@ -525,6 +525,14 @@ def counters_now(srv, readers=()) -> dict:
     return c
 
 
+def aot_keys() -> set:
+    """The shape keys of the step and compaction programs built so far:
+    which of them a window built is the difference of two of these."""
+    from fluidframework_tpu.parallel import aot
+
+    return {str(key) for key in aot._ENTRIES}
+
+
 def delta(after: dict, before: dict) -> dict:
     return {k: after[k] - before[k] for k in after}
 

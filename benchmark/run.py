@@ -132,6 +132,7 @@ def main(argv=None) -> int:
         )
         setup = ctx.meter.snapshot()
         before = H.counters(state.srv, readers.values())
+        built = H.aot_keys()
         setup_s = time.time() - T_START
         res = kind.run(ctx, state, args.seconds, tracer)
         after = H.counters(state.srv, readers.values())
@@ -144,6 +145,7 @@ def main(argv=None) -> int:
             "window", seconds=res["window_s"], setup_s=setup_s,
             compiles_in_window=window["compiles"] - setup["compiles"],
             aot_builds_in_window=ctx.window["aot_builds"],
+            aot_keys_built_in_window=sorted(H.aot_keys() - built),
             migrations_in_window=ctx.window["migrations"],
             setup_compile_s=setup["compile_s"],
             setup_cache_hits=setup["cache_hits"],

@@ -1,9 +1,11 @@
 """The layer readers that read the program's lane totals and counters
 (PR 27): each gives a number on the rehearsal cells with ``--trace 1``,
 reads nothing (and does not raise) on a program without the counters,
-and the trace reduction is pinned on traces that hold the program's
-``fluid.*`` spans: hand-made events, and two 0.3 s slices recorded on a
-TPU v5 lite (one traced run of each cell, PR 27, ``tools/trace_slice.py``).
+and the trace reduction's split of the device's idle gaps among the host
+spans that cover them (PR 28) is pinned on traces that hold the program's
+``fluid.*`` spans: hand-made events, two 0.3 s slices recorded on a TPU v5
+lite before an event kept its thread (one traced run of each cell, PR 27,
+``tools/trace_slice.py``), and one of the ws cell that keeps it (PR 28).
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests/test_lane_readers.py -q
 
@@ -196,39 +198,50 @@ def test_setup_readers_keep_the_first_snapshot(monkeypatch):
 # -- the trace reduction on traces that hold fluid.* spans -------------------
 
 
+def gaps_of(events) -> dict:
+    return dict(map(tuple, T.reduce(events)["idle_by_span"]))
+
+
 def test_gap_outside_any_bench_span_is_charged_to_the_fluid_span():
     """The ws cell's case: nothing of the benchmark encloses the server's
-    loop, so a gap goes to the program's span that covers it best."""
+    loop, so a gap goes to the program's spans that cover it, each the
+    instants it is innermost for."""
     events = [
         ev("apply_ops_packed.1", 1000, 500), ev("apply_ops_packed.1", 3000, 500),
         host("fluid.socket_out", 1600, 1300),
         host("fluid.deli", 2950, 40),
         host("np.asarray(jax.Array)", 900, 550),
     ]
-    gaps = dict(map(tuple, T.reduce(events)["idle_gaps"]))
-    assert gaps["fluid.socket_out"] == 1500 / 1e9  # the whole 1500..3000
+    # The trace starts at 900: idle 900..1000 lies under np.asarray;
+    # 1500..1600, 2900..2950 and 2990..3000 under no span.
+    assert gaps_of(events) == pytest.approx({
+        "fluid.socket_out": 1300 / 1e9, "fluid.deli": 40 / 1e9,
+        "np.asarray(jax.Array)": 100 / 1e9, T.NOTHING: (100 + 50 + 10) / 1e9,
+    })
 
 
-def test_enclosing_bench_span_still_beats_the_stage_spans_inside_it():
-    """What ``_host_activity`` does TODAY under an enclosing ``bench.*``
-    span: it charges a whole gap to ONE span by cover squared over
-    duration, so an enclosing span of about the gap's length wins over
-    every stage span inside it, whatever its docstring says. The
-    `benchmark` issue that splits a gap among the innermost spans changes
-    this test; ``tools/gap_lanes.py`` already does the split."""
+def test_enclosing_bench_span_yields_to_the_stage_spans_inside_it():
+    """Under an enclosing ``bench.*`` span of about the gap's length the
+    gap is divided among the stage spans inside it; the enclosing span is
+    left the instants between them."""
     events = [
         ev("apply_ops_packed.1", 0, 1000), ev("apply_ops_packed.1", 2400, 1000),
         host("bench.submit_frames_bulk", 1000, 1500),
         host("fluid.front_door", 1010, 300), host("fluid.deli", 1320, 500),
         host("fluid.scriptorium", 1830, 200), host("fluid.device_stage", 2040, 350),
     ]
-    gaps = dict(map(tuple, T.reduce(events)["idle_gaps"]))
-    assert gaps == {"bench.submit_frames_bulk": 1400 / 1e9}
-    split = gap_lanes.split(events, gap_lanes.idle_gaps(events))
-    assert split["fluid.deli"] == pytest.approx(500 / 1e9)
-    assert split["fluid.front_door"] == pytest.approx(300 / 1e9)
-    assert split["bench.submit_frames_bulk"] == pytest.approx(50 / 1e9)
+    r = T.reduce(events)
+    split = dict(map(tuple, r["idle_by_span"]))
+    assert split == pytest.approx({
+        "fluid.deli": 500 / 1e9, "fluid.device_stage": 350 / 1e9,
+        "fluid.front_door": 300 / 1e9, "fluid.scriptorium": 200 / 1e9,
+        "bench.submit_frames_bulk": 50 / 1e9,
+    })
     assert sum(split.values()) == pytest.approx(1400 / 1e9)
+    assert r["idle_gaps"] == r["idle_by_span"]  # five names: none cut off
+    assert r["idle_gaps"][0][0] == "fluid.deli"
+    # The tool prints the reduction's own split.
+    assert gap_lanes.report(events)["idle_by_span"] == r["idle_by_span"]
 
 
 def test_gap_split_takes_the_innermost_span_and_names_what_none_covers():
@@ -237,20 +250,71 @@ def test_gap_split_takes_the_innermost_span_and_names_what_none_covers():
         host("fluid.read_settle", 150, 600), host("fluid.deli", 200, 100),
         host("fluid.scan_consume", 400, 300),
         host("np.asarray(jax.Array)", 420, 250),
-        host("fluid.read_transfer", 0, 5000, line="executor"),  # another thread
     ]
-    assert gap_lanes.loop_line(events) == (HOST, LOOP)
-    gaps = gap_lanes.idle_gaps(events)
-    assert gaps == [(100, 1100), (1200, 5000)]
-    split = gap_lanes.split(events, gaps[:1])
-    assert split == pytest.approx({
-        "(no span)": 400 / 1e9, "fluid.read_settle": 200 / 1e9,
-        "fluid.deli": 100 / 1e9, "fluid.scan_consume": 50 / 1e9,
-        "np.asarray(jax.Array)": 250 / 1e9,
-    })
+    hosts = [e for e in events if e.plane == HOST]
+    assert T.loop_thread(hosts) == (HOST, LOOP, None)
+    assert T.innermost(hosts) == [
+        (150, 200, "fluid.read_settle"), (200, 300, "fluid.deli"),
+        (300, 400, "fluid.read_settle"), (400, 420, "fluid.scan_consume"),
+        (420, 670, "np.asarray(jax.Array)"), (670, 700, "fluid.scan_consume"),
+        (700, 750, "fluid.read_settle"),
+    ]
+    assert T.split_gaps(hosts, [(100, 1100)]) == {
+        T.NOTHING: 400, "fluid.read_settle": 200, "fluid.deli": 100,
+        "fluid.scan_consume": 50, "np.asarray(jax.Array)": 250,
+    }
     rep = gap_lanes.report(events)
+    assert rep["loop_thread"] == (HOST, LOOP, None)
     assert rep["spans"]["fluid.deli"] == [1, 100 / 1e9]
-    assert rep["fluid_spans_inside_bench_submit_frames_bulk"] == [0, 4]
+    assert rep["fluid_spans_inside_bench_submit_frames_bulk"] == [0, 3]
+
+
+def test_two_threads_over_one_gap_the_loop_is_asked_first():
+    """An executor thread's span and the feeder's wait both overlap the
+    whole gap; each takes only the instants the loop thread has no span
+    for, the one that started last first. The loop thread is the line
+    with the most ``fluid.*`` spans, told from the others by ``thread``
+    alone: every Python thread's line is named ``python3``."""
+    def on(thread, name, start, dur):
+        return T.Event(HOST, LOOP, name, start, dur, thread)
+
+    events = [
+        ev("k", 0, 100), ev("k", 1100, 100),
+        on(7, "fluid.socket_out", 150, 300), on(7, "fluid.deli", 200, 100),
+        on(7, "fluid.read_gather", 600, 100),
+        on(3, "fluid.read_transfer", 250, 700),    # executor: 250..950
+        on(1, "bench.wait_front_door", 0, 1200),   # the feeder's thread
+    ]
+    hosts = [e for e in events if e.plane == HOST]
+    assert T.loop_thread(hosts) == (HOST, LOOP, 7)
+    assert gaps_of(events) == pytest.approx({
+        "fluid.socket_out": 200 / 1e9, "fluid.deli": 100 / 1e9,
+        "fluid.read_gather": 100 / 1e9,
+        # 450..600 and 700..950: the loop is in no span
+        "fluid.read_transfer": (150 + 250) / 1e9,
+        # 100..150 and 950..1100: nobody else is
+        "bench.wait_front_door": (50 + 150) / 1e9,
+    })
+    # With the thread forgotten the three lines are one and whatever
+    # started last wins: the executor's span, begun inside ``deli``, is
+    # taken for nested in the loop's spans and robs them (250..600).
+    merged = gaps_of([e._replace(thread=None) for e in events])
+    assert merged["fluid.read_transfer"] == pytest.approx((350 + 250) / 1e9)
+    assert merged["fluid.deli"] == pytest.approx(50 / 1e9)
+    # No thread in any span: the rest of the gap has its own name.
+    alone = gaps_of(events[:5])
+    assert alone[T.NOTHING] == pytest.approx((50 + 150 + 400) / 1e9)
+
+
+def test_an_event_carries_its_thread_through_a_recording(tmp_path):
+    path = str(tmp_path / "slice.json.gz")
+    events = [T.Event(HOST, LOOP, "fluid.deli", 5, 7, 3), ev("k", 0, 100)]
+    T.dump_json(events, path)
+    assert T.load_json(path) == events and T.load_json(path)[0].thread == 3
+    assert T.load_json(path)[1].thread is None
+    # A recording of five fields an event (PR 26, PR 27) loads as it is.
+    old = T.load_json(os.path.join(DATA, "trace_ingest_fluid_slice.json.gz"))
+    assert {e.thread for e in old} == {None}
 
 
 def test_step_glue_is_the_step_program_less_its_kernel():
@@ -278,42 +342,127 @@ def test_step_glue_is_the_step_program_less_its_kernel():
 
 
 def test_recorded_ws_trace_charges_gaps_to_the_programs_spans():
+    """The ws cell: nothing encloses the loop, which waits for traffic
+    nearly half of the idle time, in no span. ``fluid.read_transfer``, an
+    executor thread's wait that merely overlaps gaps, is left what the
+    loop's own spans do not cover, a thousandth of the idle time."""
     events = T.load_json(os.path.join(DATA, "trace_ws_fluid_slice.json.gz"))
     r = T.reduce(events)
+    idle = r["window_s"] - r["busy_s"]
+    split = dict(map(tuple, r["idle_by_span"]))
+    assert sum(split.values()) == pytest.approx(idle, rel=1e-6)
     names = [name for name, _ in r["idle_gaps"]]
-    assert names[0] == "fluid.socket_out"
-    assert {"fluid.read_transfer", "fluid.read_gather", "fluid.ring_put",
-            "fluid.scan_consume"} <= set(names)
-    assert not any(name.startswith("bench.") for name in names)
+    assert names[:3] == [T.NOTHING, "np.asarray(jax.Array)", "fluid.socket_out"]
+    assert 0.4 * idle < split[T.NOTHING] < 0.5 * idle
+    assert 0.1 * idle < split["fluid.socket_out"] < 0.15 * idle
+    assert "fluid.read_transfer" not in names
+    assert 0 < split["fluid.read_transfer"] < 0.005 * idle
+    assert {"fluid.dispatch", "fluid.host_stage", "fluid.deli",
+            "fluid.ring_put", "fluid.scan_consume"} <= set(names)
+    assert not any(name.startswith("bench.") for name in split)
     rep = gap_lanes.report(events)
+    assert rep["idle_by_span"] == r["idle_by_span"]
     assert rep["fluid_spans_inside_bench_submit_frames_bulk"][0] == 0
-    assert rep["idle_s"] == pytest.approx(r["window_s"] - r["busy_s"], rel=1e-6)
+    assert rep["idle_s"] == pytest.approx(idle)
     # Every stage and read lane is on the trace, the sweeps many times.
     for lane in (*lanes.PIPELINE, *read_host_ms.LANES, "read_transfer",
                  "host_stage", "ring_put", "dispatch", "scan_consume"):
         assert rep["spans"][f"fluid.{lane}"][0] >= 1, lane
 
 
-def test_recorded_ingest_trace_one_span_takes_a_whole_gap():
+def test_recorded_ingest_trace_a_gap_is_split_among_the_stages():
     """The ingest cell as it is: ``bench.submit_frames_bulk`` encloses a
-    whole batch (93 ms, most of it the blocked wait for the step), far
-    longer than the 14 ms gap, so the reducer's score gives the gap to
-    ``fluid.deli`` — all of it, though deli covers a third. The split is
-    ``gap_lanes``'s; the stage spans lie inside the benchmark's span of
-    the same trace (one clock, no offset)."""
+    whole batch (93 ms, most of it the blocked wait for the step) and the
+    stage spans lie inside it (one clock, no offset). Of the 14 ms the
+    device idles a batch deli has the largest part, between a quarter and
+    a third, and the device
+    stage, the readback's tail, the feeder's turn-round, the front door,
+    scriptorium and the broadcaster are each named."""
     events = T.load_json(os.path.join(DATA, "trace_ingest_fluid_slice.json.gz"))
     r = T.reduce(events)
-    gaps = dict(map(tuple, r["idle_gaps"]))
     idle = r["window_s"] - r["busy_s"]
-    assert r["idle_gaps"][0][0] == "fluid.deli" and gaps["fluid.deli"] > 0.8 * idle
-    assert "bench.submit_frames_bulk" not in gaps
-    rep = gap_lanes.report(events)
-    split = dict(rep["idle_by_innermost_span"])
+    split = dict(map(tuple, r["idle_by_span"]))
+    assert r["idle_gaps"][0][0] == "fluid.deli"
     assert 0.2 * idle < split["fluid.deli"] < 0.4 * idle
-    for lane in ("front_door", "scriptorium", "broadcast", "device_stage"):
-        assert split[f"fluid.{lane}"] > 0, lane
+    assert max(split.values()) < 0.5 * idle
+    named = {name for name, _ in r["idle_gaps"]}
+    assert {"fluid.deli", "fluid.device_stage", "np.asarray(jax.Array)",
+            "bench.wait_front_door", "fluid.front_door", "fluid.scriptorium",
+            "fluid.broadcast", T.NOTHING} <= named
+    # The enclosing span keeps only the instants between the stages.
+    assert split["bench.submit_frames_bulk"] < 0.01 * idle
     assert sum(split.values()) == pytest.approx(idle, rel=1e-6)
+    rep = gap_lanes.report(events)
+    assert rep["idle_by_span"] == r["idle_by_span"]
     inside, of = rep["fluid_spans_inside_bench_submit_frames_bulk"]
     assert inside == of == 45
     # The step program less its kernel, per step: three steps in the slice.
     assert step_glue_ms.glue_ms(events) == pytest.approx(9.106, abs=0.01)
+
+
+# -- recorded with the thread kept (0.3 s of one traced run a cell, PR 28) ---
+
+
+def device_gaps(events) -> list:
+    """The idle intervals of the device plane, the slow way."""
+    spans = [e for e in events if e.dur_ns > 0]
+    busy = T.union((e.start_ns, e.start_ns + e.dur_ns) for e in spans
+                   if e.plane == DEV and e.line == OPS)
+    edges = [min(e.start_ns for e in spans)]
+    edges += [t for lo, hi in busy for t in (lo, hi)]
+    edges.append(max(e.start_ns + e.dur_ns for e in spans))
+    return [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+
+
+def test_recorded_ws_trace_an_executors_span_takes_only_what_the_loop_leaves():
+    events = T.load_json(os.path.join(DATA, "trace_ws_threads_slice.json.gz"))
+    hosts = [e for e in events if e.plane == HOST and e.dur_ns > 0]
+    loop = T.loop_thread(hosts)
+    executors = {(e.plane, e.line, e.thread) for e in hosts
+                 if e.name == "fluid.read_transfer"}
+    # Three Python threads, one line name: only ``thread`` tells them apart.
+    assert loop[:2] == (HOST, LOOP) and len(executors) == 2
+    assert loop not in executors and {k[:2] for k in executors} == {loop[:2]}
+    r = T.reduce(events)
+    split = dict(map(tuple, r["idle_by_span"]))
+    idle = r["window_s"] - r["busy_s"]
+    assert sum(split.values()) == pytest.approx(idle, rel=1e-6)
+    assert r["idle_gaps"][0][0] == T.NOTHING and split[T.NOTHING] > 0.5 * idle
+    # The instants of the gaps the loop thread has no span for, and of
+    # those the executors' waits cover: the most they can be charged.
+    gaps = sorted(device_gaps(events), key=lambda g: g[0] - g[1])
+    gaps = sorted(gaps[:T.ATTRIBUTED_GAPS])
+    on_loop = [e for e in hosts if (e.plane, e.line, e.thread) == loop]
+    left = T.charge(gaps, T.innermost(on_loop), {})
+    most: dict = {}
+    T.charge(left, T.innermost(
+        e for e in hosts if e.name == "fluid.read_transfer"), most)
+    assert 0 < split["fluid.read_transfer"] * 1e9 <= most["fluid.read_transfer"]
+    whole = sum(e.dur_ns for e in hosts if e.name == "fluid.read_transfer")
+    assert split["fluid.read_transfer"] * 1e9 < 0.02 * whole  # 0.15 of 15 ms
+
+
+def test_recorded_ingest_trace_the_feeders_thread_is_told_from_the_loops():
+    events = T.load_json(os.path.join(DATA, "trace_ingest_threads_slice.json.gz"))
+    hosts = [e for e in events if e.plane == HOST and e.dur_ns > 0]
+    loop = T.loop_thread(hosts)
+    feeder, = {(e.plane, e.line, e.thread) for e in hosts
+               if e.name == "bench.wait_front_door"}
+    assert feeder != loop and feeder[:2] == loop[:2] == (HOST, LOOP)
+    r = T.reduce(events)
+    split = dict(map(tuple, r["idle_by_span"]))
+    idle = r["window_s"] - r["busy_s"]
+    assert r["idle_gaps"][0][0] == "fluid.deli"
+    assert 0.2 * idle < split["fluid.deli"] < 0.4 * idle
+    assert max(split.values()) < 0.5 * idle
+    assert {"fluid.device_stage", "np.asarray(jax.Array)", "fluid.front_door",
+            "fluid.scriptorium", "fluid.broadcast", "bench.wait_front_door",
+            "bench.submit_frames_bulk"} <= {n for n, _ in r["idle_gaps"]}
+    # The feeder waits for the front door all through a batch; it is
+    # charged the turn-round between batches alone. With the thread
+    # forgotten its span, begun 0.7 ms into the loop's, robs the stages.
+    assert split["bench.wait_front_door"] < 0.05 * idle
+    merged = T.reduce([e._replace(thread=None) for e in events])
+    assert dict(map(tuple, merged["idle_by_span"]))[
+        "bench.wait_front_door"] > 3 * split["bench.wait_front_door"]
+    assert step_glue_ms.glue_ms(events) == pytest.approx(9.106, abs=0.02)

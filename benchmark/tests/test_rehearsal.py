@@ -143,9 +143,18 @@ def test_cell_runs_end_to_end(workload, trace):
         assert "breakdown" in last
     else:
         assert set(last["metrics"]) == names["end_to_end"]
+    events = {}
     for ln in lines[:-1]:  # every earlier line names where it ran
         rec = json.loads(ln)
         assert rec["platform"] == "cpu" and "device_kind" in rec and "device_count" in rec
+        events[rec.get("event")] = rec
+    if workload == "rehearsal-ws":
+        # One boxcar of every shape the writers can fill goes through
+        # before they start, so the window builds no step program.
+        warm = events["warm_boxcars"]
+        assert warm["shapes"] == [[1, 2], [2, 2], [4, 2], [2, 9]]
+        assert warm["dispatches"] == 4 and warm["aot_builds"] >= 4
+        assert events["window"]["aot_keys_built_in_window"] == []
 
 
 def _compared(lines):
@@ -235,7 +244,9 @@ def test_control_is_told_apart(workload, seed):
     proc, lines = _run(_argv(workload, seed, 0, "--control", "1"))
     assert proc.returncode == 0, proc.stderr[-2000:]
     control = [json.loads(ln) for ln in lines if '"control"' in ln]
-    assert control and control[0]["told_apart"] == control[0]["documents"]
+    # All but one at most: the last op of a document with several writers
+    # can be a remove of what a concurrent remove already took.
+    assert control and control[0]["told_apart"] >= control[0]["documents"] - 1
     for c in control[1:]:  # the ws kind's second control, over the reads
         assert c["told_apart"] >= c["needed"]
 

@@ -8,6 +8,9 @@ code and the wall seconds, to ``chiprun_out/<label>/results.jsonl``.
     python3 benchmark/tools/runs.py --label ingest-a --workload h100k-ingest-zipf \\
         --seeds 101,102,103 --seconds 20 --trace 0 [-- extra arguments of run.py]
 
+``--burners N`` keeps N busy-looping processes beside every run: a stand-in
+for a neighbour on a shared host, to see how far a cell's numbers move.
+
 How the spreads, the knee sweep and the seeds of PERF.md were measured.
 """
 
@@ -30,6 +33,7 @@ def main() -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", required=True)
     ap.add_argument("--trace", default="0")
+    ap.add_argument("--burners", type=int, default=0)
     ap.add_argument("extra", nargs="*")
     args = ap.parse_args()
     # Results go where the caller stands; the runs are made in the
@@ -44,11 +48,21 @@ def main() -> int:
             *args.extra,
         ]
         t0 = time.time()
-        with open(os.path.join(out_dir, f"{seed}.log"), "w") as log:
-            proc = subprocess.run(
-                cmd, cwd=ROOT, stdout=subprocess.PIPE, stderr=log, text=True
-            )
-            log.write("\n--- stdout ---\n" + proc.stdout)
+        burners = [
+            subprocess.Popen([sys.executable, "-c", "while True: pass"])
+            for _ in range(args.burners)
+        ]
+        try:
+            with open(os.path.join(out_dir, f"{seed}.log"), "w") as log:
+                proc = subprocess.run(
+                    cmd, cwd=ROOT, stdout=subprocess.PIPE, stderr=log, text=True
+                )
+                log.write("\n--- stdout ---\n" + proc.stdout)
+        finally:
+            for b in burners:
+                b.kill()
+            for b in burners:
+                b.wait()
         lines = [ln for ln in proc.stdout.strip().splitlines() if ln.strip()]
         try:
             last = json.loads(lines[-1]) if lines else None
@@ -57,7 +71,7 @@ def main() -> int:
         rec = {
             "seed": int(seed), "rc": proc.returncode,
             "wall_s": round(time.time() - t0, 2), "result": last,
-            "extra": args.extra,
+            "extra": args.extra, "burners": args.burners,
         }
         with open(os.path.join(out_dir, "results.jsonl"), "a") as f:
             f.write(json.dumps(rec) + "\n")

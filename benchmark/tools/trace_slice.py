@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """A short piece of a run's profiler trace as plain events: how the
-recorded trace of ``benchmark/tests/data`` was made.
+recorded traces of ``benchmark/tests/data`` were made. An event is written
+with its thread (six fields; the recordings of PR 26 and PR 27 hold five).
 
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace 1
     python3 benchmark/tools/trace_slice.py <cell> <seconds> <out.json.gz>

@@ -125,10 +125,49 @@ def _tell(st, **cmd) -> None:
         proc.stdin.flush()
 
 
+def warm_boxcars(ctx, st) -> None:
+    """One boxcar of each shape of the mix's ``warm_boxcars``, before the
+    writers start.
+
+    A step's program is built per boxcar shape ``[B, K]``: B the documents
+    of the boxcar and K the most ops of one of them, each rounded up to a
+    power of two (K from 8). The schedule below meets the smallest shapes
+    only; the window then builds the next one on the serving loop the
+    first time a few frames pile up: 0.25 s when the deadline ticker meets
+    it, 3.1 s when the flush that a read forces does, and more on a busy
+    host (PERF.md section 6, PR 28). So the loader sends ``[documents,
+    ops]`` of each listed shape to documents nobody else writes or
+    compares, one batch a boxcar, and leaves each to the ticker."""
+    f, dev = st.feeder, st.srv.service.device
+    taken = np.concatenate([st.ws_docs, st.watch])
+    spare = np.setdiff1d(np.arange(len(f.doc_ids)), taken)
+    spare = np.random.default_rng([ctx.seed, 5]).permutation(spare)
+    before = H.on_loop(st.srv, lambda: H.counters_now(st.srv))
+    at, by_flush = 0, 0
+    for n, k in ctx.params["warm_boxcars"]:
+        sent = H.on_loop(st.srv, lambda: dev.pump_dispatches)
+        f.land(f.build(np.sort(spare[at:at + int(n)]), int(k)))
+        give_up = time.monotonic() + 2.0
+        while H.on_loop(st.srv, lambda: dev.pump_dispatches) == sent:
+            if time.monotonic() > give_up:
+                by_flush += 1
+                break
+            time.sleep(0.005)
+        H.settle(st.srv)
+        at += int(n)
+    c = H.delta(H.on_loop(st.srv, lambda: H.counters_now(st.srv)), before)
+    ctx.out.say("warm_boxcars", shapes=ctx.params["warm_boxcars"],
+                aot_builds=c["aot_builds"], dispatches=c["pump_dispatches"],
+                seconds=c["t"], left_to_the_flush=by_flush,
+                reoffers=f.reoffers)
+
+
 def warm(ctx, st) -> None:
-    """Start the schedule and let it run until no program is built any
-    more; concurrent reads of every small batch size warm the gather."""
+    """Every boxcar shape first; then start the schedule and let it run
+    until no program is built any more; concurrent reads of every small
+    batch size warm the gather."""
     p = ctx.params
+    warm_boxcars(ctx, st)
     _tell(st, cmd="go", at=time.monotonic() + 0.3)
     from fluidframework_tpu.drivers.network_driver import NetworkFluidService
 
