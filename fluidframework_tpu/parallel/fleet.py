@@ -81,15 +81,17 @@ _jit_compact = jax.jit(
 _BLOCK_DOCS = 32
 
 
-def _per_shard(fn, sharding, n_args: int):
+def _per_shard(fn, sharding, n_args: int, n_replicated: int = 0):
     """``fn`` run by every device of the pool's mesh on its own slice of
     the slot axis — no collective: documents do not depend on each
-    other."""
+    other. The first ``n_args`` arguments are sliced along the slot axis,
+    the ``n_replicated`` after them reach every device whole."""
     from jax.sharding import PartitionSpec as P
 
     spec = P(sharding.spec[0])
     return jax.shard_map(
-        fn, mesh=sharding.mesh, in_specs=(spec,) * n_args, out_specs=spec,
+        fn, mesh=sharding.mesh,
+        in_specs=(spec,) * n_args + (P(),) * n_replicated, out_specs=spec,
         check_vma=False,  # pallas_call outputs carry no vma info
     )
 
@@ -102,66 +104,66 @@ def _pallas_compact(state: SegmentState) -> SegmentState:
     return pallas_batched_compact(state, block_docs=_BLOCK_DOCS)
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _scatter_rows(rows_b, slots, n_slots):
-    """Inflate a gathered op upload ``[B, K, OP_WIDTH]`` + ``[B]`` slot
-    indices into the dense ``[n_slots, K, OP_WIDTH]`` batch the pool step
-    consumes — ON DEVICE. Only the busy slots' rows cross host→device;
-    non-busy slots read as all-zero NOOP rows from the device-side fill.
-    Padding entries carry slot index ``n_slots`` — out of range, so the
-    scatter drops them (jax's default out-of-bounds scatter mode)."""
-    k = rows_b.shape[1]
-    dense = jnp.zeros((n_slots, k, rows_b.shape[2]), jnp.int32)
-    return dense.at[slots].set(rows_b)
+# The busy-set step pads a pool of fewer slots to this many (Mosaic's and
+# XLA's sublane tile): on the v5e a gather or scatter over an operand of 1,
+# 2 or 4 rows lost updates (PR 29, one chip, against the dense engines).
+_MIN_STEP_SLOTS = 8
 
 
 @functools.lru_cache(maxsize=None)
-def _scatter_fn(sharding):
-    """The scatter above, specialized to land its dense output PRE-SHARDED
-    over the pool's mesh (out_shardings) — without this, a mesh fleet
-    materializes every boxcar's full dense batch on one device and
-    reshards it inside the apply step (code-review r5)."""
-    if sharding is None:
-        return _scatter_rows
+def _fused_sparse_step(kernel: str, sharding):
+    """The busy-set device step, ONE jitted donated entry — the pump's
+    dispatch unit (AOT-compiled per shape bucket by
+    ``_Pool.sparse_step_aot``) and the fault fallback's
+    (``DocFleet.apply_sparse``). ``rows_b [B, K, OP_WIDTH]`` is the
+    boxcar as staged, ``slots [B]`` the pool slot of each row:
 
-    def f(rows_b, slots, n_slots):
-        k = rows_b.shape[1]
-        dense = jnp.zeros((n_slots, k, rows_b.shape[2]), jnp.int32)
-        return dense.at[slots].set(rows_b)
+    1. gather: every lane's ``[B, capacity]`` rows and the five ``[B]``
+       scalars of the boxcar's slots;
+    2. apply: the engine (the Pallas kernel or the vmapped XLA scan) on
+       that ``[B, capacity]`` state and ``rows_b`` as it is;
+    3. scatter: the results back into the DONATED pool state, in place.
 
-    return jax.jit(f, static_argnums=(2,), out_shardings=sharding)
+    The step's cost follows ``B``, not the pool's slot count. A row of
+    padding or of another capacity tier carries slot ``n_slots``, out of
+    range: it is gathered from the last slot (so its ops run on a copy of
+    that document), and its result is dropped by the scatter — an
+    untouched slot's bytes are the same before and after. Slots are UNIQUE within a boxcar
+    (``pump_stage`` stages one row per channel), so neither gather nor
+    scatter needs a combine rule.
 
+    A mesh-sharded pool runs the same body per device under
+    ``shard_map`` with the boxcar replicated: each device subtracts its
+    slice's first slot and treats every slot outside its slice as out of
+    range — no collective."""
+    engine = _pallas_apply if kernel == "pallas" else batched_apply_ops
 
-@functools.lru_cache(maxsize=None)
-def _fused_sparse_step(n_slots: int, kernel: str, sharding):
-    """Scatter + apply fused into ONE jitted donated entry — the pump's
-    dispatch unit. The legacy serving path pays two dispatches per boxcar
-    (``_scatter_fn`` then the pool step); fusing them halves the
-    per-boxcar enqueue count AND lets the whole thing compile to a single
-    AOT executable (``parallel/aot.py``) so a steady-state flush does no
-    tracing and no jit-cache lookup. The pool state (arg 0) is donated:
-    the update happens in place, no defensive copy on the hot call."""
-    if kernel != "pallas":
-        engine = batched_apply_ops
-    elif sharding is not None:
-        engine = _per_shard(_pallas_apply, sharding, 2)
-    else:
-        engine = _pallas_apply
-
-    def fluid_step(state, rows_b, slots):
-        with jax.named_scope("scatter"):
-            k = rows_b.shape[1]
-            dense = jnp.zeros((n_slots, k, rows_b.shape[2]), jnp.int32)
-            dense = dense.at[slots].set(rows_b)
-            if sharding is not None:
-                # Land the dense batch pre-sharded over the pool's mesh
-                # (the _scatter_fn out_shardings rule, expressed as a
-                # constraint inside the fused program).
-                dense = jax.lax.with_sharding_constraint(dense, sharding)
+    def busy_step(state, rows_b, slots):
+        n = state.count.shape[0]
+        if sharding is not None:  # this device's slice begins here
+            slots = slots - jax.lax.axis_index(sharding.spec[0]) * n
+        pad = max(_MIN_STEP_SLOTS - n, 0)
+        if pad:  # a few documents: step a zero-padded copy of the pool
+            state = SegmentState(*[
+                jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
+                for x in state
+            ])
+        # Negative indices would wrap NumPy-style: send them out of range.
+        local = jnp.where((slots >= 0) & (slots < n), slots, n + pad)
+        with jax.named_scope("gather"):
+            at = jnp.minimum(local, n - 1)  # a dropped row reads slot n-1
+            busy = SegmentState(*[x.at[at].get(mode="clip") for x in state])
         with jax.named_scope("apply"):
-            return engine(state, dense)
+            new = engine(busy, rows_b)
+        with jax.named_scope("scatter"):
+            out = [
+                x.at[local].set(y, mode="drop") for x, y in zip(state, new)
+            ]
+        return SegmentState(*[x[:n] for x in out] if pad else out)
 
-    return jax.jit(fluid_step, donate_argnums=(0,))
+    if sharding is not None:
+        busy_step = _per_shard(busy_step, sharding, 1, n_replicated=2)
+    return jax.jit(_program("fluid_step", busy_step), donate_argnums=(0,))
 
 
 # The Pallas compact unrolls log2(capacity) shift steps over every vreg of
@@ -449,12 +451,21 @@ class _Pool:
             self._step = _jit_step
         self._compact = _compact_entry(capacity, kernel, sharding)
 
+    def sparse_step(self, dev_rows, dev_slots) -> None:
+        """The busy-set step through its jitted entry: the fault
+        fallback's and the one-shot flush's call (a recovery path builds
+        no AOT entry)."""
+        self.state = _fused_sparse_step(self.kernel, self.sharding)(
+            self.state, dev_rows, dev_slots
+        )
+
     def sparse_step_aot(self, dev_rows, dev_slots) -> None:
-        """One pump dispatch: scatter + apply through the cached AOT
+        """One pump dispatch: the busy-set step (gather, apply on
+        ``[B, capacity]``, scatter back in place) through the cached AOT
         donated executable for this pool's shape bucket — zero tracing,
         zero jit-cache lookup on the steady-state path. ``dev_rows`` is
         the ring-staged device ``[B, K, OP_WIDTH]`` block (NOT donated:
-        a multi-tier boxcar scatters the same block into several pools);
+        a multi-tier boxcar goes to several pools as it is);
         ``dev_slots`` the per-row slot vector (out-of-range = dropped)."""
         key = (
             "fleet_sparse_step", self.capacity, self.n_slots,
@@ -462,9 +473,7 @@ class _Pool:
         )
         self.state = aot.call(
             key,
-            lambda: _fused_sparse_step(
-                self.n_slots, self.kernel, self.sharding
-            ),
+            lambda: _fused_sparse_step(self.kernel, self.sharding),
             self.state, dev_rows, dev_slots,
         )
 
@@ -599,6 +608,7 @@ class DocFleet:
         self.migrations = 0
         self.demotions = 0
         self.last_routing_s = 0.0
+        self.last_step_docs = 0
 
     def _place_arrays(self) -> Tuple[np.ndarray, np.ndarray]:
         if self._place_dirty:
@@ -663,65 +673,49 @@ class DocFleet:
         self.last_routing_s = routing
         return self.stats()
 
-    def apply_sparse(self, docs, ops_b: np.ndarray) -> dict:
-        """Apply one boxcar staged over BUSY documents only: ``docs`` are
-        external doc ids, ``ops_b [B, K, OP_WIDTH]`` their sequenced rows
-        (row i belongs to docs[i]). The upload is O(busy × K) — the dense
-        ``apply`` path stages and ships O(fleet × K) even when one channel
-        is busy (VERDICT r3 Weak #3); the dense batch the kernels consume
-        is reconstructed on device by ``_scatter_rows``. ``B`` pads to a
-        pow2 bucket (padding rows scatter out of bounds and drop) so the
-        compiled-shape set stays logarithmic in fleet size.
-
-        Routing is pure array work — one cap gather, one membership mask
-        per pool, one fancy-index copy — because at 10k+ busy channels a
-        per-member Python loop IS the serving path's staging cost.
+    def apply_sparse(self, docs, ops_b: np.ndarray) -> None:
+        """Apply one boxcar staged over BUSY documents only, from host
+        rows: ``docs`` are external doc ids, ``ops_b [n, K, OP_WIDTH]``
+        their sequenced rows (row i belongs to docs[i]). The pump's fault
+        fallback and the one-shot flush: the SAME busy-set step as
+        :meth:`dispatch_staged`, called through its jitted entry (no AOT
+        build on a recovery path). ``n`` pads to a pow2 bucket (padding
+        rows route out of range and drop) so the compiled-shape set
+        stays logarithmic in fleet size.
 
         Returns nothing — the dense ``apply``'s stats() return is a FULL
         synchronous per-pool readback, which on the serving path would
         put a device round trip on every boxcar; health rides the async
         ``begin_scan``/``finish_scan`` protocol instead."""
-        k = ops_b.shape[1]
-        routing = 0.0
-        t0 = time.perf_counter()
-        docs = np.asarray(docs, np.int64)
-        cap_arr, slot_arr = self._place_arrays()
-        caps = cap_arr[docs]
-        uniq = np.unique(caps)
-        routing += time.perf_counter() - t0
-        for cap in uniq:
-            pool = self.pools[int(cap)]
-            t0 = time.perf_counter()
-            if uniq.size == 1:
-                members = ops_b
-                mdocs = docs
-            else:
-                sel = caps == cap
-                members = ops_b[sel]
-                mdocs = docs[sel]
-            b = _pow2_at_least(len(mdocs))
-            rows_b = np.zeros((b, k, OP_WIDTH), np.int32)
-            rows_b[: len(mdocs)] = members
-            slots = np.full(b, pool.n_slots, np.int32)  # pad = dropped
-            slots[: len(mdocs)] = slot_arr[mdocs]
-            routing += time.perf_counter() - t0
-            dense = _scatter_fn(pool.sharding)(
-                jnp.asarray(rows_b), jnp.asarray(slots), pool.n_slots
-            )
-            pool.state = pool._step(pool.state, dense)
-        self.last_routing_s = routing
+        n, k = ops_b.shape[:2]
+        rows_b = np.zeros((_pow2_at_least(n), k, OP_WIDTH), np.int32)
+        rows_b[:n] = ops_b
+        self._step_pools(docs, jax.device_put(rows_b), _Pool.sparse_step)
 
     def dispatch_staged(self, docs, dev_rows) -> None:
         """Apply one ring-staged boxcar: ``docs`` are external doc ids,
         ``dev_rows`` their ``[B, K, OP_WIDTH]`` rows ALREADY RESIDENT on
         device (the ingest ring uploaded them asynchronously while the
         previous step computed — only the tiny per-pool slot vectors
-        cross host→device at dispatch time). Row i belongs to docs[i];
-        padding rows (i >= len(docs)) route out of range and drop in the
-        scatter. Placement is resolved HERE, not at stage time, so a
+        cross host→device at dispatch time). Each pool's gather + apply
+        + scatter runs as one cached AOT donated executable over the
+        boxcar's ``B`` rows (``_Pool.sparse_step_aot``), whatever the
+        pool's size."""
+        self._step_pools(docs, dev_rows, _Pool.sparse_step_aot)
+
+    def _step_pools(self, docs, dev_rows, step) -> None:
+        """Route one boxcar to the pools its documents live in and run
+        ``step(pool, dev_rows, dev_slots)`` on each. Row i belongs to
+        docs[i]; in a pool's slot vector a padding row (i >= len(docs)),
+        a row of another tier and a row of a document evicted from the
+        fleet all carry ``n_slots``, out of range, so the step's scatter
+        drops them. Placement is resolved HERE, not at stage time, so a
         promotion consumed from the previous health scan re-routes staged
-        rows to the doc's new pool. Each pool's scatter+apply runs as one
-        cached AOT donated executable (``_Pool.sparse_step_aot``)."""
+        rows to the doc's new pool. Routing is pure array work — one cap
+        gather and one membership mask per pool — because at 10k+ busy
+        channels a per-member Python loop IS the staging cost.
+        ``last_step_docs`` is Σ B over the pools stepped: what the kernel
+        ran over."""
         b = dev_rows.shape[0]
         t0 = time.perf_counter()
         docs = np.asarray(docs, np.int64)
@@ -736,8 +730,9 @@ class DocFleet:
             sel = np.flatnonzero(caps == cap)
             slots[sel] = slot_arr[docs[sel]]
             routing += time.perf_counter() - t0
-            pool.sparse_step_aot(dev_rows, jax.device_put(slots))
+            step(pool, dev_rows, jax.device_put(slots))
         self.last_routing_s = routing
+        self.last_step_docs = b * len(uniq)
 
     def compact_aot(self) -> None:
         """Compact every pool through the cached AOT donated entries —

@@ -225,6 +225,10 @@ class DeviceFleetBackend:
             "staging_s": 0.0, "dispatch_s": 0.0, "routing_s": 0.0,
             "staged_rows": 0,  # padded to the [B, K] bucket
             "real_rows": 0,  # op rows, before padding
+            # Σ over pool dispatches of the B documents the busy-set
+            # step's kernel ran over (padded): against busy documents,
+            # what the pow2 bucket and a multi-tier boxcar cost.
+            "step_docs": 0,
         }
         # The continuous device pump (r10): double-buffered ingest ring +
         # AOT donated dispatch. pump_mode routes flush() through the
@@ -702,9 +706,10 @@ class DeviceFleetBackend:
 
         Staging is GATHERED over busy channels only: the host builds
         ``[B, K]`` for the B channels with buffered rows and the device
-        scatters that into the dense batch the kernels consume — one busy
-        channel in a 100k-channel fleet stages and ships one row, not the
-        fleet (VERDICT r3 Weak #3's O(fleet) boxcar).
+        step gathers those B documents, applies on ``[B, capacity]`` and
+        scatters them back in place — one busy channel in a 100k-channel
+        fleet stages, ships and steps one row, not the fleet (VERDICT r3
+        Weak #3's O(fleet) boxcar; ROADMAP S2's O(pool) step).
 
         Health readbacks are ASYNC and one boxcar stale: each dispatch
         round starts one fused (count, err) pool scan
@@ -803,7 +808,7 @@ class DeviceFleetBackend:
         """The pre-pump serving loop (the pump's parity reference)."""
         newly_errored: List[ChannelKey] = []
         staging_s = dispatch_s = routing_s = 0.0
-        staged_rows = real_rows = 0
+        staged_rows = real_rows = step_docs = 0
         while self._buffers:
             # Consume the PREVIOUS dispatch's health scan before routing
             # this round: promotion (tier moves, sharded-overflow
@@ -862,6 +867,7 @@ class DeviceFleetBackend:
                     (sent.t1 - sent.t0) - self.fleet.last_routing_s
                 )
                 staged_rows += ops_b.shape[0] * k
+                step_docs += self.fleet.last_step_docs
                 self._scan_token = self.fleet.begin_scan()
                 self._scan_bid = bid
                 if jspans:
@@ -891,6 +897,7 @@ class DeviceFleetBackend:
             "routing_s": routing_s,
             "staged_rows": staged_rows,
             "real_rows": real_rows,
+            "step_docs": step_docs,
         }
         for key, value in self.last_flush_breakdown.items():
             self.flush_totals[key] += value
@@ -1131,7 +1138,7 @@ class DeviceFleetBackend:
         """Dispatch the oldest staged ring slot. Order per dispatch:
         (1) consume the PREVIOUS dispatch's health scan — one boxcar
         stale; promotions it carries re-route this slot's docs before the
-        scatter; (2) scatter+apply via the cached AOT donated executables
+        step; (2) the busy-set step via the cached AOT donated executables
         (``DocFleet.dispatch_staged`` — zero tracing, only the tiny slot
         vectors cross the link); (3) begin this boxcar's scan. The scan
         consumption is the pump's ONLY device→host transfer."""
@@ -1187,7 +1194,10 @@ class DeviceFleetBackend:
                 doc.rebalance()  # self-compacts when it triggers
         if compact_now:
             self.fleet.compact_aot()
-        routing = self.fleet.last_routing_s if in_fleet.any() else 0.0
+        routing = 0.0
+        if in_fleet.any():
+            routing = self.fleet.last_routing_s
+            self.flush_totals["step_docs"] += self.fleet.last_step_docs
         self.flush_totals["dispatch_s"] += (
             time.perf_counter() - t0 - routing
         )

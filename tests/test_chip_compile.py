@@ -13,6 +13,7 @@ import, in a ``skipif`` or in ``parametrize``.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,17 +124,25 @@ def test_block_rule_keeps_mosaic_tiling():
         cap *= 2
 
 
+# BASELINE config 5's fleet (131,072 slots x 128 rows) under a full
+# 512-row boxcar, under the small boxcars a websocket partition sends
+# (B = 8 one block, B = 1 and 2 whole-dim blocks; K = 16 as the ws
+# cell's warm_boxcars builds them on its 16,384 slots), and one document
+# of the top tier.
+@pytest.mark.parametrize("n_slots,cap,b,k", [
+    (131072, 128, 512, 8), (131072, 128, 8, 8), (131072, 128, 1, 8),
+    (16384, 128, 32, 16), (16384, 128, 2, 16), (8, 32768, 1, 8),
+])
 def test_fused_sparse_step_compiles_at_base_tier(
-    one_chip, no_persistent_cache, as_on_tpu
+    one_chip, no_persistent_cache, as_on_tpu, n_slots, cap, b, k
 ):
-    """The pump's dispatch unit (scatter + apply, donated) at BASELINE
-    config 5's fleet: 131,072 slots x 128 rows, a 512-row boxcar."""
-    n_slots, b, k = 131072, 512, 8
+    """The pump's dispatch unit (gather, apply on [B, cap], scatter back
+    into the donated pool) at every kind of boxcar shape it meets."""
     # __wrapped__: a fresh jitted entry, not one another test of this
     # worker may have traced in interpret mode.
-    step = fleet._fused_sparse_step.__wrapped__(n_slots, "pallas", None)
+    step = fleet._fused_sparse_step.__wrapped__("pallas", None)
     compiled = step.lower(
-        _state(n_slots, 128, one_chip), _i32((b, k, OP_WIDTH), one_chip),
+        _state(n_slots, cap, one_chip), _i32((b, k, OP_WIDTH), one_chip),
         _i32((b,), one_chip),
     ).compile()
     _assert_mosaic(compiled)
@@ -142,8 +151,23 @@ def test_fused_sparse_step_compiles_at_base_tier(
     text = compiled.as_text()
     assert text.startswith("HloModule jit_fluid_step")
     assert "%apply_ops_packed" in text
-    assert "jit(fluid_step)/scatter/" in text
-    assert "jit(fluid_step)/apply/" in text
+    for scope in ("gather", "apply", "scatter"):
+        assert f"jit(fluid_step)/{scope}/" in text, scope
+    # The kernel runs over the boxcar's B documents, and nothing of the
+    # pool's size is made besides the donated state itself: every lane
+    # is updated in place, there is no dense op batch and no copy of a
+    # lane (a scalar lane's staging through fast memory is [n_slots]).
+    assert f"s32[{N_LANES},{b},{cap}]" in text
+    lanes = N_LANES * n_slots * cap * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= lanes
+    assert mem.temp_size_in_bytes < n_slots * cap * 4
+    assert f"[{n_slots},{k},{OP_WIDTH}]" not in text
+    assert f"s32[{N_LANES},{n_slots},{cap}]" not in text
+    if n_slots > b:
+        assert not re.search(
+            rf"= s32\[{n_slots},{cap}\]\S* copy(-start)?\(", text
+        )
 
 
 @pytest.mark.parametrize("cap", [128, 256])
@@ -195,9 +219,9 @@ def test_mesh_step_has_kernel_and_no_collective(
     )
     found = [c for c in _COLLECTIVES if c in text]
     assert not found, f"collectives in the mesh apply path: {found}"
-    # The pump's fused form: the boxcar arrives replicated, the scatter
-    # lands it sharded, the same kernel applies it.
-    fused = fleet._fused_sparse_step.__wrapped__(n_slots, "pallas", sharding)
+    # The pump's busy-set step: the boxcar arrives replicated, every
+    # device keeps the slots of its own quarter and drops the rest.
+    fused = fleet._fused_sparse_step.__wrapped__("pallas", sharding)
     rep = NamedSharding(mesh, P())
     text = _assert_mosaic(
         fused.lower(

@@ -1,11 +1,16 @@
 """Fleet capacity lifecycle: pooled blocks + host-driven promotion
 (VERDICT r1 #5; reference growth analog mergeTree.ts:1268 updateRoot)."""
 
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from fluidframework_tpu.ops import encode as E
-from fluidframework_tpu.ops.segment_state import materialize
+from fluidframework_tpu.ops.segment_state import SegmentState, materialize
+from fluidframework_tpu.parallel import fleet as F
 from fluidframework_tpu.parallel.fleet import DocFleet
 from fluidframework_tpu.protocol.constants import OP_WIDTH
 from fluidframework_tpu.testing.oracle import OracleDoc
@@ -190,3 +195,212 @@ def test_stale_scan_dropped_for_reassigned_slots():
     # Consuming the stale scan must not re-promote the NEW occupant.
     promoted = fleet.check_and_migrate({c: s[0] for c, s in scans.items()})
     assert d1 not in promoted
+
+
+# -- the busy-set device step (ROADMAP S2) ------------------------------------
+#
+# ``fleet._fused_sparse_step`` gathers the boxcar's documents, applies on
+# [B, capacity] and scatters back in place. Its contract is the dense
+# engine's on the scattered batch, over the WHOLE pool.
+
+_STEP_CAP = 64
+
+
+def _edit_rows(rng, n, k, first_seq):
+    """[n, k, OP_WIDTH] real op rows for documents that hold
+    ``first_seq - 1`` one-character inserts: inserts at random positions,
+    a two-character remove now and then."""
+    rows = np.zeros((n, k, OP_WIDTH), np.int32)
+    for d in range(n):
+        length = first_seq - 1
+        for i in range(k):
+            seq = first_seq + i
+            if length > 3 and rng.random() < 0.3:
+                a = int(rng.integers(0, length - 2))
+                rows[d, i] = E.remove(a, a + 2, seq=seq, ref=seq - 1,
+                                      client=int(rng.integers(0, 4)))
+                length -= 2
+            else:
+                rows[d, i] = E.insert(int(rng.integers(0, length + 1)),
+                                      seq, 1, seq=seq, ref=seq - 1,
+                                      client=int(rng.integers(0, 4)))
+                length += 1
+    return rows
+
+
+def _host(state):
+    return SegmentState(*[np.array(x) for x in state])
+
+
+@functools.lru_cache(maxsize=None)
+def _seeded_pool(kernel, n_slots):
+    """A pool's state after every slot took four real ops, as host
+    numpy (the engines donate: each use device_puts it anew)."""
+    pool = F._Pool(_STEP_CAP, n_slots, kernel)
+    rows = _edit_rows(np.random.default_rng(n_slots), n_slots, 4, 1)
+    return _host(pool._step(pool.state, jnp.asarray(rows)))
+
+
+def _assert_states_equal(got, want, what=""):
+    for name, x, y in zip(SegmentState._fields, got, want):
+        assert np.array_equal(np.asarray(x), np.asarray(y)), (what, name)
+
+
+def _step_cases():
+    """Every kernel, pool size, boxcar bucket and K of the issue, and the
+    pools under one sublane tile (1, 2, 4 slots: the step pads them)."""
+    for kernel in ("xla", "pallas"):
+        for n_slots in (8, 64, 4096):
+            for b in (1, 3, 8, 64):
+                for k in (8, 16):
+                    yield kernel, n_slots, b, k
+        for n_slots in (1, 2, 4):
+            for b in (1, 8):
+                yield kernel, n_slots, b, 8
+
+
+@pytest.mark.parametrize("kernel,n_slots,b,k", list(_step_cases()))
+def test_busy_set_step_equals_dense_engine(kernel, n_slots, b, k):
+    """Lane for lane and scalar for scalar over the whole pool, so an
+    untouched slot is proved untouched. Every row of the padded boxcar
+    carries REAL ops; the rows that are not this pool's (padding, another
+    tier) carry slot ``n_slots`` and must change nothing, also when slot
+    ``n_slots - 1`` (what a clipped gather reads for them) is itself busy
+    in the same boxcar."""
+    rng = np.random.default_rng(1000 * n_slots + 10 * b + k)
+    bucket = F._pow2_at_least(b)
+    rows_b = _edit_rows(rng, bucket, k, 5)
+    n_busy = max(1, min(b, n_slots) - (1 if b > 1 else 0))
+    busy = rng.choice(n_slots - 1, size=n_busy - 1, replace=False)
+    busy = np.append(busy, n_slots - 1)
+    assert len(set(busy.tolist())) == n_busy  # unique within a boxcar
+    slots = np.full(bucket, n_slots, np.int32)
+    at = rng.choice(bucket, size=n_busy, replace=False)
+    slots[at] = busy
+    if b > 1:
+        assert (slots == n_slots).any() and rows_b[slots == n_slots].any()
+
+    seeded = _seeded_pool(kernel, n_slots)
+    pool = F._Pool(_STEP_CAP, n_slots, kernel)
+    dense = np.zeros((n_slots, k, OP_WIDTH), np.int32)
+    dense[busy] = rows_b[at]
+    want = _host(pool._step(jax.device_put(seeded), jnp.asarray(dense)))
+    got = F._fused_sparse_step(kernel, None)(
+        jax.device_put(seeded), jnp.asarray(rows_b), jnp.asarray(slots)
+    )
+    _assert_states_equal(got, want)
+    untouched = np.setdiff1d(np.arange(n_slots), busy)
+    _assert_states_equal(
+        [np.asarray(x)[untouched] for x in got],
+        [x[untouched] for x in seeded], "untouched",
+    )
+    assert (np.asarray(got.cur_seq)[busy] == 4 + k).all()
+
+
+def _two_tier_fleets(n):
+    """``n`` equal fleets of six documents, documents 0-2 grown into the
+    64-row tier."""
+    batches, _oracles, _payloads = grow_stream(6, rounds=4, k=8, seed=11)
+    fleets = [DocFleet(n_docs=6, capacity=32, high_water=0.7)
+              for _ in range(n)]
+    for f in fleets:
+        for ops in batches:
+            ops = ops.copy()
+            ops[3:] = 0  # documents 3-5 stay empty, in the base tier
+            f.apply(ops)
+            f.check_and_migrate()
+        assert sorted(f.pools) == [32, 64], f.stats()
+    return fleets
+
+
+def _next_rows(seq, k=8):
+    rows = np.zeros((k, OP_WIDTH), np.int32)
+    for i in range(2):
+        rows[i] = E.insert(0, 900 + seq + i, 1, seq=seq + i,
+                           ref=seq + i - 1, client=1)
+    return rows
+
+
+def test_two_tier_boxcar_lands_in_each_documents_own_pool():
+    """One boxcar with documents of two tiers through ``dispatch_staged``
+    and through ``apply_sparse`` (the fault fallback): both equal the
+    dense ``apply`` over every slot of both pools, so no pool took
+    another tier's rows."""
+    dense, staged, fallback = _two_tier_fleets(3)
+    docs = [4, 1, 5, 0]  # tiers interleaved: 32, 64, 32, 64
+    ops_b = np.stack([
+        _next_rows(33 if d < 3 else 1) for d in docs
+    ])
+    full = np.zeros((6, 8, OP_WIDTH), np.int32)
+    full[docs] = ops_b
+    dense.apply(full)
+    staged.dispatch_staged(docs, jax.device_put(ops_b))
+    fallback.apply_sparse(docs, ops_b)
+    assert staged.last_step_docs == fallback.last_step_docs == 2 * 4
+    for cap in (32, 64):
+        for f in (staged, fallback):
+            _assert_states_equal(
+                f.pools[cap].state, dense.pools[cap].state, cap
+            )
+    assert dense.stats()["docs_with_errors"] == 0
+
+
+@pytest.mark.parametrize("spread", ["several_shards", "one_shard"])
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_mesh_sharded_step_equals_unsharded(kernel, spread):
+    """On the 8 host devices: each device takes the replicated boxcar,
+    keeps the slots of its own slice and drops the rest."""
+    from fluidframework_tpu.parallel.mesh import make_mesh
+
+    if len(jax.devices()) < 8:
+        pytest.skip("needs the 8-device virtual mesh")
+    n_docs = 64  # 8 slots a device
+    docs = [3, 9, 12, 40, 47, 63] if spread == "several_shards" else [
+        16, 18, 23]
+    rng = np.random.default_rng(5)
+    one = DocFleet(n_docs, _STEP_CAP, kernel=kernel)
+    mesh = DocFleet(n_docs, _STEP_CAP, kernel=kernel, mesh=make_mesh())
+    for first_seq, via in ((1, "apply_sparse"), (9, "dispatch_staged")):
+        ops_b = _edit_rows(rng, len(docs), 8, first_seq)
+        for f in (one, mesh):
+            if via == "apply_sparse":
+                f.apply_sparse(docs, ops_b)
+            else:
+                rows = np.zeros((8, 8, OP_WIDTH), np.int32)
+                rows[: len(docs)] = ops_b
+                f.dispatch_staged(docs, jax.device_put(rows))
+        _assert_states_equal(
+            mesh.pools[_STEP_CAP].state, one.pools[_STEP_CAP].state, via
+        )
+    state = mesh.pools[_STEP_CAP].state
+    assert len(state.kind.sharding.device_set) == 8
+    assert (np.asarray(state.cur_seq)[docs] == 16).all()
+    assert int(np.asarray(state.cur_seq).sum()) == 16 * len(docs)
+
+
+def test_step_docs_counts_the_bucket_not_the_pool():
+    """A boxcar of 3 documents on a 4,096-slot pool runs the kernel over
+    4 documents: ``flush_totals["step_docs"]`` is Σ B, not Σ n_slots."""
+    from fluidframework_tpu.protocol.opframe import SeqFrame
+    from fluidframework_tpu.service.device_backend import DeviceFleetBackend
+
+    be = DeviceFleetBackend(capacity=64, pump_mode=True)
+    for i in range(4096):
+        be.ensure(f"d{i}", "s")
+    assert be.fleet.pools[64].n_slots == 4096
+    for busy in ([5, 700, 4095], [9]):
+        for i in busy:
+            rows = np.zeros((2, OP_WIDTH), np.int32)
+            for j in range(2):
+                rows[j] = E.insert(0, j + 1, 1, seq=j + 1, ref=j, client=1)
+            be.enqueue_frame(
+                f"d{i}", SeqFrame("s", 0, 1, rows, ("a", "b"), 0.0)
+            )
+        be.pump_stage()
+        be.pump_dispatch()
+    be.pump_drain()
+    assert be.pump_dispatches == 2
+    assert be.flush_totals["real_rows"] == 8
+    assert be.flush_totals["step_docs"] == 4 + 1
+    assert be.text("d700", "s") == "ba" and be.text("d9", "s") == "ba"
+    assert be.stats()["docs_with_errors"] == 0
