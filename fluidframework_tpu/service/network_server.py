@@ -38,7 +38,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
-from fluidframework_tpu.service import admission, retry, wsproto
+from fluidframework_tpu.service import admission, gc_policy, retry, wsproto
 from fluidframework_tpu.service.codec import from_jsonable, to_jsonable
 from fluidframework_tpu.service.local_server import LocalFluidService
 from fluidframework_tpu.telemetry import metrics, profiler
@@ -353,6 +353,7 @@ class FluidNetworkServer:
         # it), lag_sum_ms adds up every tick's overshoot; stalls_seen
         # counts threshold crossings.
         self._lag_task: Optional[asyncio.Task] = None
+        self._gc_held = False  # this server's count in gc_policy
         self.loop_lag_threshold_ms = 50.0
         self.lag_ticks = 0
         self.lag_sum_ms = 0.0
@@ -416,9 +417,13 @@ class FluidNetworkServer:
                 self._pump_task = asyncio.ensure_future(self._pump_ticker())
             # The loop-stall watchdog runs on EVERY front door (a
             # device-less service can still block its loop), and the gc
-            # pause hooks install once per process (idempotent).
+            # pause hooks install once per process (idempotent). With
+            # them the collector's policy while serving (gc_policy): the
+            # sentinel's tick is its safe point, stop() undoes it.
             self._lag_task = asyncio.ensure_future(self._lag_sentinel())
             profiler.install_gc_hooks()
+            gc_policy.acquire()
+            self._gc_held = True
             self._push_drainer.start()
             self._started.set()
 
@@ -450,6 +455,9 @@ class FluidNetworkServer:
         if self._thread is not None:
             self._thread.join(5)
         self._push_drainer.stop()
+        if self._gc_held:
+            self._gc_held = False
+            gc_policy.release()
 
     # -- connection handling ------------------------------------------------
 
@@ -945,9 +953,17 @@ class FluidNetworkServer:
 
         period = self.LOOP_LAG_PERIOD_S
         while True:
+            seams = profiler.spans_committed()
             t0 = time.perf_counter()
             await asyncio.sleep(period)
             t1 = time.perf_counter()
+            # The collector's safe point, between two of the loop's
+            # callbacks: a pass if enough was allocated since the last,
+            # sooner (and a full one) where the tick was idle — no seam
+            # of the served path crossed (no frame, boxcar or read),
+            # whatever queues the work: sockets, a bulk consumer's calls
+            # on the loop, the tickers.
+            gc_policy.tick(idle=profiler.spans_committed() == seams)
             self.lag_ticks += 1
             lag_ms = max(0.0, (t1 - t0 - period) * 1e3)
             self.lag_sum_ms += lag_ms

@@ -597,6 +597,13 @@ def totals() -> Dict[str, tuple]:
     return {lane: tuple(tot) for lane, tot in _TOTALS.items()}
 
 
+def spans_committed() -> int:
+    """How many spans and records every lane has taken so far: two reads
+    that agree bracket a stretch in which no seam of the served path was
+    crossed (the lag sentinel's idle test)."""
+    return sum(tot[0] for tot in _TOTALS.values())
+
+
 def intervals() -> List[Interval]:
     drain_gc_events()  # buffered collector pauses land before the read
     return PROFILER.intervals()
