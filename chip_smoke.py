@@ -119,7 +119,7 @@ def phase_device(chips: int) -> dict:
 
 def _fuzz_streams(seed: int, scripts: int, n_ops: int):
     """``scripts`` distinct err-free acked streams + the oracles that
-    evolved with them (the bench.py device_state_parity recipe)."""
+    evolved with them."""
     from fluidframework_tpu.protocol.constants import NO_CLIENT
     from fluidframework_tpu.testing.fuzz import random_acked_stream
     from fluidframework_tpu.testing.oracle import OracleDoc
@@ -320,17 +320,49 @@ def on_loop(srv, fn, timeout: float = 900.0):
     return asyncio.run_coroutine_threadsafe(run(), srv._loop).result(timeout)
 
 
+def _bulk_connect(svc, doc_ids):
+    """One writer connection per document through the real join path
+    (a sequenced ClientJoin via deli), batched: every join record lands
+    on rawdeltas first, ONE pipeline drain sequences them all, then the
+    tokens are matched up. ``svc.connect()`` pumps the whole pipeline per
+    call, O(docs^2) stage sweeps at fleet scale."""
+    import uuid
+
+    from fluidframework_tpu.protocol.types import MessageType
+    from fluidframework_tpu.service.lambdas import RAW_TOPIC
+    from fluidframework_tpu.service.pipeline import PipelineConnection
+
+    conns = {}
+    for d in doc_ids:
+        token = f"c-{uuid.uuid4().hex[:12]}"
+        conn = PipelineConnection(svc, d, token)
+        svc.rooms.setdefault(d, []).append(conn)
+        svc.log.send(RAW_TOPIC, d, {"t": "join", "mode": "write",
+                                    "token": token})
+        conns[d] = conn
+    svc.pump()
+    for d, conn in conns.items():
+        for msg in conn.take_inbox():
+            if (
+                msg.type == MessageType.CLIENT_JOIN
+                and msg.contents.get("token") == conn.token
+            ):
+                conn.client_id = msg.contents["clientId"]
+                conn.join_seq = msg.sequence_number
+                conn.conn_no = msg.contents.get("connNo", 0)
+        assert conn.client_id >= 0, d
+    return conns
+
+
 class Feeder:
     """Bulk frame traffic for a set of docs in lockstep, through the real
-    join path and the bulk frame front door (the bench_configs config-7
-    recipe): every op inserts one character at position 0. Like a real
-    client it honours the overload envelope: a frame nacked with
-    THROTTLING is offered again after its retry-after. ``call`` runs a
-    function on the thread that owns the service."""
+    join path and the bulk frame front door: every op inserts one
+    character at position 0. Like a real client it honours the overload
+    envelope: a frame nacked with THROTTLING is offered again after its
+    retry-after. ``call`` runs a function on the thread that owns the
+    service."""
 
     def __init__(self, call, svc, doc_ids):
-        from bench_configs import _bulk_connect
-
         self.call, self.svc = call, svc
         self.doc_ids = list(doc_ids)
         conns = call(lambda: _bulk_connect(svc, self.doc_ids))
@@ -738,22 +770,119 @@ def phase_tiers(srv, target: int = 2112, top: int = 4096) -> dict:
 # -- tree ---------------------------------------------------------------------
 
 
-def phase_tree(n_docs: int = 1024) -> dict:
-    """SharedTree through EditManager's device path (batch_ingest ->
-    batched_em_trunk_scan), 1k documents, a 5% move mix; parity with the
-    per-commit host engine is asserted inside."""
-    import bench_configs
+def _tree_stream(seed: int, n_commits: int, move_prob: float):
+    """One document's concurrent wire stream: three sessions author on
+    views that lag the log by up to 6 commits, inserts and deletes
+    balanced so the view stays in one dense-size bucket (no recompile in
+    mid-run), and ``move_prob`` of the commits a first-class move
+    (mout/min marks)."""
+    from fluidframework_tpu.tree import marks as M
+    from fluidframework_tpu.tree.edit_manager import Commit, EditManager
 
-    rec = bench_configs.config3c_em_kernel_concurrent(
-        n_docs=n_docs, n_commits=64, scripts=8, wave=32, move_prob=0.05,
+    r = np.random.default_rng(seed)
+    sessions = [EditManager(session=100 + s) for s in range(3)]
+    processed = [0, 0, 0]
+    log = []
+    nid = 1
+    for k in range(1, n_commits + 1):
+        s = int(r.integers(0, 3))
+        em = sessions[s]
+        target = max(
+            processed[s],
+            max((c.seq for c in log if c.session == em.session), default=0),
+            len(log) - 6,
+        )
+        for c in log[processed[s]: target]:
+            em.add_sequenced(c)
+        processed[s] = target
+        view = em.local_view()
+        if len(view) >= 4 and r.random() < move_prob:
+            i0 = int(r.integers(0, len(view) - 1))
+            cnt = int(r.integers(1, min(3, len(view) - i0) + 1))
+            dest = int(r.integers(0, len(view) - cnt + 1))
+            cells = view[i0: i0 + cnt]
+            if dest <= i0:
+                change = [M.skip(dest), M.move_in(0, cnt),
+                          M.skip(i0 - dest), M.move_out(0, cells)]
+            else:
+                change = [M.skip(i0), M.move_out(0, cells),
+                          M.skip(dest - i0), M.move_in(0, cnt)]
+        else:
+            change = []
+            i = 0
+            while i < len(view):
+                roll = r.random()
+                run = min(int(r.integers(1, 3)), len(view) - i)
+                if roll < 0.45 and len(view) > 24:
+                    change.append(M.delete(view[i: i + run]))
+                else:
+                    change.append(M.skip(run))
+                i += run
+            change.append(M.insert([
+                ((100 + s) * 1000000 + nid + j, nid + j) for j in range(2)
+            ]))
+            nid += 2
+        change = M.normalize(change)
+        em.add_local(change)
+        log.append(Commit(session=em.session, seq=k, ref=target, change=change))
+    return log
+
+
+def phase_tree(
+    n_docs: int = 1024, n_commits: int = 64, scripts: int = 8,
+    wave: int = 32, move_prob: float = 0.05,
+) -> dict:
+    """SharedTree through EditManager's device path: ``batch_ingest``
+    gathers many documents' eligible prefixes into ONE
+    ``batched_em_trunk_scan`` dispatch per wave. ``scripts`` distinct
+    streams are tiled over the documents; every commit has to ride the
+    device (moves too), and every distinct script has to end in the trunk
+    state the per-commit host EditManager folds."""
+    from fluidframework_tpu.tree import marks as M
+    from fluidframework_tpu.tree.edit_manager import EditManager, batch_ingest
+
+    streams = [
+        _tree_stream(1000 + i, n_commits, move_prob) for i in range(scripts)
+    ]
+    host_ems = []
+    for log in streams:
+        em = EditManager(session=1)
+        for c in log:
+            em.add_sequenced(c)
+        host_ems.append(em)
+
+    n_docs = scripts * max(1, n_docs // scripts)
+    ems = [EditManager(session=1) for _ in range(n_docs)]
+    logs = [streams[d % scripts] for d in range(n_docs)]
+    device_commits = total = waves = 0
+    for w0 in range(0, n_commits, wave):
+        # The collab floor trails the head by the authoring lag: commits
+        # of the NEXT wave ref up to 6 back, and the server's min_seq only
+        # passes states nothing will reference.
+        items = []
+        for em, log in zip(ems, logs):
+            chunk = log[w0: w0 + wave]
+            items.append((em, chunk, max(0, chunk[-1].seq - 8)))
+        stats = batch_ingest(items)
+        device_commits += stats["device_commits"]
+        total += stats["device_commits"] + stats["host_commits"]
+        waves += 1
+
+    assert total == n_docs * n_commits, (total, n_docs, n_commits)
+    assert device_commits == total, (
+        f"tree: {total - device_commits} of {total} commits fell back to "
+        "the host"
     )
-    assert rec["device_fraction"] == 1.0, rec
-    assert rec["parity"] == "ok"
+    for d in range(scripts):
+        assert ems[d].trunk_state == host_ems[d].trunk_state, (
+            f"tree: device/host divergence on script {d}"
+        )
+    n_moves = sum(1 for log in streams for c in log if M.has_moves(c.change))
     return {
-        "n_docs": rec["n_docs"], "commits_per_doc": rec["commits_per_doc"],
-        "waves": rec["waves"], "device_fraction": rec["device_fraction"],
-        "move_commit_fraction": rec["move_commit_fraction"],
-        "parity_with_host_engine": rec["parity"],
+        "n_docs": n_docs, "commits_per_doc": n_commits, "waves": waves,
+        "device_fraction": device_commits / total,
+        "move_commit_fraction": round(n_moves / (scripts * n_commits), 3),
+        "parity_with_host_engine": "ok",
     }
 
 

@@ -2,9 +2,9 @@
 
 Why this exists: the XLA formulation in :mod:`merge_kernel` streams every
 per-segment lane through HBM once per sequenced op (a ``lax.scan`` step) and
-``vmap`` turns its per-op ``lax.switch`` into execute-all-7-branches — on a
-v5e chip that measures ~10k ops/s, *slower than the pure-Python oracle*. The
-hot loop is memory-latency-bound, not compute-bound: the fix is to keep each
+``vmap`` turns its per-op ``lax.switch`` into execute-all-7-branches (its
+rate on the chip is not measured on current code). The hot loop is
+memory-latency-bound, not compute-bound: the fix is to keep each
 document's segment table resident in VMEM for the whole op batch and apply
 ops as branch-free vector arithmetic. That is exactly what this kernel does:
 

@@ -1,15 +1,12 @@
 """AOT donated-entry cache: compile once per static shape bucket.
 
-The r6 latency profile proved the pattern in bench-only code (an
-``.lower().compile()`` entry with ``donate_argnums`` skips tracing, the
-jit cache lookup, AND the defensive copy on every hot call — the
-``device_single_dispatch_aot_*`` estimators). r10 lifts it into
-production: every hot device entry on the serving path — the pump's
-busy-set step and compact (``parallel/fleet.py``), the mesh
-``shard_map`` step (``parallel/mesh.py``), the fleet-service commit
-(``service/fleet_service.py``) — is lowered and compiled ONCE per static
-shape bucket and then served from a dict probe, so steady-state serving
-pays zero per-flush tracing or cache-miss cost.
+An ``.lower().compile()`` entry with ``donate_argnums`` skips tracing,
+the jit cache lookup, AND the defensive copy on every hot call. Every hot
+device entry on the serving path — the pump's busy-set step and compact
+(``parallel/fleet.py``), the mesh ``shard_map`` step
+(``parallel/mesh.py``) — is lowered and compiled ONCE per static shape
+bucket and then served from a dict probe, so steady-state serving pays
+zero per-flush tracing or cache-miss cost.
 
 Keys are explicit shape-bucket tuples (callers already pow2-bucket their
 batch dims, so the entry set stays logarithmic in fleet size); values are

@@ -1138,28 +1138,6 @@ class DocFleet:
 
     # -- introspection --------------------------------------------------------
 
-    def doc_counts(self, docs: List[int]) -> np.ndarray:
-        """Live row counts for a set of docs with ONE [n_slots] count-lane
-        readback per pool — ``doc_state`` per doc would pull every lane of
-        the whole pool through the transfer path. Docs evicted out of the
-        fleet (ShardedDoc promotion) report 0: their rows live elsewhere
-        (``DeviceFleetBackend.stats`` aggregates them)."""
-        count_cache: Dict[int, np.ndarray] = {}
-        out = np.zeros(len(docs), np.int32)
-        for i, d in enumerate(docs):
-            place = self.placement[d]
-            if place is None:
-                continue  # evicted to a ShardedDoc
-            cap, slot = place
-            counts = count_cache.get(cap)
-            if counts is None:
-                # graftlint: readback(one [n_slots] count-lane pull per pool — the documented introspection cost)
-                counts = count_cache[cap] = np.asarray(
-                    self.pools[cap].state.count
-                )
-            out[i] = counts[slot]
-        return out
-
     def doc_state(self, doc: int) -> SegmentState:
         """One document's full state read back to host via a device-side
         slice ([L, S] lanes + [5] scalars come back — NOT the whole

@@ -156,9 +156,7 @@ def unpack_packed_doc_states(
     planes followed by ``[pad, N_SCALARS]`` scalar rows, flattened into
     one vector — into per-doc SegmentStates (``pad`` rows beyond
     ``len(docs)`` are gather padding, discarded). THE one unpack for
-    the packed gather layout, shared by ``DocShard.doc_states``
-    (pallas) and ``TpuFleetService.doc_states`` so the bit-parity
-    contract cannot diverge between backends."""
+    the packed gather layout (``DocShard.doc_states``, pallas)."""
     from fluidframework_tpu.ops.pallas_kernel import (
         SC_COUNT,
         SC_CUR_SEQ,

@@ -7,13 +7,10 @@ deli ``ticket()``). The reference ships one JSON ``IDocumentMessage`` per
 op; here clients already lower SharedString ops to int32 kernel rows
 (``models/shared_string.py:row_from_wire``), so the TPU-native wire ships
 THE ROWS: a frame is a contiguous run of string-kernel ops from one client
-on one channel, as planar int32 columns plus one UTF-8 text blob — the
-client-side mirror of the fleet service's width-adaptive device wire
-(``service/fleet_service.py``). Deli tickets a whole frame in one
-vectorized call (seq stamps are ``seq0 + arange``), every service stage
-handles the frame as one record, and the device stage stages the rows
-without any per-op Python — this is what takes the generic-wire pipeline
-path from single-digit-k to 100k+ ops/s.
+on one channel, as planar int32 columns plus one UTF-8 text blob. Deli
+tickets a whole frame in one vectorized call (seq stamps are
+``seq0 + arange``), every service stage handles the frame as one record,
+and the device stage stages the rows without any per-op Python.
 
 The JSON per-op wire remains the compat path: frames are additive, and a
 frame-ignorant consumer that filters on ``value["t"] == "seq"`` simply

@@ -44,9 +44,10 @@ Two coupled controllers:
 Goodput contract (ROADMAP "Overload & tenancy envelope"): at 2x the
 admitted capacity the envelope degrades LINEARLY — goodput stays pinned
 near admitted capacity while the excess receives paced nacks — instead
-of the cliff an unbounded queue produces. ``bench.py
-overload_benchmark`` measures the curve; ``docs/failure-semantics.md``
-§"Overload semantics" is the per-tier client-visible contract table.
+of the cliff an unbounded queue produces. No benchmark cell offers more
+than the admitted capacity yet (ROADMAP W5, ``p12k5-ws-burst``), so the
+curve is not measured; ``docs/failure-semantics.md`` §"Overload
+semantics" is the per-tier client-visible contract table.
 """
 
 from __future__ import annotations

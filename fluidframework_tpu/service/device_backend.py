@@ -18,24 +18,26 @@ whole fleet into ONE batched kernel dispatch per flush, runs the capacity
 lifecycle between batches, and surfaces each document's sticky err lane
 exactly once as it trips (the nack/telemetry feed).
 
-The continuous pump (r10): in ``pump_mode`` (default) the flush path is a
+The continuous pump: in ``pump_mode`` (default) the flush path is a
 pipelined ring, not a stage→dispatch→wait sequence. Round N+1's boxcar
 assembles on host and uploads asynchronously into a double-buffered
 ingest ring slot while round N computes on device, dispatches go through
 cached AOT donated executables (``parallel/aot.py`` — zero per-flush
 tracing once the shape buckets are warm), and round N-1's one-boxcar-
-stale health scan is the only device→host readback. The target is e2e
-throughput tracking DEVICE throughput instead of dispatch count.
+stale health scan is the only device→host readback. On the v5e both
+benchmark cells are bound by the host's pipeline, not by the device step
+or its dispatch (PERF.md §5); ``pump_mode=False`` keeps the one-shot
+flush, the reference of the pump's parity tests.
 
-The continuous front door (r12): boxcar FORMATION is streaming too —
+The continuous front door: boxcar FORMATION is streaming too —
 ``pump_feed()`` is a hybrid size/time trigger (a boxcar stages as soon
 as it reaches ``max_batch`` OR ``feed_deadline_ms`` expires on the
 oldest buffered row, then dispatches eagerly) that the pipeline runs
 inside its pump sweep and the network server runs from a deadline
 ticker, so the device is fed while the pipeline is still busy; the
-quiescence-time flush survives only as the final drain + err-surface
-barrier. The reference's deli is the same shape: a free-running Kafka
-consumer, not a quiescence-gated one (deli/lambda.ts).
+quiescence-time flush is the final drain + err-surface barrier. The
+reference's deli is the same shape: a free-running Kafka consumer, not a
+quiescence-gated one (deli/lambda.ts).
 
 Replay safety: delivery upstream is at-least-once; a per-channel applied-
 sequence watermark drops already-applied rows host-side, so a crashed
@@ -201,26 +203,20 @@ class DeviceFleetBackend:
         self._errored: set = set()  # fleet ids already reported
         self._unreported: List[ChannelKey] = []
         self.ops_applied = 0
-        # The read tier's amortization counters (r15): snapshot reads
-        # served vs device gather dispatches — reads_per_device_dispatch
-        # is the batching win the bench artifact gates on, and
+        # The read tier's amortization counters: snapshot reads served
+        # vs device gather dispatches (reads_per_device_dispatch), and
         # read_gather_fallbacks counts faulted batched gathers served
         # through per-doc host gathers instead (never a failed read).
         self.reads_served = 0
         self.read_gathers = 0
         self.read_gather_fallbacks = 0
         # Where flush wall goes (host staging vs upload + dispatch):
-        # last_flush_breakdown is the most recent flush; flush_totals
-        # accumulates monotonically (benches diff it across rounds —
-        # flushes fire from inside enqueue when the boxcar fills, so a
-        # last-only view misses most of them).
-        self.last_flush_breakdown: Dict[str, float] = {}
-        # routing_s (r16): the fleet-side host routing that runs INSIDE
-        # the dispatch call (fleet.last_routing_s) used to be folded
-        # back into staging_s; it now has its own bucket so staging_s
-        # is a PURE derived view of the profiler's host_stage/ring_put
-        # interval clock reads (the one-clock satellite, equivalence
-        # regression-tested).
+        # flush_totals accumulates monotonically; readers diff it over
+        # a window. routing_s is the fleet-side host routing that runs
+        # INSIDE the dispatch call (fleet.last_routing_s), in its own
+        # bucket so that staging_s is a PURE derived view of the
+        # profiler's host_stage/ring_put interval clock reads
+        # (equivalence regression-tested).
         self.flush_totals: Dict[str, float] = {
             "staging_s": 0.0, "dispatch_s": 0.0, "routing_s": 0.0,
             "staged_rows": 0,  # padded to the [B, K] bucket
@@ -230,13 +226,12 @@ class DeviceFleetBackend:
             # what the pow2 bucket and a multi-tier boxcar cost.
             "step_docs": 0,
         }
-        # The continuous device pump (r10): double-buffered ingest ring +
-        # AOT donated dispatch. pump_mode routes flush() through the
-        # ring; pump_mode=False keeps the legacy stage->dispatch->wait
-        # one-shot path (the parity reference the pump is pinned
-        # against). pump_busy_s is the union of dispatch->scan-readback
-        # wall intervals — 1 - busy/wall is the measured device idle
-        # fraction the bench reports.
+        # The continuous device pump: double-buffered ingest ring + AOT
+        # donated dispatch. pump_mode routes flush() through the ring;
+        # pump_mode=False keeps the stage->dispatch->wait one-shot path
+        # (the parity reference the pump is pinned against).
+        # pump_busy_s is the union of dispatch->scan-readback wall
+        # intervals.
         self.pump_mode = pump_mode
         self._ring = IngestRing(ring_depth)
         self.pump_dispatches = 0
@@ -260,9 +255,9 @@ class DeviceFleetBackend:
         # arrived (deadline trigger — _feed_edge tracks that arrival),
         # then dispatches eagerly, so socket reads, sequencing, and
         # device compute overlap continuously. feed_triggers counts which
-        # trigger fired (benches/tests read it); _scan_prefetch holds an
-        # off-thread transfer of the in-flight scan (the network server's
-        # deadline ticker runs the blocking half off-loop).
+        # trigger fired (chip_smoke.py and tests read it); _scan_prefetch
+        # holds an off-thread transfer of the in-flight scan (the network
+        # server's deadline ticker runs the blocking half off-loop).
         self.feed_deadline_ms = float(feed_deadline_ms)
         self._feed_edge: Optional[float] = None
         self.feed_triggers: Dict[str, int] = {"size": 0, "deadline": 0}
@@ -717,8 +712,8 @@ class DeviceFleetBackend:
         a flush never waits on its own readback. Soundness: the per-doc chunk limit
         is HALF the tier headroom, so a promotion trigger read one flush
         late still fires before the doc can overflow.
-        ``last_flush_breakdown`` / ``flush_totals`` record where the wall
-        went (host staging vs upload+dispatch)."""
+        ``flush_totals`` records where the wall went (host staging vs
+        upload+dispatch)."""
         if self._parked_rows:
             self._retry_parked_wakes()
         if self.pump_mode:
@@ -891,16 +886,13 @@ class DeviceFleetBackend:
                 self.fleet.compact()
         self._buffered_rows = 0
         self._close_pending_traces()
-        self.last_flush_breakdown = {
-            "staging_s": staging_s,
-            "dispatch_s": dispatch_s,
-            "routing_s": routing_s,
-            "staged_rows": staged_rows,
-            "real_rows": real_rows,
-            "step_docs": step_docs,
-        }
-        for key, value in self.last_flush_breakdown.items():
-            self.flush_totals[key] += value
+        totals = self.flush_totals
+        totals["staging_s"] += staging_s
+        totals["dispatch_s"] += dispatch_s
+        totals["routing_s"] += routing_s
+        totals["staged_rows"] += staged_rows
+        totals["real_rows"] += real_rows
+        totals["step_docs"] += step_docs
         self._unreported.extend(newly_errored)
         return newly_errored
 
@@ -911,10 +903,9 @@ class DeviceFleetBackend:
         ring and dispatch through the AOT donated entries. One flush call
         still applies everything buffered (the flush contract); the
         overlap comes from the async upload + async dispatch inside, and
-        from continuous feeders (the bench / a serving loop) calling
+        from continuous feeders (a serving loop) calling
         :meth:`pump_stage` / :meth:`pump_dispatch` directly so round
         N+1's staging runs while round N computes."""
-        pre = dict(self.flush_totals)
         newly: List[ChannelKey] = []
         while self._buffers:
             self._pump_stage_counted()
@@ -922,9 +913,6 @@ class DeviceFleetBackend:
         # Continuous feeders may have staged slots without dispatching.
         newly.extend(self.pump_dispatch())
         self._close_pending_traces()
-        self.last_flush_breakdown = {
-            key: self.flush_totals[key] - pre[key] for key in pre
-        }
         return newly
 
     def _close_pending_traces(self) -> None:
@@ -1663,8 +1651,7 @@ class DeviceFleetBackend:
     @property
     def reads_per_device_dispatch(self) -> float:
         """Snapshot reads served per device gather dispatch — the read
-        tier's amortization headline (1.0 = no batching win; the bench
-        gate wants > 1 under concurrent load)."""
+        tier's amortization headline (1.0 = no batching win)."""
         return self.reads_served / max(1, self.read_gathers)
 
     def text_from_state(self, key: ChannelKey, state) -> str:
@@ -1792,10 +1779,9 @@ class DeviceFleetBackend:
 
     def publish_metrics(self, registry=None, scrape: Optional[dict] = None) -> dict:
         """Fold one :meth:`telemetry` scrape into per-shard registry
-        gauges (the /metrics handler calls this once per scrape; bench.py
-        merges the same dict into the driver artifact). ``scrape`` lets an
-        async server pass a scrape whose blocking readback it already ran
-        off-thread."""
+        gauges (the /metrics handler calls this once per scrape).
+        ``scrape`` lets an async server pass a scrape whose blocking
+        readback it already ran off-thread."""
         reg = registry or metrics.REGISTRY
         tel = scrape if scrape is not None else self.telemetry()
         shard_g = reg.gauge(

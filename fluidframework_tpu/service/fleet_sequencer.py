@@ -1,15 +1,13 @@
 """Struct-of-arrays deli state for whole fleets + the native ticket loop.
 
 The per-document ``DocumentSequencer`` (service/sequencer.py) owns the full
-deli semantics — joins/leaves, nacks, scopes, control messages, traces.
-Config 5 measured its Python ticket loop at ~150k tickets/s, which is the
-end-to-end ceiling of the service shape (the chip applies ~4M merge ops/s).
-This module keeps the same state as flat int32 arrays — one row per
-document, one client table per row — and tickets entire fleets per call
-through ``native/ticket_loop.cpp``; anything off the steady-state path
-(a gap, a stale ref, an unknown client) flags the document for replay
-through the Python slow path, exactly the fast-path/slow-path split the
-reference's deli uses for its nack branches.
+deli semantics — joins/leaves, nacks, scopes, control messages, traces,
+one Python ticket call per op. This module keeps the same state as flat
+int32 arrays — one row per document, one client table per row — and
+tickets entire fleets per call through ``native/ticket_loop.cpp``;
+anything off the steady-state path (a gap, a stale ref, an unknown client)
+flags the document for replay through the Python slow path, exactly the
+fast-path/slow-path split the reference's deli uses for its nack branches.
 """
 
 from __future__ import annotations

@@ -360,13 +360,6 @@ class HistorianReadTier:
     def has(self, handle: str) -> bool:
         return self.blobs.has(handle)
 
-    def hit_ratio(self) -> float:
-        """Read-tier hit ratio across every cache (deltas + summary +
-        blob) — the bench headline's ``read_historian_hit_ratio``."""
-        hits = self.hits + self.blobs.hits
-        total = hits + self.misses + self.blobs.misses
-        return hits / total if total else 0.0
-
 
 def historian(
     inner, cache=None, chunk_bytes: int = 256 * 1024

@@ -138,12 +138,10 @@ class PipelineFluidService:
         device_capacity: int = 128,
         device_max_capacity: int = 1 << 15,
         device_sharded_overflow: bool = False,
-        device_max_batch: int = 512,
         device_flush_min_rows: int = 1,
         device_mesh=None,
         device_kernel: str = "auto",
         device_pump: bool = True,
-        device_ring_depth: int = 2,
         device_feed_deadline_ms: float = 3.0,
         device_max_resident: int = 0,
         foreman_tasks: tuple = ("summarizer",),
@@ -267,15 +265,13 @@ class PipelineFluidService:
         if device_backend:
             self._make_device(
                 device_capacity, device_max_capacity,
-                device_sharded_overflow, device_max_batch, device_mesh,
-                device_kernel, device_pump, device_ring_depth,
-                device_feed_deadline_ms, device_max_resident,
+                device_sharded_overflow, device_mesh, device_kernel,
+                device_pump, device_feed_deadline_ms, device_max_resident,
             )
 
     def _make_device(
         self, capacity: int, max_capacity: int, sharded_overflow: bool,
-        max_batch: int = 512, mesh=None, kernel: str = "auto",
-        pump: bool = True, ring_depth: int = 2,
+        mesh=None, kernel: str = "auto", pump: bool = True,
         feed_deadline_ms: float = 3.0, max_resident: int = 0,
     ) -> None:
         from fluidframework_tpu.service.device_backend import (
@@ -283,22 +279,21 @@ class PipelineFluidService:
         )
         from fluidframework_tpu.service.device_lambda import TpuDeliLambda
 
-        # pump/ring_depth: the continuous device pump (r10) — flushes
-        # ride the double-buffered ingest ring + AOT donated entries;
-        # pump=False keeps the one-shot path (the parity reference).
-        # feed_deadline_ms: the r12 continuous front door — the hybrid
-        # size/time boxcar trigger the pump sweep and the network
-        # server's deadline ticker fire (DeviceFleetBackend.pump_feed).
+        # pump: the continuous device pump — flushes ride the
+        # double-buffered ingest ring + AOT donated entries; pump=False
+        # keeps the one-shot path (the parity reference).
+        # feed_deadline_ms: the hybrid size/time boxcar trigger the pump
+        # sweep and the network server's deadline ticker fire
+        # (DeviceFleetBackend.pump_feed).
         self.device = DeviceFleetBackend(
             capacity=capacity, max_capacity=max_capacity,
-            sharded_overflow=sharded_overflow, max_batch=max_batch,
-            mesh=mesh, kernel=kernel, pump_mode=pump,
-            ring_depth=ring_depth, feed_deadline_ms=feed_deadline_ms,
+            sharded_overflow=sharded_overflow, mesh=mesh, kernel=kernel,
+            pump_mode=pump, feed_deadline_ms=feed_deadline_ms,
             max_resident=max_resident,
         )
         self._device_capacity = (
-            capacity, max_capacity, sharded_overflow, max_batch, mesh,
-            kernel, pump, ring_depth, feed_deadline_ms, max_resident,
+            capacity, max_capacity, sharded_overflow, mesh, kernel, pump,
+            feed_deadline_ms, max_resident,
         )
 
         def factory(p: int, state):

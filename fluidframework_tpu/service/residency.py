@@ -408,8 +408,8 @@ class ResidencyManager:
         residency_hit_gauge(registry).set(round(self.hit_ratio(), 6))
 
     def wake_p99_ms(self) -> float:
-        """p99 over the in-process wake latency record (the bench
-        headline; /metrics serves the histogram form)."""
+        """p99 over the in-process wake latency record (/metrics
+        serves the histogram form)."""
         if not self.wake_ms:
             return 0.0
         xs = sorted(self.wake_ms)

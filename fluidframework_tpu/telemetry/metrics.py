@@ -45,7 +45,7 @@ SPAN_SANITY_MS = 600_000.0
 # ``reg.counter/gauge/histogram("<family>", ...)`` registration in the
 # package: an undeclared family, a kind mismatch, or a declared family
 # nothing registers (a dead dashboard row) fails CI. Scrape consumers
-# (dashboards, the autoscaler, check_bench_artifact) can therefore trust
+# (dashboards, the autoscaler) can therefore trust
 # this table as THE exposition contract.
 
 FAMILIES: Dict[str, str] = {
@@ -403,9 +403,8 @@ def stage_span_summary(
     quantiles: Sequence[float] = (),
 ) -> Dict[str, Any]:
     """Per-stage summary from the shared stage histogram. The default
-    (no ``quantiles``) keeps the r9 shape — ``{stage: mean_ms}``, the
-    compact ``serving_stage_spans_ms`` form bench.py merges into the
-    driver artifact. With ``quantiles`` (e.g. ``(0.5, 0.95, 0.99)``)
+    (no ``quantiles``) is ``{stage: mean_ms}``. With ``quantiles``
+    (e.g. ``(0.5, 0.95, 0.99)``, the journal dumps' summary)
     each stage maps to ``{"mean": …, "p50": …, "p95": …, "p99": …}`` —
     estimates interpolated from the SAME fixed buckets (no new state,
     no new histogram type: scrapes across replicas stay mergeable, the

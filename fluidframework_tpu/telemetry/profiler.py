@@ -67,8 +67,8 @@ Export surfaces:
   timestamps appear only in the exported trace file.
 - :func:`summarize` — per-lane totals, the global ``loop_other`` gap,
   ``serving_host_tax_ms``, and the timeline-derived device-idle
-  fraction the bench reconciles against ``serving_pump_device_idle_frac``
-  (two instruments, one truth).
+  fraction (``tests/test_profiler.py`` holds it to the backend's
+  ``pump_busy_s``).
 
 Runtime watchdogs (fed from here, visible as their own lanes):
 
@@ -285,15 +285,13 @@ class Profiler:
         - ``lanes_ms``: total recorded wall per lane (sum of durations).
         - ``loop_other_ms``: window wall NOT covered by any recorded
           interval — the global derived gap (named-lane coverage +
-          loop_other ≡ the window by construction; the bench asserts
-          the split anyway).
+          loop_other ≡ the window by construction).
         - ``serving_host_tax_ms``: p50/p99 over boxcar rounds of
           per-round ``loop_other + host_stage`` — the per-frame host
           Python between the ticketer and the device dispatch.
-        - ``device_idle_frac``: 1 − union(device_step)/window — the
-          instrument the bench reconciles against the legacy
-          ``serving_pump_device_idle_frac`` (tolerance-asserted:
-          two instruments, one truth).
+        - ``device_idle_frac``: 1 − union(device_step)/window, on the
+          host's clock (the chip's own idle share comes from a
+          profiler trace, ``benchmark/trace_reduce.py``).
         """
         ivs = self.intervals()
         if not ivs:
@@ -341,7 +339,7 @@ class Profiler:
             "loop_other_ms": round(loop_other_ms, 3),
             # Named-lane coverage of the window: the union of recorded
             # intervals plus the derived gap — 1.0 by construction, but
-            # computed (not assumed) so the bench's ≥0.95 assertion
+            # computed (not assumed) so the tests' ≥0.95 assertion
             # exercises the arithmetic, not a constant.
             "coverage_frac": round(
                 (covered + loop_other_ms / 1e3) / window, 4

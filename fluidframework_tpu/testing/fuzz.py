@@ -1,5 +1,5 @@
-"""Fuzz op-stream generators shared by the test suite and the bench's
-on-device state-parity check.
+"""Fuzz op-stream generators shared by the test suite and
+``chip_smoke.py``'s on-device state-parity check.
 
 The reference pins merge semantics with randomized "farm" suites
 (``packages/dds/merge-tree/src/test/client.conflictFarm.spec.ts``); the

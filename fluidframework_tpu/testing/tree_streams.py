@@ -1,9 +1,9 @@
 """Concurrent tree-commit stream generation + host reference trunk.
 
-Shared by the device-trunk parity tests and the config-3 device bench:
-streams of sequenced commits where sessions lag the head by < W commits
-(see tree/device_trunk.py), plus the host rebase-based trunk fold they are
-checked against (the reference EditManager algorithm)."""
+For the device-trunk parity tests: streams of sequenced commits where
+sessions lag the head by < W commits (see tree/device_trunk.py), plus the
+host rebase-based trunk fold they are checked against (the reference
+EditManager algorithm)."""
 
 from __future__ import annotations
 

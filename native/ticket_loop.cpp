@@ -8,9 +8,7 @@
 // lambda.ts:929-938). The Python DocumentSequencer (service/sequencer.py)
 // carries the full semantics (joins, leaves, nacks, scopes, control
 // messages, traces); this library executes the steady-state write-client
-// fast path for whole fleets in one call — config 5 measured the Python
-// loop at ~150k tickets/s, the end-to-end bottleneck of the TPU service
-// shape (the chip applies ~4M ops/s).
+// fast path for whole fleets in one call.
 //
 // Layout (all int32, C-contiguous):
 //   doc_state  [n_docs, 2]               : {seq, min_seq}

@@ -6,6 +6,13 @@ devices; the chip is driven by chip_smoke.py, never by the tests.
 """
 
 import os
+import sys
+
+# tests/test_benchmark_*.py import the benchmark's own cases from
+# ``benchmark.tests`` (a namespace package of the repo root).
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:

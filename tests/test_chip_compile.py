@@ -185,7 +185,8 @@ def test_pallas_compact_compiles_at_its_tiers(
 def test_fused_apply_compact_compiles_at_headline_shape(
     one_chip, no_persistent_cache
 ):
-    """bench.py's headline and TpuFleetService.commit_round's kernel."""
+    """The fused apply + compact kernel at the shape of chip_smoke.py's
+    ``kernels`` phase."""
     n_docs, cap, k = 32768, 256, 64
     tables, scalars = _packed(n_docs, cap, one_chip)
     compiled = pallas_compact.apply_compact_packed.lower(

@@ -578,24 +578,6 @@ def test_mesh_shard_telemetry_layout():
     assert int(out[:, occ_i].sum()) == n_docs
 
 
-def test_fleet_service_telemetry_layout():
-    """TpuFleetService.telemetry_slice: the packed-fleet half of a
-    scrape, same TELEMETRY_COLS layout, one batched readback."""
-    from fluidframework_tpu.parallel.fleet import TELEMETRY_COLS
-    from fluidframework_tpu.service.fleet_service import TpuFleetService
-
-    n_docs = 8
-    svc = TpuFleetService(n_docs, capacity=64, block_docs=n_docs,
-                          interpret=True)
-    svc.join_writer(0)
-    out = svc.telemetry_slice(n_shards=2)
-    assert out.shape == (2, len(TELEMETRY_COLS))
-    occ_i = TELEMETRY_COLS.index("live_slots")
-    assert int(out[:, occ_i].sum()) == n_docs  # packed fleet: all live
-    err_i = TELEMETRY_COLS.index("err_docs")
-    assert int(out[:, err_i].sum()) == 0
-
-
 # ---------------------------------------------------------------------------
 # /metrics exposition surfaces
 
@@ -755,9 +737,9 @@ def test_quantile_interpolation_exact_cases():
     assert metrics._bucket_quantile(buckets, [0, 0, 0, 5], 0.5) == 4.0
 
 
-def test_bench_p99_rides_the_spans_histogram():
-    """The bench artifact key shape: serving_stage_p99_ms maps stage ->
-    p99 from the same histogram the means come from."""
+def test_stage_p99_rides_the_spans_histogram():
+    """Per-stage p99 comes from the same histogram the means come
+    from."""
     metrics.observe_stage_spans({"deli_ms": 3.0, "total_ms": 9.0})
     metrics.observe_stage_spans({"deli_ms": 4.0, "total_ms": 12.0})
     q = metrics.stage_span_summary(quantiles=(0.99,))

@@ -329,10 +329,9 @@ def test_legacy_counters_are_derived_views_oneshot():
 
 def test_summarize_decomposes_the_window():
     """A captured pump window decomposes into named lanes + the derived
-    loop_other gap (coverage ≈ 1 by construction — asserted ≥ 0.95, the
-    bench bar), reports per-boxcar host tax percentiles, and derives the
-    device-idle fraction the bench reconciles with
-    serving_pump_device_idle_frac."""
+    loop_other gap (coverage ≈ 1 by construction — asserted ≥ 0.95),
+    reports per-boxcar host tax percentiles, and derives a device-idle
+    fraction that agrees with ``1 - pump_busy_s / wall``."""
     be = DeviceFleetBackend(capacity=128, max_batch=1 << 20, pump_mode=True)
     assert profiler.arm(60_000)
     busy0 = be.pump_busy_s

@@ -6,7 +6,7 @@ lowered to the dense IR must produce identical documents through apply/
 rebase/invert/compose on both implementations — INCLUDING move-bearing
 changesets (r7: mout/min lower into the dense move lanes; the four laws
 are re-fuzzed on move-bearing inputs below). On CI this runs on the
-virtual CPU backend; the bench artifact runs the same kernels on real TPU.
+virtual CPU backend.
 """
 
 import numpy as np

@@ -26,7 +26,6 @@ DEVICE_PATH_SCOPE = (
     "fluidframework_tpu/tree/device_*.py",
     "fluidframework_tpu/parallel/*.py",
     "fluidframework_tpu/service/device_backend.py",
-    "fluidframework_tpu/service/fleet_service.py",
 )
 
 # Merge/sequencing modules (the determinism scope): code every replica
