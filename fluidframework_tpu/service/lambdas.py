@@ -24,12 +24,22 @@ seq, broadcaster drops seqs already delivered to a connection).
 
 from __future__ import annotations
 
+import itertools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from fluidframework_tpu.protocol.constants import (
+    F_CLIENT,
+    F_MSN,
+    F_REF,
+    F_SEQ,
+    F_TYPE,
+    OP_INSERT,
+)
+from fluidframework_tpu.protocol.opframe import SeqFrame
 from fluidframework_tpu.protocol.types import (
     DocumentMessage,
     MessageType,
@@ -49,6 +59,7 @@ from fluidframework_tpu.telemetry import (
 from fluidframework_tpu.testing.faults import inject_fault
 from fluidframework_tpu.service.sequencer import (
     DocumentSequencer,
+    FrameTicket,
     SequencerCheckpoint,
 )
 
@@ -188,9 +199,14 @@ class DocumentLambda(PartitionLambda):
         filter, dirty-marking, and demux run as one tight loop instead of
         per-record dispatch through the runner (documentLambda.ts routes
         per message; at 10k+ frames/round the layers ARE the cost).
-        A failing record raises :class:`BatchHandlerError` carrying the
-        completed prefix's outputs, preserving the per-record loop's
-        output-before-commit crash contract."""
+        A failing record (or a document the factory cannot make) raises
+        :class:`BatchHandlerError` carrying the completed prefix's
+        outputs, preserving the per-record loop's output-before-commit
+        crash contract. This is the serving path of scribe, foreman and
+        the device stage, and of deli's records other than op frames;
+        for a chunk's op frames :class:`DeliPartitionLambda` overrides
+        it, and this loop over ``DeliDocLambda.handler`` is that pass's
+        reference."""
         out: List[Tuple[str, str, Any]] = []
         docs = self._docs
         dirty = self._dirty
@@ -200,11 +216,11 @@ class DocumentLambda(PartitionLambda):
             if wants is not None and value.get("t") not in wants:
                 continue
             key = rec.key
-            lam = docs.get(key)
-            if lam is None:
-                lam = docs[key] = self._factory(key, None)
-            dirty.add(key)
             try:
+                lam = docs.get(key)
+                if lam is None:
+                    lam = docs[key] = self._factory(key, None)
+                dirty.add(key)
                 res = lam.handler(key, value)
             except Exception as e:
                 raise BatchHandlerError(out, i, e) from e
@@ -372,8 +388,8 @@ class DeliDocLambda(PartitionLambda):
     def handler(self, key: str, value: dict) -> List[Tuple[str, str, Any]]:
         t = value["t"]
         if t == "opframe":
-            # Hot path: no per-record metric object — a Lumber allocation
-            # per frame was measurable serving-path overhead; sampled op
+            # No per-record metric object — a Lumber allocation per
+            # frame was measurable serving-path overhead; sampled op
             # tracing (alfred's 1-in-N stamp) remains the observability
             # story for the data plane, metrics cover the control plane.
             return self._handle_frame(key, value)
@@ -449,18 +465,17 @@ class DeliDocLambda(PartitionLambda):
         return out
 
     def _handle_frame(self, key: str, value: dict) -> List[Tuple[str, str, Any]]:
-        """Ticket a batched binary op frame (protocol/opframe.py) in one
-        vectorized call and emit the sequenced frame as ONE deltas record
-        — the wire path that keeps per-op Python off the serving path.
-        The whole-frame-valid case (no dup prefix, no trailing nack — the
-        steady-state stream) stamps with cached aranges and reuses the
-        frame's texts tuple without the per-op insert scan."""
-        from fluidframework_tpu.protocol.constants import (
-            F_CLIENT, F_MSN, F_REF, F_SEQ, F_TYPE, OP_INSERT,
-        )
-        from fluidframework_tpu.protocol.opframe import SeqFrame
-        from fluidframework_tpu.service.sequencer import FrameTicket
-
+        """Ticket ONE batched binary op frame (protocol/opframe.py) in a
+        vectorized call and emit the sequenced frame as one deltas
+        record. The REFERENCE for a frame, and the path of every frame
+        that is not the steady-state one: a duplicate or a duplicate
+        prefix, a csn gap, refs that differ, a stale ref (a partial
+        ticket with its trailing nack), any nack, a sampled frame with
+        its trace stamps. The serving path of a steady-state frame is
+        :meth:`DeliPartitionLambda._ticket_run`, which tickets a read
+        chunk's frames together and calls this one, at its place in the
+        run, for the rest; ``tests/test_deli_chunk_ticket.py`` holds the
+        two bit-equal."""
         client = value["client"]
         frame = value["frame"]
         # Sampled frame (trace list rides the record envelope): the
@@ -544,6 +559,158 @@ class DeliDocLambda(PartitionLambda):
         if res.trailing_nack is not None:
             out.append((DELTAS_TOPIC, key, {"t": "nack", "client": client,
                                             "nack": res.trailing_nack}))
+        return out
+
+
+def _gather_frames(run):
+    """One block for a run of ``opframe`` records: the frames' rows
+    concatenated into a private ``[sum n, OP_WIDTH] int32`` copy (the copy
+    the per-record path makes per frame), and what the ticket loop wants
+    of every frame read from it at once, as Python lists: the row count,
+    the first clientSequenceNumber, the first refSeq, whether every op
+    shares that refSeq. None for a run this cannot read (an empty or
+    malformed frame): the per-record path then raises what it raises."""
+    try:
+        rows = [rec.value["frame"].rows for rec in run]
+        ns = [r.shape[0] for r in rows]
+        if 0 in ns:
+            return None
+        block = np.concatenate(rows, dtype=np.int32)
+    except Exception:
+        return None
+    starts = np.array(list(itertools.accumulate(ns, initial=0))[:-1])
+    refs = block[:, F_REF]
+    r0s = np.minimum.reduceat(refs, starts)  # the first, where all agree
+    uniform = r0s == np.maximum.reduceat(refs, starts)
+    return (block, ns, block[starts, F_SEQ].tolist(), r0s.tolist(),
+            uniform.tolist())
+
+
+class DeliPartitionLambda(DocumentLambda):
+    """The deli partition's router, and the SERVING path of an op frame:
+    a maximal run of two or more consecutive ``opframe`` records of a
+    read chunk is ticketed in one pass (one gather, one ticket loop on
+    plain integers, one stamp, each ``SeqFrame`` a view of the run's
+    block) instead of a frame at a time. The outputs are, record for
+    record and bit for bit, what ``DocumentLambda.handler_batch`` over
+    ``DeliDocLambda.handler`` gives (``tests/test_deli_chunk_ticket.py``):
+    a frame that is not the steady-state one (a duplicate, a gap, refs
+    that differ, a stale ref, any nack, a sampled frame with its trace
+    stamps) goes through ``DeliDocLambda._handle_frame`` at its place in
+    the run, and every other kind of record through the generic router.
+    So does a frame with no frame beside it (a websocket's, one a pump):
+    there is nothing to share the gather and the stamp with, and on the
+    chip's host the pass cost a third more than ``_handle_frame`` there
+    (PERF.md §6, PR 35); from two frames on it costs less.
+
+    ``frames_batched`` / ``frames_single`` count the frames each way
+    (``PipelineFluidService.stats()``)."""
+
+    def __init__(self):
+        super().__init__(lambda doc_id, s: DeliDocLambda(doc_id, s))
+        self.frames_batched = 0
+        self.frames_single = 0
+
+    def handler_batch(self, recs) -> List[Tuple[str, str, Any]]:
+        """A read chunk as alternating stretches of op frames (the run
+        pass; a lone frame takes the generic router) and of other
+        records (the generic router), in record order; a failing record
+        raises :class:`BatchHandlerError` with the chunk's completed
+        prefix, as the generic router does."""
+        out: List[Tuple[str, str, Any]] = []
+        n = len(recs)
+        i = 0
+        while i < n:
+            framed = recs[i].value.get("t") == "opframe"
+            j = i + 1
+            while j < n and (recs[j].value.get("t") == "opframe") == framed:
+                j += 1
+            run = recs[i:j]
+            try:
+                if framed and j - i > 1:
+                    out.extend(self._ticket_run(run))
+                else:
+                    self.frames_single += framed
+                    out.extend(super().handler_batch(run))
+            except BatchHandlerError as be:
+                raise BatchHandlerError(
+                    out + be.outputs, i + be.n_ok, be.cause
+                ) from be.cause
+            i = j
+        return out
+
+    def _ticket_run(self, run) -> List[Tuple[str, str, Any]]:
+        gathered = _gather_frames(run)
+        if gathered is None:
+            self.frames_single += len(run)
+            return super().handler_batch(run)
+        block, ns, csn0s, r0s, uniform = gathered
+        out: List[Tuple[str, str, Any]] = []
+        docs, dirty = self._docs, self._dirty
+        journal_on = journal._ON
+        # Per frame, for the one stamp: (the first sequence number less
+        # the frame's first row: a row's F_SEQ is that plus its own index
+        # in the block; the MSN; the client).
+        stamps: List[Tuple[int, int, int]] = []
+        single = lo = 0
+        prof = profiler._ON  # the armed-only ticket lane: once per run
+        if prof:
+            t_tk0 = time.perf_counter()
+        now = time.time()
+        try:
+            for rec, n, csn0, r0, same in zip(run, ns, csn0s, r0s, uniform):
+                key = rec.key
+                value = rec.value
+                lam = docs.get(key)
+                if lam is None:
+                    lam = docs[key] = self._factory(key, None)
+                dirty.add(key)
+                client = value["client"]
+                t = None
+                if same and value.get("traces") is None:
+                    t = lam.sequencer.ticket_uniform(client, csn0, n, r0, now)
+                if t is None:
+                    out.extend(lam._handle_frame(key, value))
+                    single += 1
+                    # Its ticket read the clock after this run's: a later
+                    # frame of the same document must not be stamped
+                    # with an earlier time.
+                    now = time.time()
+                    stamps.append((0, 0, 0))  # its rows here are nobody's
+                else:
+                    frame = value["frame"]
+                    if journal_on:
+                        journal.record(
+                            "frame.ticket", doc=key, seq=t[0],
+                            seq_hi=t[0] + n - 1, csn=csn0,
+                            csn_hi=csn0 + n - 1, client=client,
+                        )
+                    out.append((DELTAS_TOPIC, key, {
+                        "t": "seqframe",
+                        "frame": SeqFrame(
+                            frame.address, client, csn0, block[lo : lo + n],
+                            frame.texts, now,
+                        ),
+                    }))
+                    stamps.append((t[0] - lo, t[1], client))
+                lo += n
+        except Exception as e:
+            raise BatchHandlerError(out, len(stamps), e) from e
+        finally:
+            # The one stamp, also of the prefix a failing record leaves:
+            # its frames are out already, as views of the block.
+            m = len(stamps)
+            self.frames_single += single
+            self.frames_batched += m - single
+            if lo:
+                rows = np.array(stamps, np.int32).repeat(ns[:m], axis=0)
+                block[:lo, F_SEQ] = rows[:, 0] + _arange(lo)
+                block[:lo, F_MSN] = rows[:, 1]
+                block[:lo, F_CLIENT] = rows[:, 2]
+            if prof:
+                profiler.record(
+                    "ticket", t_tk0, time.perf_counter(), rows=lo
+                )
         return out
 
 

@@ -32,7 +32,7 @@ from fluidframework_tpu.service.lambdas import (
     SIGNALS_TOPIC,
     BroadcasterLambda,
     CheckpointStore,
-    DeliDocLambda,
+    DeliPartitionLambda,
     DocOpLog,
     DocumentLambda,
     PartitionRunner,
@@ -312,7 +312,7 @@ class PipelineFluidService:
 
     def _make_deli(self, checkpoint_every: int) -> PartitionRunner:
         def factory(p: int, state):
-            lam = DocumentLambda(lambda doc_id, s: DeliDocLambda(doc_id, s))
+            lam = DeliPartitionLambda()
             lam.restore_docs(state)
             return lam
 
@@ -366,6 +366,18 @@ class PipelineFluidService:
             runners.append(self._moira)
         for r in runners:
             r.checkpoint()
+
+    def stats(self) -> dict:
+        """The pipeline's own always-on counts (the device stage's are
+        ``self.device.stats()``): how many op frames deli's run pass
+        ticketed and how many it handed to the per-record path, over the
+        deli partitions as they stand (a ``crash_deli`` starts them at
+        zero, as a restarted process would)."""
+        lams = self._deli._lambdas.values()
+        return {
+            "deli_frames_batched": sum(lam.frames_batched for lam in lams),
+            "deli_frames_single": sum(lam.frames_single for lam in lams),
+        }
 
     # -- the pipeline pump -----------------------------------------------------
 
@@ -764,7 +776,9 @@ class PipelineFluidService:
 
     def submit_frame(self, doc_id: str, client_id: int, frame) -> None:
         """Front-door ingest for the batched binary wire: one raw record
-        per frame; deli tickets it vectorized (sequencer.ticket_frame).
+        per frame; deli tickets it with the read chunk's other frames
+        (lambdas.DeliPartitionLambda; sequencer.ticket_frame is the
+        reference).
         Sampled frames (alfred's 1-in-N gate, same knob as the per-op
         wire) carry a trace list on the RECORD envelope — the binary
         frame wire itself never changes — stamped at every stage

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Union
+from typing import Dict, List, Optional, Tuple, Union
 
 from fluidframework_tpu.protocol.constants import MAX_WRITERS
 from fluidframework_tpu.telemetry import tracing
@@ -292,6 +292,38 @@ class DocumentSequencer:
             traces=traces,
         )
 
+    def ticket_uniform(
+        self, client_id: int, csn0: int, n: int, r0: int, now: float
+    ) -> Optional[Tuple[int, int]]:
+        """The steady-state ticket on plain integers: ``n >= 1`` ops of a
+        known writer, next in its csn order, all authored against the one
+        refSeq ``r0`` at or above the MSN. Returns ``(seq0, msn)`` — the
+        run's first sequence number and the one MSN every op of it
+        carries, ``max(floor, min(r0, the other clients' refSeqs))`` —
+        or None, HAVING CHANGED NOTHING, for anything else: the caller
+        then takes :meth:`ticket_frame`, whose checks say which nack,
+        duplicate or partial ticket it is."""
+        entry = self.clients.get(client_id)
+        if (
+            entry is None or entry.mode != "write"
+            or self._nack_all is not None
+            or csn0 != entry.client_seq + 1 or r0 < self.min_seq
+        ):
+            return None
+        floor = r0
+        for c in self.clients.values():
+            if c.ref_seq < floor and c is not entry:
+                floor = c.ref_seq
+        if floor < self.min_seq:
+            floor = self.min_seq
+        entry.client_seq = csn0 + n - 1
+        entry.ref_seq = r0
+        entry.last_seen = now
+        seq0 = self.seq + 1
+        self.seq += n
+        self.min_seq = floor
+        return seq0, floor
+
     def ticket_frame(
         self, client_id: int, csn0: int, n: int, refs
     ) -> Union["FrameTicket", NackMessage, None]:
@@ -305,6 +337,15 @@ class DocumentSequencer:
         Returns a :class:`FrameTicket` (drop count, valid count, seq0,
         per-op msn array), a NackMessage (``client_sequence_number`` =
         first rejected csn), or None when every op is a replay duplicate.
+
+        This is the REFERENCE for a frame's ticket and the path of every
+        frame that is not the steady-state one (a duplicate, a gap, refs
+        that differ, a stale ref, any nack). The serving path is deli's
+        run pass (``lambdas.DeliPartitionLambda``), which reads a read
+        chunk's frames at once and calls :meth:`ticket_uniform` on plain
+        integers; ``tests/test_deli_chunk_ticket.py`` holds the two
+        bit-equal, ``tests/test_opframe.py`` holds this one to n
+        ``ticket()`` calls.
         """
         import numpy as np
 
@@ -334,33 +375,20 @@ class DocumentSequencer:
             )
         # Fast path — the steady-state serving stream: no dup prefix and
         # every op in the frame shares one refSeq (a client-turn batch
-        # authored against one head). MSN per op is then a constant:
-        # max(floor, min(r0, others_min)), no per-op pass at all.
+        # authored against one head): :meth:`ticket_uniform`, the state
+        # machine deli's run pass calls directly.
         now = time.time()
         if drop == 0:
             r0 = int(refs[0])
-            if r0 == int(refs[-1]) and r0 >= self.min_seq and (
+            if r0 == int(refs[-1]) and (
                 n < 3 or (np.asarray(refs) == r0).all()
             ):
-                others_min = None
-                for c in self.clients.values():
-                    if c.client_id != client_id and (
-                        others_min is None or c.ref_seq < others_min
-                    ):
-                        others_min = c.ref_seq
-                floor = r0 if others_min is None else min(r0, others_min)
-                if floor < self.min_seq:
-                    floor = self.min_seq
-                entry.client_seq = csn0 + n - 1
-                entry.ref_seq = r0
-                entry.last_seen = now
-                seq0 = self.seq + 1
-                self.seq += n
-                self.min_seq = floor
-                return FrameTicket(
-                    drop=0, m=n, seq0=seq0,
-                    msn=np.full(n, floor, np.int32), timestamp=now,
-                )
+                t = self.ticket_uniform(client_id, csn0, n, r0, now)
+                if t is not None:
+                    return FrameTicket(
+                        drop=0, m=n, seq0=t[0],
+                        msn=np.full(n, t[1], np.int32), timestamp=now,
+                    )
         # General path (per-op semantics in one pass): op i is stale
         # against the MSN established by op i-1 (the freshly advanced
         # floor per-op ticket() checks), and msn_i = max(floor,

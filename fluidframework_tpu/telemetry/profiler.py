@@ -102,7 +102,7 @@ LANES: Dict[str, str] = {
     "host_stage": "the _stage_host host Python: buffer drain + boxcar "
                   "assembly + watermark bookkeeping",
     "ring_put": "async device_put of the assembled boxcar into a ring slot",
-    "ticket": "the native/vectorized ticket_frame call (deli)",
+    "ticket": "deli's ticket of a run of op frames, or of one (ticket_frame)",
     "dispatch": "AOT donated dispatch submission + scan begin "
                 "(_dispatch_one's device half — enqueue cost)",
     "device_step": "dispatch issued → that boxcar's health-scan readback "
