@@ -46,6 +46,15 @@ def parse_url(url: str):
     return host, int(port), tenant, doc
 
 
+class ConnectRefused(ConnectionError):
+    """The server's ``connect_document_error``. ``retry_after_s`` > 0: a
+    refusal for now, to be asked again after that pause."""
+
+    def __init__(self, message: str, retry_after_s: float = 0.0):
+        super().__init__(message)
+        self.retry_after_s = retry_after_s
+
+
 class RestBlobBackend:
     """SummaryStore backend over the server's /blobs routes (historian)."""
 
@@ -140,6 +149,7 @@ class NetworkConnection:
         self._lock = threading.Lock()
         self._connected = threading.Event()
         self._error: Optional[str] = None
+        self._retry_after_s = 0.0
 
         self._sock, self._decoder, self._pending = _ws_client_connect(
             host, port
@@ -163,7 +173,7 @@ class NetworkConnection:
             if not self._connected.wait(10):
                 raise ConnectionError("connect_document timed out")
             if self._error is not None:
-                raise ConnectionError(self._error)
+                raise ConnectRefused(self._error, self._retry_after_s)
             if self.client_id < 0:
                 # Socket dropped before connect_document_success arrived.
                 raise ConnectionError("connection closed before join completed")
@@ -241,6 +251,7 @@ class NetworkConnection:
             self._connected.set()
         elif t == "connect_document_error":
             self._error = msg.get("error", "connect failed")
+            self._retry_after_s = float(msg.get("retry_after_ms") or 0.0) / 1e3
             self._connected.set()
         elif t == "op":
             self._ingest(from_jsonable(msg["msg"]))
@@ -398,6 +409,10 @@ class NetworkFluidService:
         # stalling (e.g. the op socket busy with a large submit).
         self.push = push
         self._store: Optional[SummaryStore] = None
+        # How long a connect keeps asking again after refusals that carry
+        # a retry-after, and how often one did.
+        self.connect_patience_s = 30.0
+        self.connect_retries = 0
 
     def _auth(self, doc_id: str) -> str:
         if self.key is None:
@@ -413,10 +428,30 @@ class NetworkFluidService:
             if self.key
             else ""
         )
-        return NetworkConnection(
-            self.host, self.port, doc_id, self.tenant, token, mode, from_seq,
-            push=self.push,
+        return self._connect_patiently(
+            lambda: NetworkConnection(
+                self.host, self.port, doc_id, self.tenant, token, mode,
+                from_seq, push=self.push,
+            )
         )
+
+    def _connect_patiently(self, dial):
+        """A join refused FOR NOW (``connect_document_error`` with a
+        retry-after: the document's writer slots are all taken until the
+        MSN passes a leave) is asked again after the pause the server
+        named, for ``connect_patience_s`` in all; any other refusal, and
+        the last of these, raises."""
+        give_up = time.monotonic() + self.connect_patience_s
+        while True:
+            try:
+                return dial()
+            except ConnectRefused as e:
+                if e.retry_after_s <= 0 or (
+                    time.monotonic() + e.retry_after_s > give_up
+                ):
+                    raise
+                self.connect_retries += 1
+                time.sleep(e.retry_after_s)
 
     def get_channel_text(self, doc_id: str, channel_id: str) -> str:
         """Read a string channel straight from the service's device-resident

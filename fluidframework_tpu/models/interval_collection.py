@@ -29,7 +29,10 @@ import itertools
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from fluidframework_tpu.ops.segment_state import removed_by_slot_host
+from fluidframework_tpu.ops.segment_state import (
+    rbits_of,
+    removed_by_slot_host,
+)
 from fluidframework_tpu.protocol.constants import (
     KIND_FREE,
     RSEQ_NONE,
@@ -58,7 +61,7 @@ def _visible_len(h, i: int, *, ref_seq: Optional[int], client: int) -> int:
         return 0
     rseq = int(h.rseq[i])
     by_client = client >= 0 and removed_by_slot_host(
-        int(h.rbits[i]), int(h.rbits2[i]), int(h.rbits3[i]), client
+        [lane[i] for lane in rbits_of(h)], client
     )
     removed = by_client or (
         rseq not in (RSEQ_NONE, UNASSIGNED_SEQ) and rseq <= ref_seq

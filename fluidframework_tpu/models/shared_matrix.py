@@ -21,6 +21,7 @@ import numpy as np
 from fluidframework_tpu.ops import encode as E
 from fluidframework_tpu.ops.merge_kernel import compact, jit_apply_ops
 from fluidframework_tpu.ops.segment_state import (
+    SEGMENT_LANES,
     capacity_of,
     grow,
     make_interactive_state,
@@ -232,11 +233,7 @@ class SharedMatrix(SharedObject):
             return {
                 "lanes": {
                     k: np.asarray(getattr(h, k))[:n].tolist()
-                    for k in (
-                        "kind", "orig", "off", "length", "seq", "client",
-                        "lseq", "rseq", "rlseq", "rbits", "rbits2", "rbits3", "aseq", "alseq",
-                        "aval",
-                    )
+                    for k in SEGMENT_LANES
                 },
                 "count": n,
                 "min_seq": int(h.min_seq),

@@ -22,6 +22,7 @@ import numpy as np
 from fluidframework_tpu.ops import encode as E
 from fluidframework_tpu.ops.merge_kernel import compact, jit_apply_ops
 from fluidframework_tpu.ops.segment_state import (
+    SEGMENT_LANES,
     capacity_of,
     grow,
     make_interactive_state,
@@ -349,13 +350,15 @@ class SharedString(SharedObject):
         # the live rows genuinely outgrew it. Compaction timing is
         # replica-local and only touches invisible state, so replicas stay
         # convergent regardless of when each one compacts.
+        # (Read the one scalar: pulling every lane to the host to look at
+        # ``count`` made each applied op cost a copy a lane.)
         cap = capacity_of(self._state)
-        if int(to_host(self._state).count) > cap - 8:
+        if int(self._state.count) > cap - 8:
             # References must slide off acked-removed rows before compaction
             # reclaims them (A.9 eager slide).
             self._normalize_refs()
             self._state = compact(self._state)
-            if int(to_host(self._state).count) > cap - 8:
+            if int(self._state.count) > cap - 8:
                 self._state = grow(self._state, cap * 2)
 
     # -- reconnect rebase (reference regeneratePendingOp, client.ts:917) ------
@@ -463,11 +466,10 @@ class SharedString(SharedObject):
         h = to_host(self._state)
         n = int(h.count)
         return {
-            "lanes": {k: np.asarray(getattr(h, k))[:n].tolist() for k in (
-                "kind", "orig", "off", "length", "seq", "client", "lseq",
-                "rseq", "rlseq", "rbits", "rbits2", "rbits3", "aseq",
-                "alseq", "aval",
-            )},
+            "lanes": {
+                k: np.asarray(getattr(h, k))[:n].tolist()
+                for k in SEGMENT_LANES
+            },
             "count": n,
             "min_seq": int(h.min_seq),
             "cur_seq": int(h.cur_seq),

@@ -45,8 +45,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from fluidframework_tpu.ops.segment_state import (
+    RBITS_LANES,
     SEGMENT_LANES,
     SegmentState,
+    rbits_of,
     removed_by_slot,
     writer_bits,
 )
@@ -116,9 +118,7 @@ def perspective(state: SegmentState, ref_seq, client, is_local):
     # a pending local remove never hides a row from a remote op's view,
     # and a pending local insert is invisible unless client-matched.
     rseq_eff = jnp.where(state.rseq == UNASSIGNED_SEQ, RSEQ_NONE, state.rseq)
-    removed_by_client = removed_by_slot(
-        state.rbits, state.rbits2, state.rbits3, client
-    )
+    removed_by_client = removed_by_slot(rbits_of(state), client)
     hidden = removed & ((rseq_eff <= ref_seq) | removed_by_client)
     seq_eff = jnp.where(
         state.seq == UNASSIGNED_SEQ, NORM_EXISTING_LOCAL, state.seq
@@ -231,9 +231,7 @@ def _apply_insert(state: SegmentState, op: jnp.ndarray) -> SegmentState:
         lseq=z + jnp.where(op[F_SEQ] == UNASSIGNED_SEQ, op[F_LSEQ], 0),
         rseq=z + RSEQ_NONE,
         rlseq=z,
-        rbits=z,
-        rbits2=z,
-        rbits3=z,
+        **{k: z for k in RBITS_LANES},
         aseq=z,
         alseq=z,
         aval=z,
@@ -313,7 +311,7 @@ def _apply_remove(state: SegmentState, op: jnp.ndarray) -> SegmentState:
     )
 
     local_op = op[F_SEQ] == UNASSIGNED_SEQ
-    bit_lo, bit_mid, bit_hi = writer_bits(op[F_CLIENT])
+    bits = writer_bits(op[F_CLIENT])
     not_removed = state.rseq == RSEQ_NONE
     was_local = state.rseq == UNASSIGNED_SEQ
 
@@ -324,9 +322,10 @@ def _apply_remove(state: SegmentState, op: jnp.ndarray) -> SegmentState:
         cov,
         rseq=new_rseq,
         rlseq=new_rlseq,
-        rbits=state.rbits | bit_lo,
-        rbits2=state.rbits2 | bit_mid,
-        rbits3=state.rbits3 | bit_hi,
+        **{
+            k: lane | bit
+            for k, lane, bit in zip(RBITS_LANES, rbits_of(state), bits)
+        },
     )
     return _bookkeep(state, op)
 

@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fluidframework_tpu.ops.segment_state import (
+    RBITS_LANES,
     SEGMENT_LANES,
     SegmentState,
     removed_by_slot,
@@ -76,6 +77,24 @@ from fluidframework_tpu.protocol.constants import (
 
 _I32 = jnp.int32
 N_LANES = len(SEGMENT_LANES)
+# The body names the plain lanes and walks the removers lanes as a list.
+_PLAIN = tuple(k for k in SEGMENT_LANES if k not in RBITS_LANES)
+_I_PLAIN = tuple(SEGMENT_LANES.index(k) for k in _PLAIN)
+_I_RBITS = tuple(SEGMENT_LANES.index(k) for k in RBITS_LANES)
+_I_OFF, _I_LEN = SEGMENT_LANES.index("off"), SEGMENT_LANES.index("length")
+
+
+def _named(lanes):
+    """(the plain lanes in ``_PLAIN`` order, the removers lanes)."""
+    return [lanes[i] for i in _I_PLAIN], [lanes[i] for i in _I_RBITS]
+
+
+def _packed(plain, rb):
+    """Inverse of :func:`_named`: the lanes in SEGMENT_LANES order."""
+    lanes = [None] * N_LANES
+    for i, x in zip(_I_PLAIN + _I_RBITS, list(plain) + list(rb)):
+        lanes[i] = x
+    return lanes
 # Scalar pack layout (lane dim of the [D, N_SCALARS] array).
 SC_COUNT, SC_MIN_SEQ, SC_CUR_SEQ, SC_SELF, SC_ERR = range(5)
 N_SCALARS = 8  # padded for sublane friendliness
@@ -129,8 +148,8 @@ def _apply_values(ops_ref, tables_ref, scalars_ref):
 
     def step(k, carry):
         lanes, count, min_seq, cur_seq, self_client, err = carry
-        (kind, orig, off, length, seq, client, lseq, rseq, rlseq, rbits,
-         rbits2, rbits3, aseq, alseq, aval) = lanes
+        (kind, orig, off, length, seq, client, lseq, rseq, rlseq, aseq,
+         alseq, aval), rb = _named(lanes)
 
         op = jnp.reshape(ops_ref[pl.ds(k, 1), :, :], (b, OP_WIDTH))
 
@@ -150,16 +169,13 @@ def _apply_values(ops_ref, tables_ref, scalars_ref):
         is_local = clientn == self_client
 
         # -- perspective (merge_kernel.perspective, mergeTree.ts:916-1004) --
-        def perspective(kind_, seq_, client_, length_, rseq_, rbits_,
-                        rbits2_, rbits3_):
+        def perspective(kind_, seq_, client_, length_, rseq_, rb_):
             live = kind_ != KIND_FREE
             removed = rseq_ != RSEQ_NONE
             r_acked = removed & (rseq_ != UNASSIGNED_SEQ)
             skip = r_acked & (rseq_ <= min_seq)
             rseq_eff = jnp.where(rseq_ == UNASSIGNED_SEQ, RSEQ_NONE, rseq_)
-            removed_by_client = removed_by_slot(
-                rbits_, rbits2_, rbits3_, clientn
-            )
+            removed_by_client = removed_by_slot(rb_, clientn)
             hidden = removed & ((rseq_eff <= refn) | removed_by_client)
             seq_eff = jnp.where(seq_ == UNASSIGNED_SEQ, NORM_EXISTING_LOCAL, seq_)
             ins_vis = (client_ == clientn) | (seq_eff <= refn)
@@ -169,8 +185,7 @@ def _apply_values(ops_ref, tables_ref, scalars_ref):
             part = live & ~skip
             return part, jnp.where(part, vis, 0)
 
-        part, vis = perspective(kind, seq, client, length, rseq, rbits,
-                                rbits2, rbits3)
+        part, vis = perspective(kind, seq, client, length, rseq, rb)
         prefix = _excl_cumsum(vis)
         total = jnp.sum(vis, axis=1, keepdims=True)
         rem1 = pos1 - prefix
@@ -212,9 +227,11 @@ def _apply_values(ops_ref, tables_ref, scalars_ref):
             | jnp.where(clientn >= MAX_WRITERS, ERR_CLIENT, 0)
         )
 
-        lanes = [kind, orig, off, length, seq, client, lseq, rseq, rlseq,
-                 rbits, rbits2, rbits3, aseq, alseq, aval]
-        I_OFF, I_LEN = 2, 3
+        lanes = _packed(
+            (kind, orig, off, length, seq, client, lseq, rseq, rlseq, aseq,
+             alseq, aval), rb,
+        )
+        I_OFF, I_LEN = _I_OFF, _I_LEN
 
         # -- split A at pos1 (insert mid-segment or range start) -----------
         do_a = do_a_rng | (do_ins & has1)
@@ -240,24 +257,20 @@ def _apply_values(ops_ref, tables_ref, scalars_ref):
         q_i = jnp.where(has1, idx1 + 1, idxp)
         lanes = shift1(lanes, do_ins, q_i, strict=False)
         m_new = do_ins & (col == q_i)
-        new_row = [
-            jnp.full((b, s), KIND_TEXT, _I32),  # kind
-            jnp.broadcast_to(arg, (b, s)),  # orig
-            jnp.zeros((b, s), _I32),  # off
-            jnp.broadcast_to(ilen, (b, s)),  # length
-            jnp.broadcast_to(seqn, (b, s)),  # seq
-            jnp.broadcast_to(clientn, (b, s)),  # client
-            jnp.broadcast_to(jnp.where(local_op, lseqn, 0), (b, s)),  # lseq
-            jnp.full((b, s), RSEQ_NONE, _I32),  # rseq
-            jnp.zeros((b, s), _I32),  # rlseq
-            jnp.zeros((b, s), _I32),  # rbits
-            jnp.zeros((b, s), _I32),  # rbits2
-            jnp.zeros((b, s), _I32),  # rbits3
-            jnp.zeros((b, s), _I32),  # aseq
-            jnp.zeros((b, s), _I32),  # alseq
-            jnp.zeros((b, s), _I32),  # aval
+        new_row = {  # every lane not named here starts at zero
+            "kind": jnp.full((b, s), KIND_TEXT, _I32),
+            "orig": jnp.broadcast_to(arg, (b, s)),
+            "length": jnp.broadcast_to(ilen, (b, s)),
+            "seq": jnp.broadcast_to(seqn, (b, s)),
+            "client": jnp.broadcast_to(clientn, (b, s)),
+            "lseq": jnp.broadcast_to(jnp.where(local_op, lseqn, 0), (b, s)),
+            "rseq": jnp.full((b, s), RSEQ_NONE, _I32),
+        }
+        zero_row = jnp.zeros((b, s), _I32)
+        lanes = [
+            jnp.where(m_new, new_row.get(k, zero_row), x)
+            for k, x in zip(SEGMENT_LANES, lanes)
         ]
-        lanes = [jnp.where(m_new, nv, x) for nv, x in zip(new_row, lanes)]
 
         count = jnp.where(
             is_range,
@@ -265,12 +278,11 @@ def _apply_values(ops_ref, tables_ref, scalars_ref):
             jnp.where(do_ins, count + sh, count),
         )
 
-        (kind, orig, off, length, seq, client, lseq, rseq, rlseq, rbits,
-         rbits2, rbits3, aseq, alseq, aval) = lanes
+        (kind, orig, off, length, seq, client, lseq, rseq, rlseq, aseq,
+         alseq, aval), rb = _named(lanes)
 
         # -- covered rows (post-split perspective; _covered/nodeMap) -------
-        part2, vis2 = perspective(kind, seq, client, length, rseq, rbits,
-                                  rbits2, rbits3)
+        part2, vis2 = perspective(kind, seq, client, length, rseq, rb)
         prefix2 = _excl_cumsum(vis2)
         cov = (
             part2
@@ -283,16 +295,16 @@ def _apply_values(ops_ref, tables_ref, scalars_ref):
         m_rem = cov & is_rem
         not_removed = rseq == RSEQ_NONE
         was_local = rseq == UNASSIGNED_SEQ
-        bit_lo, bit_mid, bit_hi = writer_bits(clientn)
         rseq = jnp.where(
             m_rem & (not_removed | was_local), jnp.broadcast_to(seqn, (b, s)), rseq
         )
         rlseq = jnp.where(
             m_rem & not_removed & local_op, jnp.broadcast_to(lseqn, (b, s)), rlseq
         )
-        rbits = jnp.where(m_rem, rbits | bit_lo, rbits)
-        rbits2 = jnp.where(m_rem, rbits2 | bit_mid, rbits2)
-        rbits3 = jnp.where(m_rem, rbits3 | bit_hi, rbits3)
+        rb = [
+            jnp.where(m_rem, x | bit, x)
+            for x, bit in zip(rb, writer_bits(clientn))
+        ]
 
         # -- annotate marks (annotateRange; single-lane LWW) ---------------
         pending = alseq != 0
@@ -325,8 +337,10 @@ def _apply_values(ops_ref, tables_ref, scalars_ref):
         cur_seq = jnp.maximum(cur_seq, seqn)
         min_seq = jnp.maximum(min_seq, msn)
 
-        lanes = [kind, orig, off, length, seq, client, lseq, rseq, rlseq,
-                 rbits, rbits2, rbits3, aseq, alseq, aval]
+        lanes = _packed(
+            (kind, orig, off, length, seq, client, lseq, rseq, rlseq, aseq,
+             alseq, aval), rb,
+        )
         return lanes, count, min_seq, cur_seq, self_client, err
 
     lanes0 = [tables_ref[i] for i in range(N_LANES)]
@@ -381,13 +395,14 @@ def _on_tpu() -> bool:
 
 # What the v5e compiler charges a kernel of this family: its scoped VMEM
 # stack (in/out blocks double-buffered, the loop-carried lanes, the body's
-# temporaries) came to 346-411 bytes per (doc, row) cell of the block.
+# temporaries) came to 346-411 bytes per (doc, row) cell of the block at
+# 15 lanes and 459 at 16: it goes with the lanes, so the grant does.
 # A block of 2^15 cells sits inside Mosaic's default 16 MB; bigger tiers
 # cannot go under 8 docs (the sublane tile), so they ask for more VMEM,
 # up to what the chip's 128 MiB leaves the compiler. Eight docs of 65,536
 # rows need 156 MB and do not fit: the fleet's top tier is 32,768.
 _BLOCK_CELLS = 1 << 15
-_VMEM_BYTES_PER_CELL = 448
+_VMEM_BYTES_PER_CELL = 30 * N_LANES
 _VMEM_CEILING = 120 << 20
 
 

@@ -51,6 +51,7 @@ class SegmentState(NamedTuple):
     aseq: jnp.ndarray  # seq of last annotate (0 = never)
     alseq: jnp.ndarray  # local seq of pending annotate (0 = none)
     aval: jnp.ndarray  # interned annotate value
+    rbits4: jnp.ndarray  # bitmask of removing client slots 93-123
     # --- per-document scalars ---
     count: jnp.ndarray  # high-water mark of used rows
     min_seq: jnp.ndarray  # collab-window minimum sequence number
@@ -75,7 +76,23 @@ SEGMENT_LANES = (
     "aseq",
     "alseq",
     "aval",
+    "rbits4",
 )
+
+# The removers set (reference ``removedClientIds``): one bit per writer
+# slot, RBITS_PER_LANE usable bits to an int32 lane (the sign bit stays
+# out of the shift arithmetic), lane i holding slots 31*i .. 31*i+30.
+# Everything that reads or writes the set walks this tuple; a wider set is
+# one more name here (appended to the END of SEGMENT_LANES: every packed
+# index derives from that order), one more field above, and the constant.
+RBITS_LANES = ("rbits", "rbits2", "rbits3", "rbits4")
+RBITS_PER_LANE = 31
+assert MAX_WRITERS == RBITS_PER_LANE * len(RBITS_LANES), MAX_WRITERS
+
+
+def rbits_of(state) -> tuple:
+    """The removers lanes of a state (or of a host copy), in slot order."""
+    return tuple(getattr(state, k) for k in RBITS_LANES)
 
 
 def interactive_device():
@@ -132,6 +149,7 @@ def make_state(capacity: int, self_client: int, min_seq: int = 0) -> SegmentStat
         aseq=z(),
         alseq=z(),
         aval=z(),
+        rbits4=z(),
         count=jnp.int32(0),
         min_seq=jnp.int32(min_seq),
         cur_seq=jnp.int32(0),
@@ -169,21 +187,19 @@ def grow(state: SegmentState, new_capacity: int) -> SegmentState:
     )
 
 
-def removed_by_slot(rbits, rbits2, rbits3, client):
-    """Whether the writer slot appears in the three-lane removers bitmask
-    (slots 0-30 / 31-61 / 62-92; 31 usable bits per int32 lane keeps the
-    sign bit out of shift arithmetic). Pure jnp (broadcastable) — shared
-    by the XLA and Pallas perspectives; host code can pass plain ints
-    through jnp and cast the result."""
+def removed_by_slot(rbits, client):
+    """Whether the writer slot appears in the removers bitmask ``rbits``
+    (the lanes of :data:`RBITS_LANES`, in order). Pure jnp
+    (broadcastable) — shared by the XLA and Pallas perspectives."""
     # Arithmetic lane select (masked blends + one shift): Mosaic fails to
     # lower a broadcasting select over the shifted lanes.
     client = jnp.asarray(client, jnp.int32)
-    lane = jnp.clip(client // 31, 0, 2)
-    is0 = (lane == 0).astype(jnp.int32)
-    is1 = (lane == 1).astype(jnp.int32)
-    is2 = (lane == 2).astype(jnp.int32)
-    bits = rbits * is0 + rbits2 * is1 + rbits3 * is2
-    shift = jnp.clip(client - 31 * lane, 0, 30)
+    lane = jnp.clip(client // RBITS_PER_LANE, 0, len(rbits) - 1)
+    bits = sum(
+        lane_bits * (lane == i).astype(jnp.int32)
+        for i, lane_bits in enumerate(rbits)
+    )
+    shift = jnp.clip(client - RBITS_PER_LANE * lane, 0, RBITS_PER_LANE - 1)
     # Out-of-range slots (negative sentinels, >= MAX_WRITERS) must read
     # as not-removed rather than aliasing the clipped lane's bits — the
     # sequencer nacks writer MAX_WRITERS+, but this guard keeps the read
@@ -192,31 +208,29 @@ def removed_by_slot(rbits, rbits2, rbits3, client):
     return (((bits >> shift) & 1) == 1) & in_range
 
 
-def removed_by_slot_host(rbits: int, rbits2: int, rbits3: int,
-                         client: int) -> bool:
+def removed_by_slot_host(rbits, client: int) -> bool:
     """Host-int twin of removed_by_slot for per-row Python loops (a jnp
-    call per row would cost a device dispatch each). Same slot layout —
-    keep the two in this module so the mapping has one home."""
+    call per row would cost a device dispatch each): ``rbits`` is one
+    row's lane values as ints. Same slot layout — keep the two in this
+    module so the mapping has one home."""
     if client < 0 or client >= MAX_WRITERS:
         return False
-    if client < 31:
-        return bool((rbits >> client) & 1)
-    if client < 62:
-        return bool((rbits2 >> (client - 31)) & 1)
-    return bool((rbits3 >> (client - 62)) & 1)
+    lane, bit = divmod(client, RBITS_PER_LANE)
+    return bool((int(rbits[lane]) >> bit) & 1)
 
 
-def writer_bits(slot):
-    """(lo, mid, hi) single-bit masks for a writer slot: slots 0-30 set a
-    bit in the ``rbits`` lane, 31-61 in ``rbits2``, 62-92 in ``rbits3``
-    (31 usable bits per int32 lane keeps the sign bit out of shift
-    arithmetic)."""
+def writer_bits(slot) -> tuple:
+    """One single-bit mask per removers lane for a writer slot: the lane
+    that holds the slot carries its bit, the others 0."""
     s = jnp.asarray(slot, jnp.int32)
-    lo = jnp.where(s < 31, jnp.int32(1) << jnp.clip(s, 0, 30), 0)
-    mid = jnp.where((s >= 31) & (s < 62),
-                    jnp.int32(1) << jnp.clip(s - 31, 0, 30), 0)
-    hi = jnp.where(s >= 62, jnp.int32(1) << jnp.clip(s - 62, 0, 30), 0)
-    return lo.astype(jnp.int32), mid.astype(jnp.int32), hi.astype(jnp.int32)
+    n = RBITS_PER_LANE
+    return tuple(
+        jnp.where(
+            (s >= n * i) & (s < n * (i + 1)),
+            jnp.int32(1) << jnp.clip(s - n * i, 0, n - 1), 0,
+        ).astype(jnp.int32)
+        for i in range(len(RBITS_LANES))
+    )
 
 
 def adopt_client_slot(state: SegmentState, new_client_id: int) -> SegmentState:
@@ -234,19 +248,14 @@ def adopt_client_slot(state: SegmentState, new_client_id: int) -> SegmentState:
 
     pending_ins = state.seq == UNASSIGNED_SEQ
     pending_rem = state.rlseq > 0
-    old_lo, old_mid, old_hi = writer_bits(state.self_client)
-    new_lo, new_mid, new_hi = writer_bits(jnp.int32(new_client_id))
+    old = writer_bits(state.self_client)
+    new = writer_bits(jnp.int32(new_client_id))
     return state._replace(
         client=jnp.where(pending_ins, new_client_id, state.client),
-        rbits=jnp.where(
-            pending_rem, (state.rbits & ~old_lo) | new_lo, state.rbits
-        ),
-        rbits2=jnp.where(
-            pending_rem, (state.rbits2 & ~old_mid) | new_mid, state.rbits2
-        ),
-        rbits3=jnp.where(
-            pending_rem, (state.rbits3 & ~old_hi) | new_hi, state.rbits3
-        ),
+        **{
+            k: jnp.where(pending_rem, (lane & ~o) | n, lane)
+            for k, lane, o, n in zip(RBITS_LANES, rbits_of(state), old, new)
+        },
         self_client=jnp.int32(new_client_id),
     )
 

@@ -49,27 +49,34 @@ F_MSN = 9  # minimum sequence number rider (advances the collab window)
 OP_WIDTH = 10
 
 # Cap on concurrent writers per document: remover sets are stored as
-# THREE int32 bitmask lanes (rbits: slots 0-30, rbits2: 31-61, rbits3:
-# 62-92; 31 usable bits per lane keeps the sign bit out of the
-# arithmetic). The reference stores removedClientIds as a list
-# (mergeTreeNodes.ts) with a 1M-client config cap; 93 *concurrent*
+# FOUR int32 bitmask lanes (rbits: slots 0-30, rbits2: 31-61, rbits3:
+# 62-92, rbits4: 93-123; 31 usable bits per lane keeps the sign bit out
+# of the arithmetic). The reference stores removedClientIds as a list
+# (mergeTreeNodes.ts) with a 1M-client config cap; 124 *concurrent*
 # writers per document with slot recycling (service/sequencer.py) covers
-# the same sessions over time.
+# the same sessions over time, and the reference's own service load test
+# (test-service-load, profile ci: 120 clients in one document) with four
+# slots to spare for its reconnects.
 #
 # SCALING STORY (the formal contract for this ceiling): the cap counts
 # SIMULTANEOUS write connections to ONE document, not sessions — slots
-# recycle on leave (sequencer.py:96-137), writer 94 gets a clean
-# ERR_CLIENT + nack rather than corruption, and read connections are
-# unlimited. Widening is mechanical and O(lanes): each extra int32 lane
-# (rbits4, ...) adds 31 slots at a cost of one [D, S] lane (~4 bytes/row)
-# through segment_state/merge_kernel/pallas_kernel's removed_by_slot and
-# the summary lane lists — the same pattern the rbits2 (r2) and rbits3
-# (r3) widenings followed. Append new lanes at the END of SEGMENT_LANES:
-# every packed index derives from that order. The cap is a per-build
-# constant rather than a runtime knob because lane count fixes compiled
-# kernel shapes; deployments needing more concurrent writers per doc
-# rebuild with more lanes, trading HBM per row.
-MAX_WRITERS = 93
+# recycle on leave once the leave's seq is at or under the MSN
+# (sequencer.py ``join``), a join that meets the cap gets a 429 nack with
+# a retry-after and the client's connect comes back after it
+# (network_driver), writer 125 would get a clean ERR_CLIENT rather than
+# corruption, and read connections are unlimited. Widening is mechanical
+# and O(lanes): each extra int32 lane adds 31 slots at a cost of one
+# [D, S] lane (~4 bytes/row, +6.7% of a step's bytes at 15 -> 16 lanes)
+# for every document of every deployment. One name more in
+# segment_state.RBITS_LANES (appended at the END of SEGMENT_LANES: every
+# packed index derives from that order), one field more in SegmentState,
+# and this constant: the kernels, compaction, the fleet's step, the mesh
+# body, ShardedDoc and the summaries walk those tuples (PR 36 added
+# rbits4 that way; rbits2 and rbits3 were hand-written widenings). The
+# cap is a per-build constant rather than a runtime knob because lane
+# count fixes compiled kernel shapes; deployments needing more concurrent
+# writers per doc rebuild with more lanes, trading HBM per row.
+MAX_WRITERS = 124
 
 # Error flag bits in SegmentState.err.
 ERR_CAPACITY = 1  # segment table full; op dropped

@@ -38,6 +38,15 @@ class TombstoneError(Exception):
     """Access to a tombstoned (GC'd) object (garbageCollection.ts:415)."""
 
 
+# The collab-window heartbeat (reference container-loader
+# ``collabWindowTracker.ts``: ``defaultNoopTimeFrequency`` 2000 ms,
+# ``defaultNoopCountFrequency`` 50): a client that keeps receiving others'
+# ops and sends nothing of its own tells the service how far it has read,
+# or it would hold the document's MSN back for as long as it stays quiet.
+NOOP_TIME_FREQUENCY_S = 2.0
+NOOP_COUNT_FREQUENCY = 50
+
+
 class ContainerRuntime:
     """One client's runtime for one document."""
 
@@ -116,6 +125,14 @@ class ContainerRuntime:
         # time.sleep). throttle_waits counts paces for tests/telemetry.
         self.throttle_sleep: Callable[[float], None] = time.sleep
         self.throttle_waits = 0
+        # The collab-window heartbeat (CollabWindowTracker): others' ops
+        # processed since this client last sent anything, when the first
+        # of them was (on ``clock``, which tests replace), and the noops
+        # it made this client send.
+        self.clock: Callable[[], float] = time.monotonic
+        self._ops_since_send = 0
+        self._first_unsent_at = 0.0
+        self.heartbeat_noops = 0
         # Summary tracking (reference SummaryCollection / RunningSummarizer).
         self.last_summary_seq = 0
         self.summary_interval: Optional[int] = None  # auto-summarize period
@@ -202,6 +219,7 @@ class ContainerRuntime:
         if not self.connected:
             return False
         self.client_seq += 1
+        self._ops_since_send = 0  # the message says how far we have read
         try:
             self.connection.submit(
                 DocumentMessage(
@@ -294,6 +312,8 @@ class ContainerRuntime:
         (protocol/opframe.py) — the batched wire the service tickets and
         stages without per-op Python. Acks are unchanged: frames consume
         one clientSequenceNumber per op and come back expanded."""
+        if batch:
+            self._ops_since_send = 0  # an op carries our refSeq
         if self._try_send_frame(batch):
             return
         envelopes = [
@@ -402,6 +422,7 @@ class ContainerRuntime:
         at JS-turn end before the inbound DeltaQueue resumes).
         """
         self.flush()
+        self._collab_window_tick()
         msgs = self.connection.take_inbox(n)
         for msg in msgs:
             self._process_one(msg)
@@ -684,6 +705,8 @@ class ContainerRuntime:
                     msg.contents["handle"],
                     msg.contents["head"],
                 )
+        if msg.type == MessageType.OPERATION and not local:
+            self._collab_window_saw_op()
         self._check_proposals()
         self._maybe_auto_summarize()
         if self.on_op is not None:
@@ -853,11 +876,55 @@ class ContainerRuntime:
         self._regenerate_through_channels(to_replay)
 
     def send_noop(self) -> None:
-        """Flush our refSeq to the service so the MSN can advance (the
-        reference CollabWindowTracker's periodic noop). A noop lost to a
-        dead connection needs no recovery — the next connection's join
-        refreshes our refSeq server-side."""
-        self._submit_system(MessageType.NOOP)
+        """Flush our refSeq to the service so the MSN can advance, AT
+        ONCE: the reference's immediate noop (non-null contents), which
+        deli sequences like an op, so every client hears of the new MSN
+        with it. A noop lost to a dead connection needs no recovery — the
+        next connection's join refreshes our refSeq server-side."""
+        self._submit_system(MessageType.NOOP, "")
+
+    # -- the collab-window heartbeat (reference CollabWindowTracker) ----------
+
+    def _collab_window_saw_op(self) -> None:
+        """Another client's op was processed (``scheduleSequenceNumber
+        Update``): the NOOP_COUNT_FREQUENCY-th since this client last
+        sent anything makes it say so now; the first starts the timer
+        that :meth:`_collab_window_tick` reads."""
+        self._ops_since_send += 1
+        if self._ops_since_send == 1:
+            self._first_unsent_at = self.clock()
+        elif self._ops_since_send >= NOOP_COUNT_FREQUENCY:
+            self._heartbeat()
+
+    def _collab_window_tick(self) -> None:
+        """The tracker's timer, read at this client's turns (a runtime
+        has no loop of its own): others' ops were processed
+        NOOP_TIME_FREQUENCY_S ago or longer and nothing was sent since."""
+        if self._ops_since_send and (
+            self.clock() - self._first_unsent_at >= NOOP_TIME_FREQUENCY_S
+        ):
+            self._heartbeat()
+
+    def _heartbeat(self) -> None:
+        """The tracker's noop (null contents): our refSeq and nothing
+        else. Deli takes it in without sequencing it (``sequencer.
+        _take_noop``), so it takes no clientSequenceNumber either and
+        nothing comes back for it."""
+        self._ops_since_send = 0
+        if not self.connected or self._mode != "write":
+            return
+        try:
+            self.connection.submit(
+                DocumentMessage(
+                    client_sequence_number=self.client_seq,
+                    reference_sequence_number=self.ref_seq,
+                    type=MessageType.NOOP,
+                    contents=None,
+                )
+            )
+            self.heartbeat_noops += 1
+        except OSError:
+            self.connected = False
 
     def propose(self, key: str, value: Any) -> None:
         """Submit a quorum proposal (approved once MSN >= its seq). On a

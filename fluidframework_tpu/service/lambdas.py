@@ -61,6 +61,7 @@ from fluidframework_tpu.service.sequencer import (
     DocumentSequencer,
     FrameTicket,
     SequencerCheckpoint,
+    SequencerStats,
 )
 
 RAW_TOPIC = "rawdeltas"
@@ -361,8 +362,12 @@ class DeliDocLambda(PartitionLambda):
     lowers raw control/op records to sequenced messages on ``deltas`` (and
     signal numbers on ``signals``)."""
 
-    def __init__(self, doc_id: str, state: Optional[dict] = None):
+    def __init__(self, doc_id: str, state: Optional[dict] = None,
+                 partition: Optional["DeliPartitionLambda"] = None):
         self.doc_id = doc_id
+        # The partition's shared counts and its set of documents whose
+        # client noops wait for a carrier (None: a deli that stands alone).
+        self._partition = partition
         checkpoint = None
         self._signal_counter = 0
         # Monotone dedupe floor per service-signal group: an upstream
@@ -376,7 +381,10 @@ class DeliDocLambda(PartitionLambda):
             checkpoint = SequencerCheckpoint(**state["sequencer"])
             self._signal_counter = state["signals"]
             self._signal_basis = dict(state.get("signal_basis", {}))
-        self.sequencer = DocumentSequencer(doc_id, checkpoint)
+        self.sequencer = DocumentSequencer(
+            doc_id, checkpoint,
+            partition.stats if partition is not None else None,
+        )
 
     def state(self) -> dict:
         return {
@@ -434,7 +442,22 @@ class DeliDocLambda(PartitionLambda):
                 )
             elif res is not None:
                 out.append((DELTAS_TOPIC, key, {"t": "seq", "msg": res}))
-            # duplicates (None) are dropped silently (checkOrder)
+            # duplicates (None) are dropped silently (checkOrder); so is a
+            # client's collab-window noop, which took no sequence number:
+            # if it moved the MSN, the document waits for a carrier
+            # (``DeliPartitionLambda.noops_due``).
+            elif (
+                self._partition is not None
+                and self.sequencer.noop_pending_since is not None
+            ):
+                self._partition.noop_waiting.add(key)
+        elif t == "servernoop":
+            # The consolidation timer's record (reference deli sends its
+            # delayed noop through rawdeltas too, so a replay of the raw
+            # log tickets the same numbers).
+            res = self.sequencer.server_noop()
+            if res is not None:
+                out.append((DELTAS_TOPIC, key, {"t": "seq", "msg": res}))
         elif t == "opframe":
             out.extend(self._handle_frame(key, value))
         elif t == "summary_decision":
@@ -455,6 +478,8 @@ class DeliDocLambda(PartitionLambda):
                     return out  # replayed service signal: already sent
                 self._signal_basis[group] = basis
             self._signal_counter += 1
+            if self._partition is not None:
+                self._partition.signals_received += 1
             out.append(
                 (SIGNALS_TOPIC, key,
                  {"client": value["client"], "num": self._signal_counter,
@@ -604,12 +629,33 @@ class DeliPartitionLambda(DocumentLambda):
     (PERF.md §6, PR 35); from two frames on it costs less.
 
     ``frames_batched`` / ``frames_single`` count the frames each way
-    (``PipelineFluidService.stats()``)."""
+    (``PipelineFluidService.stats()``), ``stats`` is the ticket loop's
+    counts over the partition's documents, ``signals_received`` the
+    signals deli numbered, and ``noop_waiting`` the documents whose
+    client noops moved the MSN and wait for a message to carry it."""
 
     def __init__(self):
-        super().__init__(lambda doc_id, s: DeliDocLambda(doc_id, s))
+        super().__init__(lambda doc_id, s: DeliDocLambda(doc_id, s, self))
         self.frames_batched = 0
         self.frames_single = 0
+        self.stats = SequencerStats()
+        self.signals_received = 0
+        self.noop_waiting: set = set()
+
+    def noops_due(self, now: float) -> List[str]:
+        """The waiting documents that have sequenced nothing for the
+        consolidation time: each is due one ``servernoop`` record. A
+        document whose MSN a sequenced message has carried meanwhile
+        stops waiting."""
+        due = []
+        for key in list(self.noop_waiting):
+            lam = self._docs.get(key)
+            if lam is None or lam.sequencer.noop_pending_since is None:
+                self.noop_waiting.discard(key)
+            elif lam.sequencer.noop_due(now):
+                self.noop_waiting.discard(key)
+                due.append(key)
+        return due
 
     def handler_batch(self, recs) -> List[Tuple[str, str, Any]]:
         """A read chunk as alternating stretches of op frames (the run
@@ -1046,6 +1092,7 @@ class BroadcasterLambda(PartitionLambda):
 class SignalBroadcasterLambda(PartitionLambda):
     def __init__(self, rooms: Dict[str, list]):
         self.rooms = rooms
+        self.delivered = 0  # signals put on a connection's queue
 
     def handler(self, key: str, value: dict) -> List[Tuple[str, str, Any]]:
         from fluidframework_tpu.protocol.types import SignalMessage
@@ -1060,4 +1107,5 @@ class SignalBroadcasterLambda(PartitionLambda):
                     )
                 )
                 conn.delivered_signal = value["num"]
+                self.delivered += 1
         return []

@@ -333,6 +333,12 @@ class FluidNetworkServer:
         self.frames_received = 0
         self.frames_expanded = 0  # ingress frames per-op fallback-expanded
         self.frames_delivered = 0
+        # What the delivery sweep wrote to op sockets besides frames:
+        # sequenced messages on the JSON wire (one count a socket), and
+        # signals taken in and written out (one count a socket).
+        self.ops_delivered = 0
+        self.signals_received = 0
+        self.signals_delivered = 0
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
         self._started = threading.Event()
@@ -1052,6 +1058,13 @@ class FluidNetworkServer:
                     # fault) must not kill future ticks — the doc simply
                     # stays RESIDENT.
                     pass
+            # Noop consolidation's timer: a document whose client noops
+            # moved the MSN and that has been quiet for 250 ms gets its
+            # one server noop; the sweep's pump sequences it and the
+            # sockets get it.
+            noops_due = getattr(self.service, "noops_due", None)
+            if noops_due is not None and noops_due():
+                self._drain_all()
             if dev is None or not (
                 dev.needs_flush() or dev.needs_scan_drain()
             ):
@@ -1569,8 +1582,12 @@ class FluidNetworkServer:
                         msg.get("from_seq", 0),
                     )
             except ConnectionError as e:
-                self._send(session, {"type": "connect_document_error",
-                                     "error": str(e)})
+                # A refusal for now (the document's writer slots are all
+                # taken) says when to come back; the driver waits it out.
+                self._send(session, {
+                    "type": "connect_document_error", "error": str(e),
+                    "retry_after_ms": 1e3 * getattr(e, "retry_after_s", 0.0),
+                })
                 return
             session.conn = conn
             session.doc_id = doc_id
@@ -1624,6 +1641,7 @@ class FluidNetworkServer:
         elif t == "submitOp" and session.conn is not None:
             session.conn.submit(from_jsonable(msg["op"]))
         elif t == "submitSignal" and session.conn is not None:
+            self.signals_received += 1
             session.conn.submit_signal(msg.get("content"))
         elif t == "disconnect" and session.conn is not None:
             self._close_session(session)
@@ -1696,6 +1714,7 @@ class FluidNetworkServer:
                             self._deliver_obj(
                                 s, {"type": "op", "msg": to_jsonable(m)}
                             )
+                            self.ops_delivered += 1
                         else:
                             # SeqFrame: n sequenced ops in ONE binary frame.
                             self._deliver(s, wsproto.encode_frame(
@@ -1716,6 +1735,7 @@ class FluidNetworkServer:
                             "num": sig.client_connection_number,
                             "content": sig.content,
                         })
+                        self.signals_delivered += 1
                     except Exception as e:
                         self._requeue(
                             s.conn.signals, self._unsent_tail(sigs, j, e)

@@ -14,6 +14,7 @@ and replay (deterministic re-production, idempotent consumers).
 from __future__ import annotations
 
 import itertools
+import time
 import uuid
 from typing import Any, Callable, Dict, List, Optional
 
@@ -47,9 +48,21 @@ from fluidframework_tpu.service.admission import (
     OverloadController,
 )
 from fluidframework_tpu.service.queue import PartitionedLog
+from fluidframework_tpu.service.sequencer import SequencerStats
 from fluidframework_tpu.service.summary_store import SummaryStore
 from fluidframework_tpu.telemetry import journal, profiler, tracing
 from fluidframework_tpu.testing.faults import inject_fault
+
+
+class JoinRefused(ConnectionError):
+    """A join that deli nacked. ``retry_after_s`` > 0 says the refusal is
+    for now (a document whose writer slots are all taken: one frees when
+    the MSN passes its leave) and when to come back; the front door
+    passes it on and the network driver's connect waits it out."""
+
+    def __init__(self, message: str, retry_after_s: float = 0.0):
+        super().__init__(message)
+        self.retry_after_s = retry_after_s
 
 
 class PipelineConnection:
@@ -373,11 +386,38 @@ class PipelineFluidService:
         ticketed and how many it handed to the per-record path, over the
         deli partitions as they stand (a ``crash_deli`` starts them at
         zero, as a restarted process would)."""
-        lams = self._deli._lambdas.values()
-        return {
+        lams = list(self._deli._lambdas.values())
+        out = {
             "deli_frames_batched": sum(lam.frames_batched for lam in lams),
             "deli_frames_single": sum(lam.frames_single for lam in lams),
+            "signals_received": sum(lam.signals_received for lam in lams),
+            "signals_delivered": sum(
+                lam.delivered for lam in self._signals._lambdas.values()
+            ),
         }
+        # The ticket loop's counts (sequencer.SequencerStats): sums over
+        # the partitions, but the most write slots any document held.
+        for k in SequencerStats.__slots__:
+            fold = max if k == "writer_slots_peak" else sum
+            out[k] = fold([getattr(lam.stats, k) for lam in lams] or [0])
+        return out
+
+    def noops_due(self) -> bool:
+        """Noop consolidation's timer (reference deli
+        ``noOpConsolidationTimeout``): for every document whose client
+        noops moved the MSN and that has sequenced nothing for 250 ms
+        since, one ``servernoop`` record on the raw log, which deli
+        turns into ONE sequenced server noop carrying the MSN. True when
+        any was sent: the caller's next ``pump()`` sequences them. Run
+        at the start of every ``pump()`` and by the network server's
+        deadline ticker (a quiet document has nobody else to call it)."""
+        sent = False
+        for lam in self._deli._lambdas.values():
+            if lam.noop_waiting:
+                for doc_id in lam.noops_due(time.time()):
+                    self._send_raw(doc_id, {"t": "servernoop"})
+                    sent = True
+        return sent
 
     # -- the pipeline pump -----------------------------------------------------
 
@@ -393,6 +433,7 @@ class PipelineFluidService:
         err-surface barrier, and the one-shot path stays bit-exact
         (feeds ride the same stage/dispatch machinery as flush)."""
         total = 0
+        self.noops_due()
         while True:
             # One span per stage per sweep: the stage's lane on the
             # device trace's clock and in the always-on lane totals.
@@ -646,7 +687,10 @@ class PipelineFluidService:
         if conn.client_id < 0:
             self.rooms[doc_id].remove(conn)
             nack = conn.nacks[0] if conn.nacks else None
-            raise ConnectionError(nack.message if nack else "join failed")
+            raise JoinRefused(
+                nack.message if nack else "join failed",
+                nack.retry_after_s if nack else 0.0,
+            )
         return conn
 
     def _send_raw(self, doc_id: str, rec: dict) -> None:

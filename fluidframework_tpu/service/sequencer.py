@@ -34,6 +34,36 @@ from fluidframework_tpu.protocol.types import (
 
 FULL_SCOPES = ("doc:read", "doc:write", "summary:write")
 
+# Noop consolidation (reference deli ``noOpConsolidationTimeout``, 250 ms
+# in routerlicious ``config.json``): how long a document must have had
+# nothing sequenced before deli spends a sequence number on a server noop
+# that carries an MSN which client noops have moved.
+NOOP_CONSOLIDATION_S = 0.25
+# What a join refused for want of a writer slot tells the client to wait:
+# a left slot frees when every writer has sent something (an op or its
+# collab-window noop, at most two seconds after the leave) past the leave.
+JOIN_RETRY_AFTER_S = 1.0
+
+
+class SequencerStats:
+    """Always-on integers of the ticket loop, one object for every
+    document of a deli partition (``PipelineFluidService.stats()`` sums
+    the partitions; a restarted deli starts at zero, as a restarted
+    process would): client noops taken in without a sequence number and
+    noops sequenced (a client's immediate one, or the server's
+    consolidated one); the most write slots any document held at once
+    and the joins refused for want of one; head - MSN summed at every
+    ticket, and the tickets."""
+
+    __slots__ = (
+        "noops_received", "noops_sequenced", "writer_slots_peak",
+        "join_nacks_slots", "msn_lag_sum", "msn_lag_count",
+    )
+
+    def __init__(self):
+        for k in self.__slots__:
+            setattr(self, k, 0)
+
 
 @dataclass
 class _ClientEntry:
@@ -74,8 +104,13 @@ class SequencerCheckpoint:
 class DocumentSequencer:
     """Assigns the total order for one document (deli ``ticket()``)."""
 
-    def __init__(self, doc_id: str, checkpoint: Optional[SequencerCheckpoint] = None):
+    def __init__(
+        self, doc_id: str, checkpoint: Optional[SequencerCheckpoint] = None,
+        stats: Optional[SequencerStats] = None,
+    ):
         self.doc_id = doc_id
+        self.stats = stats if stats is not None else SequencerStats()
+        self.writer_slots_peak = 0  # this document's own
         self.seq = 0
         self.min_seq = 0
         # Control plane (reference deli lambda.ts:989+ ControlMessageType):
@@ -96,6 +131,11 @@ class DocumentSequencer:
         # never-recycled identity clients scope content ids to (a recycled
         # slot must not collide payload/cell id keyspaces).
         self._conn_count = 0
+        # Consolidation: when client noops last left the MSN ahead of
+        # what the stream has said (None: nothing to carry), and when
+        # the document last sequenced anything.
+        self.noop_pending_since: Optional[float] = None
+        self.last_sequenced_at = 0.0
         if checkpoint is not None:
             self.seq = checkpoint.sequence_number
             self.min_seq = checkpoint.minimum_sequence_number
@@ -113,8 +153,11 @@ class DocumentSequencer:
         """Admit a client; returns the sequenced ClientJoin op.
 
         The slot cap mirrors the kernel's removers bitmask width: deli's
-        1M-clients/doc cap (config.json:57) becomes MAX_WRITERS concurrent
-        write slots per document in round 1.
+        1M-clients/doc cap (config.json:57) becomes MAX_WRITERS (124)
+        concurrent write slots per document. A slot a writer left is
+        handed out again once the leave's seq is at or under the MSN; a
+        join that finds none is nacked 429 with a retry-after, and the
+        client's connect comes back after it.
         """
         slot = None
         for i, (s, leave_seq) in enumerate(self._free_slots):
@@ -124,9 +167,11 @@ class DocumentSequencer:
                 break
         if slot is None:
             if self._next_slot >= MAX_WRITERS:
+                self.stats.join_nacks_slots += 1
                 return NackMessage(
                     self.seq, 429, NackErrorType.LIMIT_EXCEEDED,
                     f"document writer slots exhausted ({MAX_WRITERS})",
+                    retry_after_s=JOIN_RETRY_AFTER_S,
                 )
             slot = self._next_slot
             self._next_slot += 1
@@ -144,6 +189,11 @@ class DocumentSequencer:
             client_id=slot, ref_seq=msg.sequence_number, client_seq=0, mode=mode,
             last_seen=time.time(), scopes=tuple(scopes),
         )
+        live = sum(c.mode == "write" for c in self.clients.values())
+        if live > self.writer_slots_peak:
+            self.writer_slots_peak = live
+            if live > self.stats.writer_slots_peak:
+                self.stats.writer_slots_peak = live
         return msg
 
     def leave(self, client_id: int) -> Optional[SequencedDocumentMessage]:
@@ -225,6 +275,8 @@ class DocumentSequencer:
             return NackMessage(
                 self.seq, 403, NackErrorType.INVALID_SCOPE, "read-only client"
             )
+        if msg.type == MessageType.NOOP and msg.contents is None:
+            return self._take_noop(entry, msg.reference_sequence_number)
         if self._nack_all is not None:
             # Maintenance mode (NackMessages control): reject without
             # consuming the clientSequenceNumber so a later resubmit works.
@@ -272,11 +324,17 @@ class DocumentSequencer:
         if traces:
             tracing.stamp(traces, "deli", "start")
 
-        # Unlike the reference (deli lambda.ts:896-927 leaves NoOps
-        # un-sequenced and coalesces them), NOOPs here consume a sequence
-        # number like any op: clients then see a strictly gapless stream,
-        # which keeps the device-side scan and the dedup rules uniform.
+        # A noop that reaches this line is the reference's IMMEDIATE one
+        # (non-null contents, ``ContainerRuntime.send_noop()``): it is
+        # sequenced like any op (deli lambda.ts:896-927 sends it at
+        # once). The collab-window heartbeat's noop (null contents) left
+        # through :meth:`_take_noop` above and took no sequence number.
         self.seq += 1
+        now = time.time()
+        if msg.type == MessageType.NOOP:
+            self.stats.noops_sequenced += 1
+        msn = self._compute_msn()
+        self._sequenced(now, 1, msn)
         if traces:
             tracing.stamp(traces, "deli", "end")
         return SequencedDocumentMessage(
@@ -284,13 +342,66 @@ class DocumentSequencer:
             sequence_number=self.seq,
             client_sequence_number=msg.client_sequence_number,
             reference_sequence_number=msg.reference_sequence_number,
-            minimum_sequence_number=self._compute_msn(),
+            minimum_sequence_number=msn,
             type=msg.type,
             contents=msg.contents,
             metadata=msg.metadata,
-            timestamp=time.time(),
+            timestamp=now,
             traces=traces,
         )
+
+    # -- noop consolidation (reference deli lambda.ts:896-927) ----------------
+
+    def _take_noop(self, entry: _ClientEntry, ref_seq: int) -> None:
+        """A client's collab-window noop (null contents): it says how far
+        the client has read and nothing else, so it moves the client's
+        refSeq and is NOT sequenced. If that leaves the MSN ahead of what
+        the stream has said, the next sequenced message of the document
+        carries it; :meth:`server_noop` does when nothing else comes for
+        ``NOOP_CONSOLIDATION_S``. Unlike the reference's, the noop takes
+        no clientSequenceNumber either: this client's nack recovery
+        numbers its resubmission from the last op it saw echoed
+        (``ContainerRuntime.process_incoming``), and a number that only
+        the server had counted would make the next op a duplicate."""
+        self.stats.noops_received += 1
+        now = time.time()
+        entry.last_seen = now
+        if ref_seq > entry.ref_seq:
+            entry.ref_seq = ref_seq
+            if (
+                self.noop_pending_since is None
+                and self._refseq_floor() > self.min_seq
+            ):
+                self.noop_pending_since = now
+        return None
+
+    def noop_due(self, now: float) -> bool:
+        """Whether client noops have moved the MSN and the document has
+        sequenced nothing for ``NOOP_CONSOLIDATION_S`` since."""
+        since = self.noop_pending_since
+        return since is not None and (
+            now - max(since, self.last_sequenced_at) >= NOOP_CONSOLIDATION_S
+        )
+
+    def server_noop(self) -> Optional[SequencedDocumentMessage]:
+        """The consolidated noop: ONE sequenced server message that
+        carries the MSN the clients' noops moved, or None when a message
+        sequenced meanwhile has carried it already."""
+        self.noop_pending_since = None
+        if not self.clients or self._refseq_floor() <= self.min_seq:
+            return None
+        self.stats.noops_sequenced += 1
+        return self._sequence_system(MessageType.NOOP, contents=None)
+
+    def _sequenced(self, now: float, n: int, msn: int) -> None:
+        """Bookkeeping of every ticket: the lag of the collab window
+        behind the head, and that whatever client noops had moved is now
+        said."""
+        st = self.stats
+        st.msn_lag_sum += self.seq - msn
+        st.msn_lag_count += 1
+        self.last_sequenced_at = now
+        self.noop_pending_since = None
 
     def ticket_uniform(
         self, client_id: int, csn0: int, n: int, r0: int, now: float
@@ -322,6 +433,7 @@ class DocumentSequencer:
         seq0 = self.seq + 1
         self.seq += n
         self.min_seq = floor
+        self._sequenced(now, n, floor)
         return seq0, floor
 
     def ticket_frame(
@@ -425,6 +537,7 @@ class DocumentSequencer:
         seq0 = self.seq + 1
         self.seq += m
         self.min_seq = int(msn_l[-1])
+        self._sequenced(now, m, self.min_seq)
         nack = None
         if m < n_rem:
             nack = NackMessage(
@@ -440,24 +553,27 @@ class DocumentSequencer:
     def _compute_msn(self) -> int:
         """MSN = min over per-client refSeq; no clients -> current seq
         (deli lambda.ts:929-938). The MSN never regresses."""
-        if not self.clients:
-            msn = self.seq
-        else:
-            msn = min(c.ref_seq for c in self.clients.values())
+        msn = self._refseq_floor() if self.clients else self.seq
         self.min_seq = max(self.min_seq, msn)
         return self.min_seq
 
+    def _refseq_floor(self) -> int:
+        return min(c.ref_seq for c in self.clients.values())
+
     def _sequence_system(self, ty: MessageType, contents) -> SequencedDocumentMessage:
         self.seq += 1
+        now = time.time()
+        msn = self._compute_msn()
+        self._sequenced(now, 1, msn)
         return SequencedDocumentMessage(
             client_id=-1,
             sequence_number=self.seq,
             client_sequence_number=-1,
             reference_sequence_number=-1,
-            minimum_sequence_number=self._compute_msn(),
+            minimum_sequence_number=msn,
             type=ty,
             contents=contents,
-            timestamp=time.time(),
+            timestamp=now,
         )
 
     def checkpoint_dict(self) -> dict:

@@ -493,10 +493,10 @@ def test_a_record_that_raises_mid_chunk_leaves_the_prefix_stamped(
         chunks[0].pop(2)
         real = L.DeliDocLambda.__init__
 
-        def init(self, doc_id, state=None):
+        def init(self, doc_id, state=None, *rest):
             if doc_id == "c":
                 raise RuntimeError("no such document")
-            real(self, doc_id, state)
+            real(self, doc_id, state, *rest)
 
         monkeypatch.setattr(L.DeliDocLambda, "__init__", init)
     deli, script = both(chunks)
@@ -592,7 +592,10 @@ def test_no_consumer_writes_into_a_sequenced_frames_rows(monkeypatch):
 
 def test_the_service_counts_frames_each_way():
     svc = PipelineFluidService(n_partitions=4, device_backend=False)
-    assert svc.stats() == {
+    frames_of = lambda st: {k: st[k] for k in (
+        "deli_frames_batched", "deli_frames_single")}
+    assert set(svc.stats().values()) == {0}
+    assert frames_of(svc.stats()) == {
         "deli_frames_batched": 0, "deli_frames_single": 0}
     conns = {f"d{i}": svc.connect(f"d{i}") for i in range(12)}
     build = lambda d, csn0, k=2: OpFrame.build(
@@ -604,8 +607,14 @@ def test_the_service_counts_frames_each_way():
         [(d, c.client_id, build(d, 3)) for d, c in conns.items()]
         + [("d0", conns["d0"].client_id, build("d0", 1))])  # a replay
     conns["d1"].submit_frame(build("d1", 5))  # the websocket's way in
-    assert svc.stats() == {  # the replay and the lone frame: single
+    assert frames_of(svc.stats()) == {  # the replay and the lone frame
         "deli_frames_batched": 24, "deli_frames_single": 2}
+    # The ticket loop's own counts: 12 joins and 25 frames ticketed (the
+    # replay was not), one writer a document, no noop, no refusal.
+    st = svc.stats()
+    assert (st["msn_lag_count"], st["writer_slots_peak"]) == (37, 1)
+    assert (st["noops_received"], st["noops_sequenced"],
+            st["join_nacks_slots"]) == (0, 0, 0)
     svc.crash_deli()
     assert svc.stats()["deli_frames_batched"] == 0
 

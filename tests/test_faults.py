@@ -881,13 +881,13 @@ class TestLeaseChaos:
 
 
 # ---------------------------------------------------------------------------
-# The 93-writer cap: nack-at-cap + slot-expiry reuse through the pipeline
+# The writer cap (MAX_WRITERS): nack-at-cap + slot-expiry reuse through the pipeline
 
 
 class TestWriterCap:
     def test_nack_at_cap_and_slot_reuse(self):
-        """ROADMAP open item: MAX_WRITERS is enforced END TO END — writer
-        94 gets a clean 429 nack through the full pipeline, and after a
+        """ROADMAP open item: MAX_WRITERS is enforced END TO END — the
+        writer past the cap gets a clean 429 nack through the full pipeline, and after a
         leave whose seq falls below the collab-window floor the freed
         slot readmits a new writer."""
         svc = PipelineFluidService(n_partitions=1, device_backend=False)

@@ -287,21 +287,35 @@ def test_random_sequenced_stream_matches_oracle(seed):
     assert materialize(st, payloads) == doc.text(payloads)
 
 
-def test_wide_writer_slots_overlap_remove():
-    """Writer slots land across THREE removers lanes (rbits / rbits2 /
-    rbits3) and behave identically: overlapping removes record every
-    remover, and the remover's own perspective hides the row
-    (MAX_WRITERS = 93)."""
+def _bit(h, row: int, slot: int) -> int:
+    """Bit of writer ``slot`` in row ``row``'s removers set."""
+    from fluidframework_tpu.ops.segment_state import (
+        RBITS_PER_LANE, rbits_of,
+    )
+
+    lane, bit = divmod(slot, RBITS_PER_LANE)
+    return (int(rbits_of(h)[lane][row]) >> bit) & 1
+
+
+@pytest.mark.parametrize("top_slot", [92, 123])
+def test_wide_writer_slots_overlap_remove(top_slot):
+    """Writer slots land across every removers lane (rbits .. rbits4)
+    and behave identically: overlapping removes record every remover,
+    and the remover's own perspective hides the row. The cases: the top
+    slot of the three lanes the cap stood at until PR 36 (93), and the
+    top slot of the fourth (MAX_WRITERS = 124)."""
+    from fluidframework_tpu.ops.segment_state import RBITS_LANES
     from fluidframework_tpu.protocol.constants import MAX_WRITERS
 
-    assert MAX_WRITERS == 93
+    assert MAX_WRITERS == 124 == 31 * len(RBITS_LANES)
     payloads = {1: "abcdef"}
     rows = [
         E.insert(0, 1, 6, seq=1, ref=0, client=40),
         E.remove(1, 3, seq=2, ref=1, client=33),  # mid-lane remover
         E.remove(1, 3, seq=3, ref=1, client=2),  # lo-lane overlap
         E.remove(1, 3, seq=4, ref=1, client=70),  # hi-lane overlap
-        E.remove(3, 5, seq=5, ref=1, client=92),  # top slot
+        E.remove(1, 3, seq=5, ref=1, client=100),  # fourth-lane overlap
+        E.remove(3, 5, seq=6, ref=1, client=top_slot),  # top slot
     ]
     ops = np.stack(rows).astype(np.int32)
     st = jit_apply_ops(make_state(32, NO_CLIENT), ops)
@@ -309,25 +323,24 @@ def test_wide_writer_slots_overlap_remove():
     assert int(h.err) == 0
     assert materialize(st, payloads) == "af"
     live = [i for i in range(int(h.count)) if int(h.kind[i]) != 0]
-    # The overlapped rows carry every remover across the three lanes.
-    overlapped = [
-        i for i in live
-        if int(h.rseq[i]) == 2 and (int(h.rbits[i]) >> 2) & 1
-    ]
+    # The overlapped rows carry every remover across the lanes.
+    overlapped = [i for i in live if int(h.rseq[i]) == 2 and _bit(h, i, 2)]
     assert overlapped and all(
-        (int(h.rbits2[i]) >> (33 - 31)) & 1
-        and (int(h.rbits3[i]) >> (70 - 62)) & 1
+        _bit(h, i, 33) and _bit(h, i, 70) and _bit(h, i, 100)
+        and not _bit(h, i, top_slot)
         for i in overlapped
     )
-    top = [i for i in live if int(h.rseq[i]) == 5]
-    assert top and all(
-        (int(h.rbits3[i]) >> (92 - 62)) & 1 for i in top
-    )
+    top = [i for i in live if int(h.rseq[i]) == 6]
+    assert top and all(_bit(h, i, top_slot) for i in top)
 
 
-def test_wide_slot_client_error_flag():
-    rows = [E.insert(0, 1, 2, seq=1, ref=0, client=93)]  # beyond the mask
+@pytest.mark.parametrize("slot,flagged", [(93, False), (123, False),
+                                          (124, True)])
+def test_wide_slot_client_error_flag(slot, flagged):
+    """The first slot beyond the mask is flagged; slot 93, which was the
+    first one beyond it until PR 36, and the last one are not."""
+    rows = [E.insert(0, 1, 2, seq=1, ref=0, client=slot)]
     st = jit_apply_ops(make_state(8, NO_CLIENT), np.stack(rows).astype(np.int32))
     from fluidframework_tpu.protocol.constants import ERR_CLIENT
 
-    assert int(to_host(st).err) & ERR_CLIENT
+    assert bool(int(to_host(st).err) & ERR_CLIENT) == flagged

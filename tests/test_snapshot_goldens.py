@@ -153,3 +153,48 @@ def test_r1_format_summary_still_loads():
     )
     assert rt.get_channel("text").get_text() == golden["text"]
     assert rt.get_channel("map").get("title") == "golden doc"
+
+
+def test_parent_format_summary_loads_and_replays():
+    """A summary written before the fourth removers lane (PR 35's golden:
+    rbits, rbits2, rbits3 and no rbits4; its noops sequenced with null
+    contents) loads into a live container, whose replica then takes ops
+    from writers in the new lane's slots like any other: load_core leaves
+    the missing lane at its empty default."""
+    import numpy as np
+
+    from fluidframework_tpu.ops.segment_state import RBITS_LANES, to_host
+
+    with open(os.path.join(GOLDEN_DIR, "golden_session_pr35.json")) as f:
+        golden = json.load(f)
+    lanes = golden["summary"]["channels"]["text"]["lanes"]
+    assert "rbits3" in lanes and "rbits4" not in lanes
+    assert RBITS_LANES[-1] == "rbits4"
+    svc = LocalFluidService()
+    handle = svc.store.put_summary(golden["summary"])
+    doc = svc._doc("golden4")
+    doc.latest_summary = (handle, golden["summary"]["sequence_number"])
+    doc.sequencer.seq = golden["summary"]["sequence_number"]
+    a = ContainerRuntime(
+        svc, "golden4", channels=(SharedString("text"), SharedMap("map"))
+    )
+    b = ContainerRuntime(
+        svc, "golden4", channels=(SharedString("text"), SharedMap("map"))
+    )
+    assert a.get_channel("text").get_text() == golden["text"]
+    assert [list(x) for x in a.get_channel("text").annotations()] == (
+        golden["annotations"])
+    h = to_host(a.get_channel("text")._state)
+    assert not np.asarray(h.rbits4).any()
+    # The loaded replicas go on: concurrent overlapping removes, then an
+    # insert, converge.
+    n = len(golden["text"])
+    a.get_channel("text").remove_range(1, n - 1)
+    b.get_channel("text").remove_range(2, n)
+    b.get_channel("text").insert_text(0, "!")
+    for rt in (a, b):
+        rt.flush()
+    while any(rt.process_incoming() for rt in (a, b)):
+        pass
+    assert a.get_channel("text").get_text() == b.get_channel("text").get_text()
+    assert a.get_channel("text").get_text() == "!" + golden["text"][0]
