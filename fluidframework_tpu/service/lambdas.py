@@ -1097,15 +1097,18 @@ class SignalBroadcasterLambda(PartitionLambda):
     def handler(self, key: str, value: dict) -> List[Tuple[str, str, Any]]:
         from fluidframework_tpu.protocol.types import SignalMessage
 
+        # ONE object a signal, queued on every connection of the room as
+        # the op broadcaster queues a sequenced message: the socket
+        # layer's delivery sweep encodes what it finds queued once per
+        # object, not once per socket.
+        sig = SignalMessage(
+            client_id=value["client"],
+            client_connection_number=value["num"],
+            content=value["content"],
+        )
         for conn in self.rooms.get(key, []):
             if value["num"] > conn.delivered_signal:
-                conn.signals.append(
-                    SignalMessage(
-                        client_id=value["client"],
-                        client_connection_number=value["num"],
-                        content=value["content"],
-                    )
-                )
+                conn.signals.append(sig)
                 conn.delivered_signal = value["num"]
                 self.delivered += 1
         return []

@@ -101,6 +101,33 @@ class _Session:
         self.push_busy = False
 
 
+def _text_frame(obj: dict) -> bytes:
+    """One JSON object as a websocket text frame."""
+    return wsproto.encode_frame(wsproto.OP_TEXT, json.dumps(obj).encode())
+
+
+def _op_text_frame(m) -> bytes:
+    """A sequenced message on the JSON wire: THE message → text frame of
+    this server, shared by the push fan-out and the delivery sweep."""
+    return _text_frame({"type": "op", "msg": to_jsonable(m)})
+
+
+def _signal_text_frame(sig) -> bytes:
+    """A signal on the wire (signals have the JSON form only)."""
+    return _text_frame({
+        "type": "signal",
+        "client_id": sig.client_id,
+        "num": sig.client_connection_number,
+        "content": sig.content,
+    })
+
+
+def _seq_frame_binary(frame) -> bytes:
+    """A SeqFrame on the frame wire: n sequenced ops in ONE binary
+    websocket frame."""
+    return wsproto.encode_frame(wsproto.OP_BINARY, frame.encode())
+
+
 class _PushEncodeCache:
     """Per-(doc, sweep) lazy byte cache — the encode-once contract of
     the r15 push fan-out: each durable-log entry's wire bytes are built
@@ -126,16 +153,7 @@ class _PushEncodeCache:
                 else obj.messages()
             )
             got = self._json[i] = [
-                (
-                    m.sequence_number,
-                    wsproto.encode_frame(
-                        wsproto.OP_TEXT,
-                        json.dumps(
-                            {"type": "op", "msg": to_jsonable(m)}
-                        ).encode(),
-                    ),
-                )
-                for m in msgs
+                (m.sequence_number, _op_text_frame(m)) for m in msgs
             ]
         return got
 
@@ -143,10 +161,55 @@ class _PushEncodeCache:
         got = self._frame.get(i)
         if got is None:
             self.encodes += 1
-            got = self._frame[i] = wsproto.encode_frame(
-                wsproto.OP_BINARY, entry[2].encode()
-            )
+            got = self._frame[i] = _seq_frame_binary(entry[2])
         return got
+
+
+class _SweepEncodeCache:
+    """Per-sweep lazy byte cache of the connected-writer delivery: the
+    broadcasters queue ONE object (a sequenced message, a ``SeqFrame``,
+    a signal) on every connection of a room, and within one delivery
+    sweep of ``_drain_all`` its wire bytes are built AT MOST ONCE per
+    wire format, whatever the number of sockets that have it queued (a
+    frame's JSON-wire form is its expanded messages, objects of their
+    own, so one table serves both wires). Keyed by ``id()``: every entry
+    holds the object it keyed, so no id can be reused while the cache
+    lives, and a cache lives for one sweep. ``encodes`` counts the
+    encode passes (pinned flat across 1/10/120 connections of a room by
+    the tests)."""
+
+    __slots__ = ("_bytes", "_expanded", "encodes")
+
+    def __init__(self) -> None:
+        self._bytes: Dict[int, tuple] = {}  # id(item) -> (bytes, item)
+        self._expanded: Dict[int, tuple] = {}  # id(frame) -> (msgs, frame)
+        self.encodes = 0
+
+    def encoded(self, item, encode) -> bytes:
+        """``encode(item)``, built the first time a sweep meets ``item``."""
+        got = self._bytes.get(id(item))
+        if got is None:
+            self.encodes += 1
+            got = self._bytes[id(item)] = (encode(item), item)
+        return got[0]
+
+    def expanded(self, items: list) -> list:
+        """``items`` as a JSON-wire session takes them: every SeqFrame
+        replaced by its per-op messages. A frame expands once a sweep,
+        so every such session of the room holds the same message
+        objects and each one's text is built once."""
+        if all(hasattr(m, "sequence_number") for m in items):
+            return items
+        flat: list = []
+        for m in items:
+            if hasattr(m, "sequence_number"):
+                flat.append(m)
+                continue
+            got = self._expanded.get(id(m))
+            if got is None:
+                got = self._expanded[id(m)] = (m.messages(), m)
+            flat.extend(got[0])
+        return flat
 
 
 class _PushStall(Exception):
@@ -339,6 +402,11 @@ class FluidNetworkServer:
         self.ops_delivered = 0
         self.signals_received = 0
         self.signals_delivered = 0
+        # Encode passes of the delivery sweep, every wire format: 1.0 a
+        # delivery at a fan-out of one, 1/120 in a meeting document
+        # (each queued item's bytes are built once a sweep, not once a
+        # socket).
+        self.delivery_encodes = 0
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
         self._started = threading.Event()
@@ -1227,9 +1295,7 @@ class FluidNetworkServer:
             session.conn = None
 
     def _send(self, session: _Session, obj: dict) -> None:
-        data = wsproto.encode_frame(
-            wsproto.OP_TEXT, json.dumps(obj).encode()
-        )
+        data = _text_frame(obj)
         if session.push_sock is not None:
             # Drainer-owned socket: EVERY loop-side write (error
             # replies to a repeat subscribe/connect included) must ride
@@ -1245,14 +1311,6 @@ class FluidNetworkServer:
         boundary (control-plane replies go through :meth:`_send` and are
         not injected: their recovery is the client's reconnect)."""
         session.writer.write(data)
-
-    def _deliver_obj(self, session: _Session, obj: dict) -> None:
-        """JSON-text delivery through the injected boundary (the _send
-        encoding, minus the control-plane path)."""
-        self._deliver(
-            session,
-            wsproto.encode_frame(wsproto.OP_TEXT, json.dumps(obj).encode()),
-        )
 
     def _requeue(self, target: list, rest: list) -> None:
         """Delivery-failure recovery: the unsent tail goes back to the
@@ -1684,25 +1742,34 @@ class FluidNetworkServer:
                 nack = getattr(self.service, "_nack_device_errors", None)
                 if nack is not None:
                     nack()
-        # Push delivery (r15, encode-once fan-out): subscribers group by
-        # doc, the durable log is read ONCE per (doc, sweep) from the
-        # group's minimum watermark, every sequenced entry encodes ONCE
-        # per wire format, and the same bytes write to every subscriber
-        # past their watermark. Per-subscriber state is a watermark + a
-        # requeue tail — the r11 exactly-once crash-after semantics per
-        # socket are unchanged.
+        # Delivery, encode-once on both paths. Push subscribers (r15)
+        # group by doc: the durable log is read ONCE per (doc, sweep)
+        # from the group's minimum watermark, every sequenced entry
+        # encodes ONCE per wire format (_PushEncodeCache, one per group
+        # per sweep), and the same bytes write to every subscriber past
+        # its watermark; per-subscriber state is a watermark + a requeue
+        # tail. Connected writers: the broadcasters queued the SAME
+        # object on every connection of a room, so one
+        # _SweepEncodeCache, alive for this call only, builds each
+        # queued item's bytes at most once per wire format and every
+        # session that holds the item is written those bytes. Each write
+        # is still one _deliver of one message, and the encode happens
+        # inside its try: the r11 exactly-once crash-after semantics
+        # per socket are unchanged (a failed k-th socket requeues its own
+        # tail; the bytes the others share are not touched).
         with profiler.span("socket_out"):
             self._push_sweep()
+            cache = _SweepEncodeCache()
             for s in self._sessions:
                 if s.conn is None:
                     continue
                 nopump = getattr(s.conn, "supports_nopump", False)
-                take_raw = (
-                    getattr(s.conn, "take_inbox_raw", None)
-                    if s.frames_ok else None
-                )
+                take_raw = getattr(s.conn, "take_inbox_raw", None)
                 if take_raw is not None:
                     msgs = take_raw(pump=False) if nopump else take_raw()
+                    if not s.frames_ok:
+                        # JSON wire: a frame goes out as its per-op texts.
+                        msgs = cache.expanded(msgs)
                 else:
                     msgs = (
                         s.conn.take_inbox(pump=False)
@@ -1711,15 +1778,12 @@ class FluidNetworkServer:
                 for j, m in enumerate(msgs):
                     try:
                         if hasattr(m, "sequence_number"):
-                            self._deliver_obj(
-                                s, {"type": "op", "msg": to_jsonable(m)}
-                            )
+                            self._deliver(s, cache.encoded(m, _op_text_frame))
                             self.ops_delivered += 1
                         else:
-                            # SeqFrame: n sequenced ops in ONE binary frame.
-                            self._deliver(s, wsproto.encode_frame(
-                                wsproto.OP_BINARY, m.encode()
-                            ))
+                            self._deliver(
+                                s, cache.encoded(m, _seq_frame_binary)
+                            )
                             self.frames_delivered += 1
                     except Exception as e:
                         self._requeue(
@@ -1729,12 +1793,9 @@ class FluidNetworkServer:
                 sigs, s.conn.signals[:] = list(s.conn.signals), []
                 for j, sig in enumerate(sigs):
                     try:
-                        self._deliver_obj(s, {
-                            "type": "signal",
-                            "client_id": sig.client_id,
-                            "num": sig.client_connection_number,
-                            "content": sig.content,
-                        })
+                        self._deliver(
+                            s, cache.encoded(sig, _signal_text_frame)
+                        )
                         self.signals_delivered += 1
                     except Exception as e:
                         self._requeue(
@@ -1744,11 +1805,13 @@ class FluidNetworkServer:
                 nacks, s.conn.nacks[:] = list(s.conn.nacks), []
                 for j, nk in enumerate(nacks):
                     try:
-                        self._deliver_obj(
-                            s, {"type": "nack", "nack": to_jsonable(nk)}
-                        )
+                        # A nack goes to one connection: nothing to share.
+                        self._deliver(s, _text_frame(
+                            {"type": "nack", "nack": to_jsonable(nk)}
+                        ))
                     except Exception as e:
                         self._requeue(
                             s.conn.nacks, self._unsent_tail(nacks, j, e)
                         )
                         break
+            self.delivery_encodes += cache.encodes
