@@ -22,9 +22,9 @@ import numpy as np
 from fluidframework_tpu.ops import encode as E
 from fluidframework_tpu.ops.merge_kernel import compact, jit_apply_ops
 from fluidframework_tpu.ops.segment_state import (
-    SEGMENT_LANES,
     capacity_of,
     grow,
+    lanes_summary,
     make_interactive_state,
     materialize,
     to_host,
@@ -463,16 +463,8 @@ class SharedString(SharedObject):
     # -- summary / load (round-1: full state snapshot) ------------------------
 
     def summarize_core(self) -> dict:
-        h = to_host(self._state)
-        n = int(h.count)
         return {
-            "lanes": {
-                k: np.asarray(getattr(h, k))[:n].tolist()
-                for k in SEGMENT_LANES
-            },
-            "count": n,
-            "min_seq": int(h.min_seq),
-            "cur_seq": int(h.cur_seq),
+            **lanes_summary(to_host(self._state)),
             "payloads": dict(self._payloads),
             "intervals": {
                 label: col.summarize()

@@ -274,6 +274,22 @@ def to_host(state: SegmentState) -> "SegmentState":
     return SegmentState(*[np.asarray(x) for x in state])
 
 
+def lanes_summary(h: SegmentState) -> dict:
+    """One state's rows in use as the summary format's lane lists (what
+    ``summarize_core`` of a kernel-backed channel saves and ``load_core``
+    restores). ``h`` holds host lanes."""
+    n = int(h.count)
+    return {
+        "lanes": {
+            lane: np.asarray(getattr(h, lane))[:n].tolist()
+            for lane in SEGMENT_LANES
+        },
+        "count": n,
+        "min_seq": int(h.min_seq),
+        "cur_seq": int(h.cur_seq),
+    }
+
+
 def materialize(state: SegmentState, payloads: dict) -> str:
     """Join live, locally-visible rows into the document text.
 

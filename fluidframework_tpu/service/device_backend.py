@@ -47,6 +47,7 @@ offset zero (the scribe rebuild model, ``scribe/lambda.ts:106``).
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -56,10 +57,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fluidframework_tpu.ops.segment_state import (
-    SEGMENT_LANES,
-    materialize,
-)
+from fluidframework_tpu.models.shared_matrix import axis_row_from_wire
+from fluidframework_tpu.ops.segment_state import lanes_summary, materialize
 from fluidframework_tpu.parallel.fleet import (
     TELEMETRY_COLS,
     DocFleet,
@@ -67,7 +66,8 @@ from fluidframework_tpu.parallel.fleet import (
     split_telemetry,
 )
 from fluidframework_tpu.protocol.constants import F_ARG, F_SEQ, OP_WIDTH
-from fluidframework_tpu.service import retry
+from fluidframework_tpu.service import matrix_channel, retry
+from fluidframework_tpu.service.matrix_channel import MatrixChannel, MatrixRead
 from fluidframework_tpu.service.residency import HeatTracker, ResidencyManager
 from fluidframework_tpu.telemetry import journal, metrics, profiler, tracing
 from fluidframework_tpu.testing import faults
@@ -77,6 +77,11 @@ from fluidframework_tpu.utils import pow2_at_least as _pow2_at_least
 ChannelKey = Tuple[str, str]  # (doc_id, channel address)
 
 _WARMED: set = set()  # (capacity, max_capacity) warmups done this process
+
+#: Row and column removals a table takes between two gathers before the
+#: backend asks for a gather of its own (``tables_due``): a removed row's
+#: cells leave the store at a gather, and a table nobody reads has none.
+TABLE_SWEEP_REMOVALS = 16
 
 
 class _RingSlot:
@@ -136,7 +141,12 @@ class IngestRing:
 
 class DeviceFleetBackend:
     """The service's device compute backend: one DocFleet slot per string
-    channel, shared by every partition's device lambdas."""
+    channel and two per matrix channel (its row and column axes, with the
+    cells beside them on the host: ``service/matrix_channel.py``), shared
+    by every partition's device lambdas. ``_index``/``_keys`` and
+    everything under them speak slot keys; what callers see
+    (:meth:`channels`, :attr:`applied_seq`, :meth:`has_channel`, the read
+    path, :meth:`take_errors`) speaks channels."""
 
     def __init__(
         self,
@@ -175,6 +185,20 @@ class DeviceFleetBackend:
         self._index: Dict[ChannelKey, int] = {}
         self._keys: List[ChannelKey] = []  # dense fleet id -> key
         self.payloads: Dict[ChannelKey, dict] = {}
+        # Matrix channels: channel key -> its host state, and each axis
+        # slot's key -> its channel's. The always-on integers: axis ops
+        # lowered to kernel rows, cell writes taken in, cells the stores
+        # hold, cells dropped under a removed row or column, grids joined.
+        # _tables_due: tables that took TABLE_SWEEP_REMOVALS removals with
+        # no gather in between (an ordered set; see tables_due).
+        self._matrix: Dict[ChannelKey, MatrixChannel] = {}
+        self._axis_of: Dict[ChannelKey, ChannelKey] = {}
+        self._tables_due: Dict[ChannelKey, None] = {}
+        self.matrix_axis_ops = 0
+        self.matrix_cell_ops = 0
+        self.matrix_cells_live = 0
+        self.matrix_cells_dropped = 0
+        self.matrix_reads = 0
         # Per-channel watermarks as DENSE ARRAYS indexed by fleet id (the
         # r10 satellite: at 10k+ busy channels the per-channel dict loop
         # in flush() was residual Python wall inside the pump —
@@ -355,10 +379,14 @@ class DeviceFleetBackend:
     @property
     def applied_seq(self) -> Dict[ChannelKey, int]:
         """Per-channel applied-seq watermarks as a dict view (the hot
-        path reads the dense array directly)."""
-        return {
+        path reads the dense array directly); a matrix channel's is the
+        highest sequence number it took in, axis op or cell."""
+        out = {
             k: int(self._applied_a[i]) for i, k in enumerate(self._keys)
+            if k not in self._axis_of
         }
+        out.update((k, mc.seq) for k, mc in self._matrix.items())
+        return out
 
     @property
     def ops_since_summary(self) -> Dict[ChannelKey, int]:
@@ -368,10 +396,15 @@ class DeviceFleetBackend:
         }
 
     def channels(self) -> List[ChannelKey]:
-        return list(self._keys)
+        return [k for k in self._keys if k not in self._axis_of] + list(
+            self._matrix
+        )
 
     def has_channel(self, doc_id: str, address: str) -> bool:
-        return (doc_id, address) in self._index
+        key = (doc_id, address)
+        return key in self._matrix or (
+            key in self._index and key not in self._axis_of
+        )
 
     # -- ingest ----------------------------------------------------------------
 
@@ -397,6 +430,55 @@ class DeviceFleetBackend:
         self._buffered_rows += 1
         if self._buffered_rows >= self.max_batch:
             self._boxcar_full()
+
+    def enqueue_matrix(
+        self, doc_id: str, address: str, op: dict, *,
+        seq: int, ref: int, client: int, msn: int,
+    ) -> None:
+        """Take in one sequenced op of a matrix channel (its kind is known
+        from this, its first op: ``insrow``/``inscol``/``remrow``/
+        ``remcol``/``cell``). An axis op is lowered to the one kernel row
+        every client replica applies for the same message and buffered
+        for that axis's fleet slot; a cell write goes to the channel's
+        store, last sequenced writer wins. Replay-idempotent like
+        :meth:`enqueue`: an op at or under the channel's high-water mark
+        drops."""
+        with profiler.span("matrix_stage"):
+            key = (doc_id, address)
+            mc = self._matrix.get(key)
+            if mc is None:
+                mc = self._matrix[key] = MatrixChannel(doc_id, address)
+                for axis in mc.axes:
+                    self._axis_of[axis] = key
+                    self.ensure(*axis)
+            if seq <= mc.seq:
+                return
+            mc.seq = seq
+            if msn > mc.msn:
+                mc.msn = msn
+            kind = op["k"]
+            if kind == "cell":
+                if not self.residency.note_op(doc_id):
+                    # The first op to a COLD document wakes it, whatever
+                    # its kind (the axes' slots come back; nothing parks).
+                    self.residency.begin_wake(doc_id)
+                    self._try_wake(doc_id)
+                self.matrix_cell_ops += 1
+                self.matrix_cells_live += mc.write(
+                    (tuple(op["row"]), tuple(op["col"])), op["val"]
+                )
+                return
+            self.matrix_axis_ops += 1
+            if kind.startswith("rem"):
+                mc.removals += 1
+                if mc.removals >= TABLE_SWEEP_REMOVALS:
+                    self._tables_due[key] = None
+            self.enqueue(
+                *mc.axes[kind.endswith("col")],
+                axis_row_from_wire(
+                    op, seq=seq, ref=ref, client=client, msn=msn
+                ),
+            )
 
     def enqueue_frame(self, doc_id: str, frame) -> None:
         """Buffer a whole sequenced op frame (the batched binary wire,
@@ -668,6 +750,11 @@ class DeviceFleetBackend:
     ) -> None:
         st: Optional[Dict[int, object]] = None
         if states is not None:
+            states = dict(states)
+            for k, read in list(states.items()):
+                if isinstance(read, MatrixRead):  # a table: two slots
+                    rows, cols = self._matrix[k].axes
+                    states[rows], states[cols] = read.rows, read.cols
             st = {
                 self._index[k]: states[k] for k in keys if k in states
             }
@@ -684,7 +771,8 @@ class DeviceFleetBackend:
         """Drain channels whose err lane tripped since the last drain (the
         service turns these into nacks + telemetry)."""
         out, self._unreported = self._unreported, []
-        return out
+        # An axis slot's error is its table's.
+        return list(dict.fromkeys(self._axis_of.get(k, k) for k in out))
 
     def flush(self) -> List[ChannelKey]:
         """Apply every buffered row in batched kernel dispatches; returns
@@ -1550,8 +1638,22 @@ class DeviceFleetBackend:
         :meth:`read_finish` — the telemetry-scrape split applied to
         reads. A faulted gather (the ``read.gather`` site) falls back to
         per-doc host gathers HERE, counted, never silent."""
+        # A table is two slots of the gather and the loan of its cells, all
+        # as of one sequence number: whatever of its axes' rows is still
+        # buffered or staged goes to the device first, so that the axes
+        # stand where the cells do.
+        tables = {
+            key: self._matrix[key] for key in keys if key in self._matrix
+        }
+        if tables and (self._buffers or len(self._ring)):
+            self.flush()
+        matrix = {key: mc.lend() for key, mc in tables.items()}
+        for key in tables:
+            self._tables_due.pop(key, None)
         order: List[Tuple[ChannelKey, int]] = [
-            (key, self._index[key]) for key in keys
+            (slot, self._index[slot])
+            for key in keys
+            for slot in (tables[key].axes if key in tables else (key,))
         ]
         sharded = {
             idx: self._sharded[idx].to_single()
@@ -1595,6 +1697,7 @@ class DeviceFleetBackend:
         return {
             "order": order, "sharded": sharded, "cold": cold,
             "dev": dev, "layout": layout, "fallback": fallback,
+            "matrix": matrix,
         }
 
     @inject_fault("read.gather")
@@ -1615,9 +1718,9 @@ class DeviceFleetBackend:
         self, token: dict, host: Optional[np.ndarray] = None
     ) -> Dict[ChannelKey, "object"]:
         """Split one read batch into per-channel states (key ->
-        SegmentState) and advance the amortization counters
-        (``reads_served`` / ``read_gathers`` →
-        ``reads_per_device_dispatch``)."""
+        SegmentState, or a :class:`MatrixRead` for a table) and advance
+        the amortization counters (``reads_served`` / ``read_gathers`` →
+        ``reads_per_device_dispatch``; a table is one read)."""
         states: Dict[int, object] = {}
         if token["fallback"] is not None:
             states.update(token["fallback"])
@@ -1630,8 +1733,12 @@ class DeviceFleetBackend:
             )
         states.update(token["sharded"])
         states.update(token.get("cold") or {})
-        self.reads_served += len(token["order"])
-        return {key: states[idx] for key, idx in token["order"]}
+        out = {key: states[idx] for key, idx in token["order"]}
+        for key, loan in token["matrix"].items():
+            rows, cols = self._matrix[key].axes
+            out[key] = MatrixRead(out.pop(rows), out.pop(cols), *loan)
+        self.reads_served += len(out)
+        return out
 
     def doc_states(
         self, keys: List[ChannelKey]
@@ -1659,20 +1766,51 @@ class DeviceFleetBackend:
         dict (the batched-read consumer half)."""
         return materialize(state, self.payloads[key])
 
+    def grid_from_state(self, key: ChannelKey, read: MatrixRead) -> list:
+        """One gathered table as its grid (rows in axis order, each a
+        list of cell values, None where unset), joined from the cut the
+        gather took. The cells of that cut whose row or column the axes
+        no longer hold leave the live store here."""
+        with profiler.span("matrix_read"):
+            grid, gone = matrix_channel.join(read)
+            self._drop_cells(key, gone)
+        self.matrix_reads += 1
+        return grid
+
+    def _drop_cells(self, key: ChannelKey, gone: List[tuple]) -> None:
+        if gone:
+            dropped = self._matrix[key].drop(gone)
+            self.matrix_cells_dropped += dropped
+            self.matrix_cells_live -= dropped
+
+    def tables_due(self, limit: int = 8) -> List[ChannelKey]:
+        """Tables that took ``TABLE_SWEEP_REMOVALS`` row or column
+        removals with no gather in between: nobody reads them, so nothing
+        has dropped the removed rows' cells. The deadline ticker (or
+        ``PipelineFluidService.table_sweep``) gathers them through the
+        batched read path and hands the states to :meth:`sweep_tables`."""
+        return list(itertools.islice(self._tables_due, limit))
+
+    def sweep_tables(self, states: Dict[ChannelKey, MatrixRead]) -> None:
+        """Drop the unreachable cells of tables gathered for no reader
+        (a gather dispatch that served no read)."""
+        self.reads_served -= len(states)
+        for key, read in states.items():
+            self._drop_cells(key, matrix_channel.unreachable(read))
+
     def summary_from_state(self, key: ChannelKey, h) -> dict:
         """One gathered state in the client ``summarize_core`` lane
         format (the batched-read consumer half of
-        :meth:`channel_summary`)."""
-        n = int(h.count)
+        :meth:`channel_summary`); a table's in ``SharedMatrix``'s."""
+        if isinstance(h, MatrixRead):
+            for axis in self._matrix[key].axes:
+                self._since_a[self._index[axis]] = 0
+            summary, gone = matrix_channel.summary(h)
+            self._drop_cells(key, gone)
+            return summary
         self._since_a[self._index[key]] = 0
         return {
-            "lanes": {
-                lane: np.asarray(getattr(h, lane))[:n].tolist()
-                for lane in SEGMENT_LANES
-            },
-            "count": n,
-            "min_seq": int(h.min_seq),
-            "cur_seq": int(h.cur_seq),
+            **lanes_summary(h),
             "payloads": dict(self.payloads[key]),
             "intervals": {},
         }
@@ -1692,10 +1830,19 @@ class DeviceFleetBackend:
         read back from device (the device-scribe producer). Returns None
         for unknown channels."""
         key = (doc_id, address)
-        if key not in self._index:
+        if not self.has_channel(doc_id, address):
             return None
         self.flush()
         return self.summary_from_state(key, self.doc_states([key])[key])
+
+    def grid(self, doc_id: str, address: str) -> Optional[list]:
+        """A matrix channel's grid from device state (a batch of one
+        through the batched read path). None for anything else."""
+        key = (doc_id, address)
+        if key not in self._matrix:
+            return None
+        self.flush()
+        return self.grid_from_state(key, self.doc_states([key])[key])
 
     def dirty_channels(self, threshold: int = 1) -> List[ChannelKey]:
         """Channels with >= threshold ops applied since their last summary
@@ -1706,7 +1853,8 @@ class DeviceFleetBackend:
         for idx, chunks in self._buffers.items():
             pending[idx] = sum(c.shape[0] for c in chunks)
         hot = np.flatnonzero(self._since_a[:n] + pending >= threshold)
-        return [self._keys[i] for i in hot]
+        keys = (self._keys[i] for i in hot)
+        return list(dict.fromkeys(self._axis_of.get(k, k) for k in keys))
 
     def _telemetry_start(self):
         """The serving-thread half of one scrape: assemble the device-side
@@ -1840,6 +1988,11 @@ class DeviceFleetBackend:
             reads_per_device_dispatch=round(
                 self.reads_per_device_dispatch, 3
             ),
+            matrix_axis_ops=self.matrix_axis_ops,
+            matrix_cell_ops=self.matrix_cell_ops,
+            matrix_cells_live=self.matrix_cells_live,
+            matrix_cells_dropped=self.matrix_cells_dropped,
+            matrix_reads=self.matrix_reads,
             hibernations=self.hibernations,
             cold_channels=len(self._cold),
             parked_rows=self._parked_rows,

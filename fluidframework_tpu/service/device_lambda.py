@@ -7,7 +7,8 @@ plugged into the partition framework by the document router
 two halves are split the TPU way: ticketing stays in the sequencer
 (``service/sequencer.py`` / the native FleetSequencer), and THIS stage —
 a consumer group on the ``deltas`` topic, demuxed per document — applies
-every sequenced string-channel op to the service's device-resident replica
+every sequenced string-channel and matrix-channel op to the service's
+device-resident replica
 (:class:`~fluidframework_tpu.service.device_backend.DeviceFleetBackend`),
 so reads, device summaries, and capacity errors come from the accelerator,
 not a host mirror.
@@ -15,7 +16,11 @@ not a host mirror.
 Wire decoding mirrors the client exactly: the same
 ``RemoteMessageProcessor`` undoes compression/chunking and the same
 ``row_from_wire`` lowering produces byte-identical kernel rows, so the
-device replica converges with every client replica by construction.
+device replica converges with every client replica by construction. A
+SharedMatrix channel's axis ops are lowered the same way
+(``axis_row_from_wire``, the row a remote ``SharedMatrix`` applies) onto
+the TWO fleet slots of its row and column axes, and its cell writes go to
+the channel's host store (``DeviceFleetBackend.enqueue_matrix``).
 
 Crash recovery: this stage checkpoints no state — its durable form IS the
 deltas log (+ device-scribe summaries). A restarted consumer replays from
@@ -33,6 +38,7 @@ from __future__ import annotations
 
 from typing import Any, List, Tuple
 
+from fluidframework_tpu.models.shared_matrix import MATRIX_KINDS
 from fluidframework_tpu.models.shared_string import row_from_wire
 from fluidframework_tpu.protocol.types import MessageType
 from fluidframework_tpu.runtime.op_lifecycle import RemoteMessageProcessor
@@ -82,8 +88,18 @@ class TpuDeliLambda(PartitionLambda):
         inner = envelope.get("contents")
         if not isinstance(inner, dict):
             return []
-        if inner.get("k") not in ("ins", "rem", "ann"):
-            return []  # not a string-kernel op (other DDS types, intervals)
+        kind = inner.get("k")
+        if kind in MATRIX_KINDS:
+            self.backend.enqueue_matrix(
+                self.doc_id, address, inner,
+                seq=msg.sequence_number,
+                ref=msg.reference_sequence_number,
+                client=msg.client_id,
+                msn=msg.minimum_sequence_number,
+            )
+            return []
+        if kind not in ("ins", "rem", "ann"):
+            return []  # not a kernel op (other DDS types, intervals)
         idx_key = (self.doc_id, address)
         # ensure() before lowering: row_from_wire records insert payloads
         # into the channel's payload dict.

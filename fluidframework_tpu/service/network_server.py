@@ -41,6 +41,7 @@ from urllib.parse import parse_qs, urlparse
 from fluidframework_tpu.service import admission, gc_policy, retry, wsproto
 from fluidframework_tpu.service.codec import from_jsonable, to_jsonable
 from fluidframework_tpu.service.local_server import LocalFluidService
+from fluidframework_tpu.service.matrix_channel import MatrixRead
 from fluidframework_tpu.telemetry import metrics, profiler
 from fluidframework_tpu.testing import faults
 from fluidframework_tpu.testing.faults import inject_fault
@@ -819,8 +820,10 @@ class FluidNetworkServer:
             and parts[2] == "channels"
         ):
             # Device-served read (GET /documents/:id/channels/:cid?view=…):
-            # the string channel's state straight from the service's
-            # device-resident replica — no client replica involved. The
+            # the channel's state straight from the service's
+            # device-resident replica — no client replica involved: a
+            # string channel's text, a matrix channel's grid, or either's
+            # summary in the client's summarize_core shape. The
             # request queues for one aggregation window and the whole
             # pending batch is served by ONE device gather + ONE
             # off-loop host transfer (r15 batched snapshot reads — the
@@ -991,13 +994,12 @@ class FluidNetworkServer:
                 # Per-request isolation: one bad channel must fail
                 # ITS reader, not every future in the batch.
                 if view == "summary":
-                    payload = json.dumps(
-                        dev.summary_from_state(key, states[key])
-                    ).encode()
+                    body = dev.summary_from_state(key, states[key])
+                elif isinstance(states[key], MatrixRead):
+                    body = {"grid": dev.grid_from_state(key, states[key])}
                 else:
-                    payload = json.dumps({
-                        "text": dev.text_from_state(key, states[key])
-                    }).encode()
+                    body = {"text": dev.text_from_state(key, states[key])}
+                payload = json.dumps(body).encode()
                 result = (200, payload)
             except Exception as e:
                 result = (
@@ -1126,6 +1128,15 @@ class FluidNetworkServer:
                     # fault) must not kill future ticks — the doc simply
                     # stays RESIDENT.
                     pass
+            # Tables that take removals and that nobody reads: gathered
+            # here so that the removed rows' cells leave the store.
+            if dev is not None and dev.tables_due(1):
+                try:
+                    await self._table_sweep(dev, loop)
+                except asyncio.CancelledError:
+                    raise
+                except Exception:
+                    pass  # the ticker's contract: the next tick retries
             # Noop consolidation's timer: a document whose client noops
             # moved the MSN and that has been quiet for 250 ms gets its
             # one server noop; the sweep's pump sequences it and the
@@ -1177,6 +1188,20 @@ class FluidNetworkServer:
             nack = getattr(self.service, "_nack_device_errors", None)
             if nack is not None:
                 nack()
+
+    @staticmethod
+    async def _table_sweep(dev, loop) -> None:
+        """One bounded gather of the tables due for it, with the read
+        path's off-loop discipline: the gather's dispatch and the drop run
+        ON the loop, the device→host transfer in the executor. A cell
+        written meanwhile is no part of the cut the gather lent."""
+        token = dev.read_start(dev.tables_due())
+        host = None
+        if token["dev"] is not None:
+            host = await loop.run_in_executor(
+                None, dev.read_transfer, token["dev"]
+            )
+        dev.sweep_tables(dev.read_finish(token, host))
 
     async def _residency_sweep(
         self, dev, loop, max_docs: int = 4,

@@ -400,6 +400,11 @@ class PipelineFluidService:
         for k in SequencerStats.__slots__:
             fold = max if k == "writer_slots_peak" else sum
             out[k] = fold([getattr(lam.stats, k) for lam in lams] or [0])
+        # The device stage's matrix channels: axis ops lowered, cell
+        # writes taken in, cells held, cells dropped, grids joined.
+        for k in ("matrix_axis_ops", "matrix_cell_ops", "matrix_cells_live",
+                  "matrix_cells_dropped", "matrix_reads"):
+            out[k] = getattr(self.device, k, 0)
         return out
 
     def noops_due(self) -> bool:
@@ -557,6 +562,14 @@ class PipelineFluidService:
         self.flush_device()
         return self.device.text(doc_id, channel_id)
 
+    def device_grid(self, doc_id: str, channel_id: str):
+        """Read a matrix channel's grid straight from the device replica
+        (rows in axis order, each a list of cell values)."""
+        assert self.device is not None, "device backend disabled"
+        self.pump()
+        self.flush_device()
+        return self.device.grid(doc_id, channel_id)
+
     def device_summary(self, doc_id: str, channel_id: str):
         """Channel summary produced from device state (the device-scribe
         producer; see service/device_scribe.py for the service stage)."""
@@ -620,6 +633,20 @@ class PipelineFluidService:
             if self._hibernate_one(doc_id):
                 done.append(doc_id)
         return done
+
+    def table_sweep(self, max_tables: int = 8) -> int:
+        """Gather the tables that took removals and that nobody has read
+        since (``DeviceFleetBackend.tables_due``) and drop their
+        unreachable cells. Like :meth:`hibernate_sweep`, never called
+        inline by the serving loop: the network server's deadline ticker
+        does it off-loop, tests and benches call this. Returns the tables
+        gathered."""
+        if self.device is None:
+            return 0
+        keys = self.device.tables_due(max_tables)
+        if keys:
+            self.device.sweep_tables(self.device.doc_states(keys))
+        return len(keys)
 
     def _hibernate_one(self, doc_id: str) -> bool:
         """The summarize→durable-pointer→evict walk for one document.

@@ -142,6 +142,12 @@ LANES: Dict[str, str] = {
     # -- start-up -----------------------------------------------------------
     "aot_build": "one AOT entry lowered and compiled (parallel/aot.call "
                  "miss), at start or inside a window",
+    # -- matrix channels (appended: a lane's trace id is its place here) ----
+    "matrix_stage": "one sequenced op of a matrix channel taken in inside "
+                    "device_stage: an axis op lowered to its kernel row "
+                    "and buffered, or a cell written to the host store",
+    "matrix_read": "a table's grid joined inside read_finish: both axes' "
+                   "handles x the cells of the gather's cut",
 }
 
 #: Deterministic Perfetto thread id per lane (tid = declaration order).

@@ -154,3 +154,40 @@ def test_matrix_farm(seed):
     drain(rts)
     grids = [m.to_list() for m in mats]
     assert grids[0] == grids[1] == grids[2]
+
+
+def test_handles_are_pulled_once_per_change_of_an_axis():
+    """``set_cell``, ``get_cell`` and ``to_list`` read an axis's handles
+    from a copy kept until that axis next changes: one device-to-host pull
+    an axis a change, however many cells are touched."""
+    a, b = pair()
+    ma, mb = a.get_channel("m"), b.get_channel("m")
+    ma.insert_rows(0, 4)
+    ma.insert_cols(0, 3)
+    drain([a, b])
+
+    def pulls(m):
+        return m._rows.pulls, m._cols.pulls
+
+    ma.to_list()
+    base = pulls(ma)
+    for r in range(4):
+        for c in range(3):
+            ma.set_cell(r, c, r * 3 + c)
+            assert ma.get_cell(r, c) == r * 3 + c
+    assert ma.to_list()[3] == [9, 10, 11]
+    assert ma.row_count == 4 and ma.col_count == 3
+    assert pulls(ma) == base  # 12 writes, 12 reads, a grid: no pull
+    drain([a, b])  # cell ops leave both axes as they are
+    assert pulls(ma) == base
+    mb.insert_rows(1, 1)  # a remote change of the row axis alone
+    drain([a, b])
+    assert ma.to_list()[1] == [None, None, None]
+    ma.set_cell(1, 0, "new")
+    assert pulls(ma) == (base[0] + 1, base[1])
+    ma.remove_cols(0, 1)  # a local change of the column axis
+    assert ma.to_list()[0] == [1, 2]
+    drain([a, b])
+    assert ma.to_list() == mb.to_list()
+    # The local op and its ack each replace the column state.
+    assert pulls(ma)[0] == base[0] + 1 and pulls(ma)[1] <= base[1] + 2

@@ -120,13 +120,13 @@ LOOP_ENTRY = {
         "device_summary", "take_inbox", "take_inbox_raw",
     ),
     "fluidframework_tpu/service/device_backend.py": (
-        "enqueue", "enqueue_frame", "flush", "needs_flush",
+        "enqueue", "enqueue_frame", "enqueue_matrix", "flush", "needs_flush",
         "needs_scan_drain", "prefetch_scan", "scan_prefetched",
         "collect_now", "pump_feed", "pump_feed_counted",
         "pump_feed_absorbed", "pump_stage", "pump_dispatch", "pressure",
         "read_start", "read_finish", "publish_metrics", "has_channel",
         "take_errors", "text_from_state", "summary_from_state",
-        "dirty_channels",
+        "grid_from_state", "tables_due", "sweep_tables", "dirty_channels",
     ),
     "fluidframework_tpu/service/lambdas.py": (
         "handler", "handler_batch", "_handle", "_handle_frame", "_emit",
