@@ -72,26 +72,27 @@ def _program(name: str, fn):
 # One jitted step shared by every pool: jax caches compilations per shape,
 # so pools of equal (D, S) reuse each other's executables across fleets.
 _jit_step = jax.jit(batched_apply_ops, donate_argnums=(0,))
-_jit_compact = jax.jit(
-    _program("fluid_compact", batched_compact), donate_argnums=(0,)
-)
 
 # Upper bound on the Pallas doc block; the kernels derive the block that
 # runs from each pool's (slots, capacity) — pallas_kernel.doc_block.
 _BLOCK_DOCS = 32
 
 
-def _per_shard(fn, sharding, n_args: int, n_replicated: int = 0):
+def _per_shard(fn, sharding, n_args: int, n_replicated: int = 0,
+               n_out: int = 1):
     """``fn`` run by every device of the pool's mesh on its own slice of
     the slot axis — no collective: documents do not depend on each
     other. The first ``n_args`` arguments are sliced along the slot axis,
-    the ``n_replicated`` after them reach every device whole."""
+    the ``n_replicated`` after them reach every device whole; each of the
+    ``n_out`` results is the devices' results laid end to end along its
+    first axis."""
     from jax.sharding import PartitionSpec as P
 
     spec = P(sharding.spec[0])
     return jax.shard_map(
         fn, mesh=sharding.mesh,
-        in_specs=(spec,) * n_args + (P(),) * n_replicated, out_specs=spec,
+        in_specs=(spec,) * n_args + (P(),) * n_replicated,
+        out_specs=spec if n_out == 1 else (spec,) * n_out,
         check_vma=False,  # pallas_call outputs carry no vma info
     )
 
@@ -109,36 +110,52 @@ def _pallas_compact(state: SegmentState) -> SegmentState:
 # 2 or 4 rows lost updates (PR 29, one chip, against the dense engines).
 _MIN_STEP_SLOTS = 8
 
+# A pool holds at most this many unbegun scans and unmerged dirty vectors:
+# the serving path begins a scan a boxcar and compacts every
+# ``compact_every``, so only a caller that does neither reaches it.
+_PENDING_MAX = 256
 
-@functools.lru_cache(maxsize=None)
-def _fused_sparse_step(kernel: str, sharding):
-    """The busy-set device step, ONE jitted donated entry — the pump's
-    dispatch unit (AOT-compiled per shape bucket by
-    ``_Pool.sparse_step_aot``) and the fault fallback's
-    (``DocFleet.apply_sparse``). ``rows_b [B, K, OP_WIDTH]`` is the
-    boxcar as staged, ``slots [B]`` the pool slot of each row:
+# A pool that no scan of a token names: nothing scanned.
+_NO_SCAN = (np.zeros(0, np.int32),) * 3
 
-    1. gather: every lane's ``[B, capacity]`` rows and the five ``[B]``
-       scalars of the boxcar's slots;
-    2. apply: the engine (the Pallas kernel or the vmapped XLA scan) on
-       that ``[B, capacity]`` state and ``rows_b`` as it is;
+
+def _busy_set_entry(name: str, engine, scope: str, n_extra: int, sharding):
+    """ONE jitted donated entry that runs ``engine`` over a SET of a
+    pool's slots and leaves every other slot's bytes as they were — the
+    shape the busy-set step and the dirty-set compaction share. The
+    entry takes ``(state, *extra, slots)``: ``slots [M]`` names the
+    slots, ``extra`` (``n_extra`` arguments with a leading ``M`` axis)
+    goes to the engine as it is:
+
+    1. gather: every lane's ``[M, capacity]`` rows and the five ``[M]``
+       scalars of those slots;
+    2. ``scope``: ``engine(busy, *extra)`` on that ``[M, capacity]``
+       state;
     3. scatter: the results back into the DONATED pool state, in place.
 
-    The step's cost follows ``B``, not the pool's slot count. A row of
+    It returns the state and the health scan of what it ran over:
+    ``[2, M]``, the (count, err) of each slot after the engine, in the
+    order of ``slots``.
+
+    The cost follows ``M``, not the pool's slot count. An entry of
     padding or of another capacity tier carries slot ``n_slots``, out of
-    range: it is gathered from the last slot (so its ops run on a copy of
-    that document), and its result is dropped by the scatter — an
-    untouched slot's bytes are the same before and after. Slots are UNIQUE within a boxcar
-    (``pump_stage`` stages one row per channel), so neither gather nor
+    range: it is gathered from the last slot (so the engine runs on a
+    copy of that document), its result is dropped by the scatter and its
+    scan column reads 0 — an untouched slot's bytes are the same before
+    and after. Slots are UNIQUE within one call (``pump_stage`` stages
+    one row per channel; the dirty set is a set), so neither gather nor
     scatter needs a combine rule.
 
     A mesh-sharded pool runs the same body per device under
-    ``shard_map`` with the boxcar replicated: each device subtracts its
-    slice's first slot and treats every slot outside its slice as out of
-    range — no collective."""
-    engine = _pallas_apply if kernel == "pallas" else batched_apply_ops
+    ``shard_map`` with ``extra`` and ``slots`` replicated: each device
+    subtracts its slice's first slot and treats every slot outside its
+    slice as out of range — no collective. The scan is then the
+    devices' ``[2, M]`` laid end to end, ``[2 * devices, M]``, each slot
+    non-zero in its owner's pair of rows alone: the host adds them up
+    (``DocFleet.finish_scan``)."""
 
-    def busy_step(state, rows_b, slots):
+    def body(state, *args):
+        *extra, slots = args
         n = state.count.shape[0]
         if sharding is not None:  # this device's slice begins here
             slots = slots - jax.lax.axis_index(sharding.spec[0]) * n
@@ -149,21 +166,41 @@ def _fused_sparse_step(kernel: str, sharding):
                 for x in state
             ])
         # Negative indices would wrap NumPy-style: send them out of range.
-        local = jnp.where((slots >= 0) & (slots < n), slots, n + pad)
+        mine = (slots >= 0) & (slots < n)
+        local = jnp.where(mine, slots, n + pad)
         with jax.named_scope("gather"):
             at = jnp.minimum(local, n - 1)  # a dropped row reads slot n-1
             busy = SegmentState(*[x.at[at].get(mode="clip") for x in state])
-        with jax.named_scope("apply"):
-            new = engine(busy, rows_b)
+        with jax.named_scope(scope):
+            new = engine(busy, *extra)
         with jax.named_scope("scatter"):
             out = [
                 x.at[local].set(y, mode="drop") for x, y in zip(state, new)
             ]
-        return SegmentState(*[x[:n] for x in out] if pad else out)
+        with jax.named_scope("scan"):
+            scan = jnp.where(mine, jnp.stack([new.count, new.err]), 0)
+        return SegmentState(*[x[:n] for x in out] if pad else out), scan
 
     if sharding is not None:
-        busy_step = _per_shard(busy_step, sharding, 1, n_replicated=2)
-    return jax.jit(_program("fluid_step", busy_step), donate_argnums=(0,))
+        body = _per_shard(
+            body, sharding, 1, n_replicated=n_extra + 1, n_out=2
+        )
+    # graftlint: recompile(built once per (engine, placement): both callers below are lru_cached builders)
+    return jax.jit(_program(name, body), donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=None)
+def _fused_sparse_step(kernel: str, sharding):
+    """The busy-set device step — the pump's dispatch unit (AOT-compiled
+    per shape bucket by ``_Pool.sparse_step``) and the fault
+    fallback's (``DocFleet.apply_sparse``): :func:`_busy_set_entry` with
+    the apply engine (the Pallas kernel or the vmapped XLA scan), called
+    ``(state, rows_b, slots)``. ``rows_b [B, K, OP_WIDTH]`` is the boxcar
+    as staged, ``slots [B]`` the pool slot of each row. Its ``[2, B]``
+    scan is the boxcar's health readback: the step's own output, not a
+    second program."""
+    engine = _pallas_apply if kernel == "pallas" else batched_apply_ops
+    return _busy_set_entry("fluid_step", engine, "apply", 1, sharding)
 
 
 # The Pallas compact unrolls log2(capacity) shift steps over every vreg of
@@ -173,27 +210,25 @@ def _fused_sparse_step(kernel: str, sharding):
 # per ``compact_every`` boxcars, so past this tier the fleet takes XLA's.
 _PALLAS_COMPACT_MAX_CAP = 256
 
+# One compaction pass takes at most this many table cells' worth of
+# documents a lane (1,024 documents at the base tier of 128 rows, 8 at
+# 16,384 rows), so what a pass gathers stays a few megabytes at every
+# tier: ``_Pool.compact_bucket``.
+_COMPACT_CELLS = 1 << 17
+
 
 @functools.lru_cache(maxsize=None)
 def _compact_entry(capacity: int, kernel: str, sharding):
-    """The compact engine as one jitted donated entry per tier, engine
-    and placement (jax caches the compilations per pool shape)."""
-    if kernel != "pallas" or capacity > _PALLAS_COMPACT_MAX_CAP:
-        return _jit_compact
-    if sharding is not None:
-        fn = _per_shard(_pallas_compact, sharding, 1)
+    """The dirty-set compaction of one tier, engine and placement:
+    :func:`_busy_set_entry` with the compact engine, called
+    ``(state, slots)`` over ``slots [D]``, the slots written since the
+    pool's last compaction. Its ``[2, D]`` scan carries the counts
+    compaction left, for the demotion pass."""
+    if kernel == "pallas" and capacity <= _PALLAS_COMPACT_MAX_CAP:
+        engine = _pallas_compact
     else:
-        fn = _pallas_compact
-    return jax.jit(_program("fluid_compact", fn), donate_argnums=(0,))
-
-
-@jax.jit
-@functools.partial(_program, "fluid_scan")
-def _pool_scan(state: SegmentState):
-    """One [2, n_slots] (count, err) scan per pool — the fused health
-    readback the serving path consumes asynchronously (one transfer per
-    boxcar instead of two synchronous pulls per flush)."""
-    return jnp.stack([state.count, state.err])
+        engine = batched_compact
+    return _busy_set_entry("fluid_compact", engine, "compact", 0, sharding)
 
 
 # Device telemetry lanes (telemetry/README.md): one jitted per-pool
@@ -410,8 +445,6 @@ def _write_slot(state: SegmentState, slot, doc: SegmentState):
     )
 
 
-
-
 class _Pool:
     """One capacity tier: a [D, S] batched state + slot bookkeeping.
     ``doc_of_slot`` is an int32 array (-1 = free) so batch routing is a
@@ -441,54 +474,112 @@ class _Pool:
         # path that never popped it), so a stale entry skips instead of
         # double-allocating; an exhausted list falls back to the scan.
         self._free: List[int] = list(range(n_slots - 1, -1, -1))
-        # The eager engines (warm-up, dense apply, demotion's compact):
-        # the same functions the AOT entries below compile.
+        # The eager dense engine (warm-up, ``DocFleet.apply``).
         if kernel == "pallas" and sharding is not None:
             self._step = _mesh_step(sharding)
         elif kernel == "pallas":
             self._step = _pallas_apply
         else:
             self._step = _jit_step
-        self._compact = _compact_entry(capacity, kernel, sharding)
+        # The slots written since the pool's last compaction, as the
+        # slot vectors the writers had in hand (a set once taken): what
+        # compaction runs over. A slot not written since has nothing new
+        # to reclaim, because ``min_seq`` reaches a slot only through the
+        # ops applied to it.
+        self._dirty: List[np.ndarray] = []
+        # Health scans not yet begun: ``(dev, at, slots)`` of every step
+        # and compaction pass since the last ``DocFleet.begin_scan`` —
+        # ``dev`` the program's ``[2, M]`` output, ``slots`` the real
+        # slots among its ``M`` entries and ``at`` their places.
+        self._scans: List[Tuple[Any, np.ndarray, np.ndarray]] = []
+        # A cold slot a demotion pass had no budget left for, with its
+        # placement generation: the next pass takes it up again (no later
+        # scan will name a document that nothing writes to).
+        self.cold_left: Dict[int, int] = {}
 
-    def sparse_step(self, dev_rows, dev_slots) -> None:
-        """The busy-set step through its jitted entry: the fault
-        fallback's and the one-shot flush's call (a recovery path builds
-        no AOT entry)."""
-        self.state = _fused_sparse_step(self.kernel, self.sharding)(
-            self.state, dev_rows, dev_slots
+    @property
+    def compact_bucket(self) -> int:
+        """Slots one compaction pass runs over, ``D``: fixed by the
+        pool's tier and slot count (so one program a pool shape), never
+        below ``_MIN_STEP_SLOTS`` (the v5e's small-operand scatter)."""
+        return max(
+            _MIN_STEP_SLOTS,
+            min(self.n_slots, _COMPACT_CELLS // self.capacity),
         )
 
-    def sparse_step_aot(self, dev_rows, dev_slots) -> None:
-        """One pump dispatch: the busy-set step (gather, apply on
-        ``[B, capacity]``, scatter back in place) through the cached AOT
-        donated executable for this pool's shape bucket — zero tracing,
-        zero jit-cache lookup on the steady-state path. ``dev_rows`` is
-        the ring-staged device ``[B, K, OP_WIDTH]`` block (NOT donated:
-        a multi-tier boxcar goes to several pools as it is);
-        ``dev_slots`` the per-row slot vector (out-of-range = dropped)."""
+    def mark_dirty(self, slots) -> None:
+        """Remember ``slots`` as written since the last compaction."""
+        self._dirty.append(np.asarray(slots, np.int32).reshape(-1))
+        if len(self._dirty) > _PENDING_MAX:  # a caller that never compacts
+            self._dirty = [np.unique(np.concatenate(self._dirty))]
+
+    def _run(self, key, build, use_aot: bool, at, slots, *args) -> None:
+        """One busy-set entry over this pool's state: through the cached
+        AOT donated executable under ``key`` (the pump: zero tracing,
+        zero jit-cache lookup on the steady-state path) or through
+        ``build()``'s jitted entry (the fault fallback, the one-shot
+        flush, callers outside the pump: a recovery path builds no AOT
+        entry). Keeps the entry's scan, with the real ``slots`` among
+        its columns and their places ``at``, for the next
+        ``DocFleet.begin_scan``."""
+        if use_aot:
+            out = aot.call(key, build, self.state, *args)
+        else:
+            out = build()(self.state, *args)
+        self.state, scan = out
+        self._scans.append((scan, at, slots))
+        if len(self._scans) > _PENDING_MAX:  # a caller that never scans
+            del self._scans[: _PENDING_MAX // 2]
+
+    def sparse_step(
+        self, dev_rows, slots: np.ndarray, use_aot: bool = False
+    ) -> None:
+        """One busy-set step (gather, apply on ``[B, capacity]``, scatter
+        back in place; ``use_aot``: the pump's dispatch, see
+        :meth:`_run`). ``dev_rows`` is the device ``[B, K, OP_WIDTH]``
+        block (NOT donated: a multi-tier boxcar goes to several pools as
+        it is); ``slots [B]`` the per-row slot vector on the host
+        (``n_slots``, out of range = dropped)."""
+        at = np.flatnonzero(slots < self.n_slots)
+        real = slots[at]
+        self.mark_dirty(real)
         key = (
             "fleet_sparse_step", self.capacity, self.n_slots,
             tuple(dev_rows.shape), self.kernel, self.sharding,
         )
-        self.state = aot.call(
-            key,
-            lambda: _fused_sparse_step(self.kernel, self.sharding),
-            self.state, dev_rows, dev_slots,
+        self._run(
+            key, lambda: _fused_sparse_step(self.kernel, self.sharding),
+            use_aot, at, real, dev_rows, jax.device_put(slots),
         )
 
-    def compact_aot(self) -> None:
-        """Compact through the cached AOT donated entry (the pump's
-        cadence compaction — same engine choice as ``_compact``)."""
+    def compact_dirty(self, use_aot: bool = False) -> int:
+        """Compact the slots written since the last compaction, a
+        ``compact_bucket`` at a time (the set-up's load dirties more than
+        one bucket: the same program in several passes), and forget them.
+        The engine is the tier's (``_compact_entry``); ``use_aot`` is the
+        pump's cadence compaction (:meth:`_run`). Returns the slots the
+        passes ran over, padding included."""
+        if not self._dirty:
+            return 0
+        dirty = np.unique(np.concatenate(self._dirty))
+        self._dirty = []
+        d = self.compact_bucket
         key = (
-            "fleet_compact", self.capacity, self.n_slots, self.kernel,
+            "fleet_compact", self.capacity, self.n_slots, d, self.kernel,
             self.sharding,
         )
-        self.state = aot.call(
-            key,
-            lambda: _compact_entry(self.capacity, self.kernel, self.sharding),
-            self.state,
-        )
+        for o in range(0, dirty.size, d):
+            real = dirty[o: o + d]
+            slots = np.full(d, self.n_slots, np.int32)  # pad = dropped
+            slots[: real.size] = real
+            self._run(
+                key,
+                lambda: _compact_entry(
+                    self.capacity, self.kernel, self.sharding
+                ),
+                use_aot, np.arange(real.size), real, jax.device_put(slots),
+            )
+        return d * -(-dirty.size // d)
 
     def _put(self, host: SegmentState):
         """Host state -> device, honoring the pool's mesh sharding (the
@@ -670,6 +761,7 @@ class DocFleet:
             routed[live] = ops[pool.doc_of_slot[live]]
             routing += time.perf_counter() - t0
             pool.state = pool._step(pool.state, jnp.asarray(routed))
+            pool.mark_dirty(live)
         self.last_routing_s = routing
         return self.stats()
 
@@ -690,7 +782,7 @@ class DocFleet:
         n, k = ops_b.shape[:2]
         rows_b = np.zeros((_pow2_at_least(n), k, OP_WIDTH), np.int32)
         rows_b[:n] = ops_b
-        self._step_pools(docs, jax.device_put(rows_b), _Pool.sparse_step)
+        self._step_pools(docs, jax.device_put(rows_b), use_aot=False)
 
     def dispatch_staged(self, docs, dev_rows) -> None:
         """Apply one ring-staged boxcar: ``docs`` are external doc ids,
@@ -699,13 +791,15 @@ class DocFleet:
         previous step computed — only the tiny per-pool slot vectors
         cross host→device at dispatch time). Each pool's gather + apply
         + scatter runs as one cached AOT donated executable over the
-        boxcar's ``B`` rows (``_Pool.sparse_step_aot``), whatever the
+        boxcar's ``B`` rows (``_Pool.sparse_step``), whatever the
         pool's size."""
-        self._step_pools(docs, dev_rows, _Pool.sparse_step_aot)
+        self._step_pools(docs, dev_rows, use_aot=True)
 
-    def _step_pools(self, docs, dev_rows, step) -> None:
+    def _step_pools(self, docs, dev_rows, use_aot: bool) -> None:
         """Route one boxcar to the pools its documents live in and run
-        ``step(pool, dev_rows, dev_slots)`` on each. Row i belongs to
+        ``pool.sparse_step(dev_rows, slots, use_aot)`` on each (which also
+        notes the slots as dirty and keeps the step's scan for the next
+        :meth:`begin_scan`). Row i belongs to
         docs[i]; in a pool's slot vector a padding row (i >= len(docs)),
         a row of another tier and a row of a document evicted from the
         fleet all carry ``n_slots``, out of range, so the step's scatter
@@ -730,55 +824,101 @@ class DocFleet:
             sel = np.flatnonzero(caps == cap)
             slots[sel] = slot_arr[docs[sel]]
             routing += time.perf_counter() - t0
-            step(pool, dev_rows, jax.device_put(slots))
+            pool.sparse_step(dev_rows, slots, use_aot)
         self.last_routing_s = routing
         self.last_step_docs = b * len(uniq)
 
-    def compact_aot(self) -> None:
-        """Compact every pool through the cached AOT donated entries —
-        the pump's cadence compaction."""
-        for pool in self.pools.values():
-            pool.compact_aot()
+    def compact_aot(self) -> int:
+        """The pump's cadence compaction: every pool's dirty slots
+        through the cached AOT donated entries
+        (:meth:`_Pool.compact_dirty`). Returns the slots compacted,
+        padding included."""
+        return sum(p.compact_dirty(True) for p in self.pools.values())
 
-    def begin_scan(self) -> Dict[int, object]:
-        """Start an async (count, err) readback of every pool; returns a
-        token for :meth:`finish_scan`. Device arrays snapshot the state
-        at call time, so consuming the token after further dispatches
-        reads a consistent (if slightly stale) view. The token also
-        snapshots each pool's slot generations: a slot whose occupant
-        changed between begin and finish is dropped at finish (its scan
-        column describes the departed doc, not the new one)."""
+    def compact(self) -> int:
+        """:meth:`compact_aot` through the jitted entries: the one-shot
+        flush's cadence, and callers outside the pump."""
+        return sum(p.compact_dirty(False) for p in self.pools.values())
+
+    def begin_scan(self) -> Dict[int, tuple]:
+        """Start the async (count, err) readback of what ran since the
+        last call: per pool, the ``[2, M]`` scans the busy-set steps and
+        compaction passes returned beside the state (the programs' own
+        outputs; nothing is launched here). Returns a token for
+        :meth:`finish_scan`: cap -> ``(devs, at, slots, gens)``, the
+        device arrays, the places of the real slots among their columns
+        laid end to end, those slots, and the slots' placement
+        generations as of now: a slot whose occupant changes between
+        begin and finish is dropped at finish (its scan column describes
+        the departed doc, not the new one). Everything on the host is as
+        long as what ran — a boxcar's ``B``, a compaction's ``D`` — and
+        nothing as long as the pool."""
         token = {}
         for cap, pool in self.pools.items():
-            dev = _pool_scan(pool.state)
-            dev.copy_to_host_async()
-            token[cap] = (dev, pool.slot_gen.copy())
+            if not pool._scans:
+                continue
+            parts, pool._scans = pool._scans, []
+            for dev, _at, _slots in parts:
+                dev.copy_to_host_async()
+            if len(parts) == 1:
+                dev, at, slots = parts[0]
+                devs = (dev,)
+            else:
+                devs = tuple(p[0] for p in parts)
+                starts = np.cumsum([0] + [d.shape[-1] for d in devs[:-1]])
+                at = np.concatenate(
+                    [p[1] + o for p, o in zip(parts, starts)]
+                )
+                slots = np.concatenate([p[2] for p in parts])
+            token[cap] = (devs, at, slots, pool.slot_gen[slots])
         return token
 
-    def finish_scan(self, token, host=None) -> Dict[int, np.ndarray]:
-        """Wait for a begin_scan token: cap -> [2, n_slots] host array.
-        Columns for slots reassigned since begin_scan are zeroed (no
-        false promotion/nack for the new occupant; the next scan sees
-        its true state). ``host`` lets a caller that already ran the
-        blocking device→host transfer off-thread (the network server's
-        deadline ticker — DeviceFleetBackend.scan_transfer) pass the
-        per-cap host arrays in, so only the slot-generation masking —
-        which reads live pool state — runs here."""
-        out = {}
-        for cap, (dev, gen_snap) in token.items():
-            arr = np.array(dev) if host is None else host[cap]
-            pool = self.pools.get(cap)
-            if pool is not None:
-                n = min(arr.shape[1], len(gen_snap), len(pool.slot_gen))
-                stale = pool.slot_gen[:n] != gen_snap[:n]
-                if stale.any():
-                    arr[:, :n][:, stale] = 0
-            out[cap] = arr
-        return out
+    @staticmethod
+    def scan_size(token) -> int:
+        """Columns a token's scans carry, padding included."""
+        return sum(
+            dev.shape[-1] for devs, *_ in token.values() for dev in devs
+        )
 
-    def compact(self) -> None:
-        for pool in self.pools.values():
-            pool.state = pool._compact(pool.state)
+    def finish_scan(
+        self, token, host=None
+    ) -> Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray]]:
+        """Wait for a begin_scan token: cap -> ``(slots, counts, errs)``
+        of the scanned slots, each slot once with its newest reading (a
+        compaction's pass and a later step may both name it). Slots
+        reassigned since begin_scan are left out (no false promotion or
+        nack for the new occupant; its own next step scans it). ``host``
+        lets a caller that already ran the blocking device→host transfer
+        off-thread (the network server's deadline ticker —
+        DeviceFleetBackend.scan_transfer) pass the per-cap host arrays
+        in, so only the slot-generation masking — which reads live pool
+        state — runs here."""
+        out = {}
+        for cap, (devs, at, slots, gens) in token.items():
+            pool = self.pools.get(cap)
+            if pool is None:
+                continue
+            arrs = (
+                [np.array(dev) for dev in devs] if host is None
+                else host[cap]
+            )
+            # A mesh pool's scan is one [2, M] a device, end to end.
+            arrs = [
+                a if a.shape[0] == 2
+                else a.reshape(-1, 2, a.shape[-1]).sum(axis=0)
+                for a in arrs
+            ]
+            scan = arrs[0] if len(arrs) == 1 else np.concatenate(arrs, axis=1)
+            keep = pool.slot_gen[slots] == gens
+            if len(arrs) > 1:  # the last reading of a slot is the newest
+                _, last = np.unique(slots[::-1], return_index=True)
+                newest = np.zeros(len(slots), bool)
+                newest[len(slots) - 1 - last] = True
+                keep &= newest
+            if not keep.all():
+                at, slots = at[keep], slots[keep]
+            out[cap] = (slots, scan[0, at], scan[1, at])
+        return out
 
     def _telemetry_device(self):
         """The device half of one scrape, NO readback: every pool's
@@ -840,22 +980,28 @@ class DocFleet:
     # -- capacity lifecycle ---------------------------------------------------
 
     def check_and_migrate(
-        self, counts: Optional[Dict[int, np.ndarray]] = None
+        self, scans: Optional[Dict[int, tuple]] = None
     ) -> List[int]:
         """Host-driven promotion pass: move every doc above the high-water
         mark into the next capacity tier. Call between batches; returns the
-        promoted doc ids. ``counts`` (cap -> [n_slots], e.g. from a
-        ``begin_scan`` token) substitutes for the synchronous count-lane
-        readback — a one-boxcar-stale trigger is sound as long as per-doc
-        growth per flush stays within HALF the tier headroom (the serving
-        backend halves its chunk limit for exactly this)."""
+        promoted doc ids. ``scans`` (what :meth:`finish_scan` returns: cap
+        -> the scanned slots with their counts and errs) substitutes for
+        the synchronous count-lane readback: the pass then walks the
+        scanned slots alone, and a pool no scan names has grown nowhere.
+        A one-boxcar-stale trigger is sound as long as per-doc growth per
+        flush stays within HALF the tier headroom (the serving backend
+        halves its chunk limit for exactly this)."""
         promoted: List[int] = []
         for cap in sorted(self.pools):
             pool = self.pools[cap]
             if cap * 2 > self.max_capacity:
                 continue
-            c = counts.get(cap) if counts is not None else None
-            hot_slots = self._hot_slots(pool, cap, c)
+            if scans is None:
+                hot_slots = self._hot_slots(pool, cap)
+            elif cap in scans:
+                hot_slots = self._hot_slots(pool, cap, scans[cap])
+            else:
+                continue
             hot = [(int(s), int(pool.doc_of_slot[s])) for s in hot_slots]
             if not hot:
                 continue
@@ -882,6 +1028,7 @@ class DocFleet:
         dst_host = SegmentState(*[np.array(x) for x in dst.state])  # graftlint: readback(same promotion copy)
         empty = _np_batched_state(1, cap)
         free = [int(s) for s in np.flatnonzero(dst.doc_of_slot < 0)]
+        dst.mark_dirty(free[: len(hot)])  # what the source had not compacted
         for (slot, doc), dst_slot in zip(hot, free):
             for lane in SEGMENT_LANES:
                 src = getattr(src_host, lane)[slot]
@@ -911,29 +1058,41 @@ class DocFleet:
 
     def check_and_demote(
         self,
-        counts: Optional[Dict[int, np.ndarray]] = None,
+        scans: Optional[Dict[int, tuple]] = None,
         max_moves: int = 32,
     ) -> List[int]:
         """Host-driven demotion pass — the inverse of the promotion walk:
         move docs whose live rows fell below ``low_water * cap`` down one
         capacity tier, so a cooling doc releases HBM in steps before
-        hibernation takes it out entirely. ``counts`` substitutes for the
-        synchronous readback exactly as in :meth:`check_and_migrate`; a
+        hibernation takes it out entirely. ``scans`` substitutes for the
+        synchronous readback exactly as in :meth:`check_and_migrate` (a
+        compaction pass's scan carries the counts it left, so a document
+        that cooled by reclaim is seen without being written to); a
         one-boxcar-stale trigger is sound because the fresh post-compact
         host copy re-verifies the fit before any row is moved (a doc that
         heated back up in the gap simply stays put). ``max_moves`` bounds
         the host copies per pass — demotion is a background economy, not
-        a correctness deadline, so the rest waits for the next sweep."""
+        a correctness deadline, so the rest is remembered
+        (``_Pool.cold_left``) and waits for the next pass."""
         demoted: List[int] = []
         for cap in sorted(self.pools, reverse=True):
-            if len(demoted) >= max_moves:
-                break
             pool = self.pools[cap]
             if cap // 2 < self.base_capacity:
                 continue
-            c = counts.get(cap) if counts is not None else None
-            cold_slots = self._cold_slots(pool, cap, c)
-            budget = max_moves - len(demoted)
+            budget = max(max_moves - len(demoted), 0)
+            if scans is None:
+                if not budget:
+                    break
+                cold_slots = self._cold_slots(pool, cap)
+            elif cap in scans or pool.cold_left:
+                cold_slots = self._cold_slots(
+                    pool, cap, scans.get(cap, _NO_SCAN)
+                )
+                pool.cold_left = {
+                    int(s): int(pool.slot_gen[s]) for s in cold_slots[budget:]
+                }
+            else:
+                continue
             cold = [
                 (int(s), int(pool.doc_of_slot[s]))
                 for s in cold_slots[:budget]
@@ -954,7 +1113,8 @@ class DocFleet:
         candidates that no longer fit, or whose sticky err lane fired,
         are skipped — moving corrupt state would launder the error)."""
         new_cap = cap // 2
-        pool.state = pool._compact(pool.state)
+        pool.mark_dirty([slot for slot, _doc in cold])
+        pool.compact_dirty()
         dst = self.pools.get(new_cap)
         if dst is None:
             dst = self.pools[new_cap] = _Pool(
@@ -976,6 +1136,7 @@ class DocFleet:
                 continue
             dst_slot = free[fi]
             fi += 1
+            dst.mark_dirty([dst_slot])
             for lane in SEGMENT_LANES:
                 src = getattr(src_host, lane)[slot]
                 d = getattr(dst_host, lane)
@@ -1005,39 +1166,43 @@ class DocFleet:
         return moved
 
     def _cold_slots(
-        self, pool: _Pool, cap: int, counts: Optional[np.ndarray] = None
+        self, pool: _Pool, cap: int, scan: Optional[tuple] = None
     ) -> np.ndarray:
-        """Live slots below the low-water mark — the demotion predicate
-        (the half-width fit itself is re-checked post-compact against a
-        fresh host copy in :meth:`_demote_batch`)."""
-        if counts is None:
+        """Live slots below the low-water mark, ascending — the demotion
+        predicate (the half-width fit itself is re-checked post-compact
+        against a fresh host copy in :meth:`_demote_batch`). With a
+        ``scan`` the scanned slots alone are read, and the slots an
+        earlier pass left over join them: unless a newer scan names the
+        slot (its reading then stands) or its occupant changed."""
+        if scan is None:
             counts = np.asarray(pool.state.count)  # graftlint: readback(synchronous fallback when no begin_scan token was supplied)
-        if len(counts) < pool.n_slots:
-            counts = np.concatenate(
-                [counts, np.zeros(pool.n_slots - len(counts), np.int32)]
+            return np.flatnonzero(
+                (pool.doc_of_slot >= 0) & (counts < self.low_water * cap)
             )
-        return np.flatnonzero(
-            (pool.doc_of_slot >= 0)
-            & (counts[: pool.n_slots] < self.low_water * cap)
-        )
+        slots, counts, _errs = scan
+        cold = slots[counts < self.low_water * cap]
+        left = pool.cold_left
+        if left:
+            for s in slots.tolist():
+                left.pop(s, None)
+            kept = [s for s, g in left.items() if pool.slot_gen[s] == g]
+            cold = np.concatenate([cold, np.asarray(kept, cold.dtype)])
+        return np.sort(cold[pool.doc_of_slot[cold] >= 0])
 
     def _hot_slots(
-        self, pool: _Pool, cap: int, counts: Optional[np.ndarray] = None
+        self, pool: _Pool, cap: int, scan: Optional[tuple] = None
     ) -> np.ndarray:
-        """Live slots above the high-water mark — the single promotion
-        predicate shared by tier promotion and sharded-overflow scans."""
-        if counts is None:
+        """Live slots above the high-water mark, ascending — the single
+        promotion predicate shared by tier promotion and sharded-overflow
+        scans. With a ``scan`` the scanned slots alone are read."""
+        if scan is None:
             counts = np.asarray(pool.state.count)  # graftlint: readback(synchronous fallback when no begin_scan token was supplied)
-        if len(counts) < pool.n_slots:
-            # The pool grew slots after the scan was taken: unseen slots
-            # read as empty (they were just placed; next scan covers them).
-            counts = np.concatenate(
-                [counts, np.zeros(pool.n_slots - len(counts), np.int32)]
+            return np.flatnonzero(
+                (pool.doc_of_slot >= 0) & (counts > self.high_water * cap)
             )
-        return np.flatnonzero(
-            (pool.doc_of_slot >= 0)
-            & (counts[: pool.n_slots] > self.high_water * cap)
-        )
+        slots, counts, _errs = scan
+        hot = slots[counts > self.high_water * cap]
+        return np.sort(hot[pool.doc_of_slot[hot] >= 0])
 
     def overflowing_docs(self) -> List[int]:
         """Healthy docs above high water in a tier that cannot promote
@@ -1099,6 +1264,7 @@ class DocFleet:
             pool.grow_slots()
             slot = pool.free_slot()
         pool.state = _write_slot(pool.state, slot, state)
+        pool.mark_dirty([slot])  # as compacted as the host state was
         pool.doc_of_slot[slot] = doc
         pool.slot_gen[slot] += 1
         self.placement[doc] = (cap, slot)

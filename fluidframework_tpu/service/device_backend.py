@@ -213,7 +213,7 @@ class DeviceFleetBackend:
         self._buffers: Dict[int, List[np.ndarray]] = {}
         self._buffered_rows = 0
         self._flushes = 0
-        self._scan_token = None  # in-flight async (count, err) pool scan
+        self._scan_token = None  # in-flight async (count, err) scan of a boxcar's slots
         # Sampled-frame trace spine (telemetry/tracing.py): traces of
         # frames enqueued since the last flush, then awaiting the health
         # scan that covers their boxcar. Untraced frames never land here.
@@ -249,6 +249,12 @@ class DeviceFleetBackend:
             # step's kernel ran over (padded): against busy documents,
             # what the pow2 bucket and a multi-tier boxcar cost.
             "step_docs": 0,
+            # Σ D, the slots the cadence compaction's passes ran over
+            # (padded to the pool's bucket), and Σ columns the consumed
+            # health scans carried (padded): both follow the boxcars'
+            # slots, neither the pools'.
+            "compact_slots": 0,
+            "scan_slots": 0,
         }
         # The continuous device pump: double-buffered ingest ring + AOT
         # donated dispatch. pump_mode routes flush() through the ring;
@@ -285,7 +291,7 @@ class DeviceFleetBackend:
         self.feed_deadline_ms = float(feed_deadline_ms)
         self._feed_edge: Optional[float] = None
         self.feed_triggers: Dict[str, int] = {"size": 0, "deadline": 0}
-        self._scan_prefetch: Optional[Tuple[object, Dict[int, np.ndarray]]] = None
+        self._scan_prefetch: Optional[Tuple[object, Dict[int, List[np.ndarray]]]] = None
         # Fleet-as-cache (r19): the residency manager owns the per-doc
         # RESIDENT → IDLE → HIBERNATING → COLD → WAKING lifecycle;
         # ``max_resident`` (0 = unbounded) is the slot budget that turns
@@ -795,9 +801,10 @@ class DeviceFleetBackend:
         Weak #3's O(fleet) boxcar; ROADMAP S2's O(pool) step).
 
         Health readbacks are ASYNC and one boxcar stale: each dispatch
-        round starts one fused (count, err) pool scan
-        (``DocFleet.begin_scan``) and consumes the PREVIOUS round's, so
-        a flush never waits on its own readback. Soundness: the per-doc chunk limit
+        round begins the readback of the ``[2, B]`` (count, err) scan its
+        own step returned (``DocFleet.begin_scan``: the boxcar's slots,
+        not the pool's) and consumes the PREVIOUS round's, so a flush
+        never waits on its own readback. Soundness: the per-doc chunk limit
         is HALF the tier headroom, so a promotion trigger read one flush
         late still fires before the doc can overflow.
         ``flush_totals`` records where the wall went (host staging vs
@@ -971,7 +978,7 @@ class DeviceFleetBackend:
                     doc.compact()
                 doc.rebalance()  # self-compacts when it triggers
             if compact_now:
-                self.fleet.compact()
+                self.flush_totals["compact_slots"] += self.fleet.compact()
         self._buffered_rows = 0
         self._close_pending_traces()
         totals = self.flush_totals
@@ -1140,9 +1147,9 @@ class DeviceFleetBackend:
         injection boundary. The boundary wraps the AOT dispatch alone;
         an INJECTED fault fires before the dispatch runs, so the caller's
         fallback provably re-applies un-applied rows only. Scan-begin
-        runs after either path in the caller; a crash that skips it is
-        covered by the next dispatch's scan (err lanes are sticky and
-        counts are current-state reads)."""
+        runs after either path in the caller; a crash that skips it
+        leaves the step's scan waiting in its pool, and the next
+        dispatch's ``begin_scan`` takes both."""
         self.fleet.dispatch_staged(docs, dev_rows)
 
     def _dispatch_fallback(self, slot: _RingSlot, in_fleet: np.ndarray) -> None:
@@ -1216,8 +1223,12 @@ class DeviceFleetBackend:
         stale; promotions it carries re-route this slot's docs before the
         step; (2) the busy-set step via the cached AOT donated executables
         (``DocFleet.dispatch_staged`` — zero tracing, only the tiny slot
-        vectors cross the link); (3) begin this boxcar's scan. The scan
-        consumption is the pump's ONLY device→host transfer."""
+        vectors cross the link); (3) begin the readback of this boxcar's
+        scan, the ``[2, B]`` its step returned (and, the round after a
+        cadence, the ``[2, D]`` of the compaction passes); (4) on the
+        cadence, compact the slots stepped since the last compaction.
+        The scan consumption is the pump's ONLY device→host transfer,
+        and nothing on the host is as long as a pool."""
         slot = self._ring.pop()
         newly: List[ChannelKey] = []
         self._consume_pending_scan(newly)
@@ -1269,7 +1280,7 @@ class DeviceFleetBackend:
                     doc.compact()
                 doc.rebalance()  # self-compacts when it triggers
         if compact_now:
-            self.fleet.compact_aot()
+            self.flush_totals["compact_slots"] += self.fleet.compact_aot()
         routing = 0.0
         if in_fleet.any():
             routing = self.fleet.last_routing_s
@@ -1459,7 +1470,7 @@ class DeviceFleetBackend:
         return self._scan_token
 
     @staticmethod
-    def scan_transfer(token) -> Dict[int, np.ndarray]:
+    def scan_transfer(token) -> Dict[int, List[np.ndarray]]:
         """The blocking device→host half of one scan consume — ``token``
         holds immutable concrete device arrays, so an async server may
         run THIS half (and only this half) off the serving thread, then
@@ -1467,12 +1478,14 @@ class DeviceFleetBackend:
         one-boxcar-stale transfer the pump would run inline, moved
         off-loop — not an extra readback (the ticker adds zero new
         transfers; the counting-shim test pins it)."""
-        return {
-            cap: np.array(dev)  # graftlint: readback(the pump's one-boxcar-stale health scan, run off-loop by the deadline ticker — the same single transfer per round, telemetry/README.md contract)
-            for cap, (dev, _gen) in token.items()
-        }
+        host = {}
+        for cap, (devs, *_) in token.items():
+            host[cap] = [np.array(dev) for dev in devs]  # graftlint: readback(the pump's one-boxcar-stale health scan, run off-loop by the deadline ticker — the same single transfer per round, telemetry/README.md contract)
+        return host
 
-    def scan_prefetched(self, token, host: Dict[int, np.ndarray]) -> None:
+    def scan_prefetched(
+        self, token, host: Dict[int, List[np.ndarray]]
+    ) -> None:
         """Install an off-thread :meth:`scan_transfer` result: the next
         scan consume uses it instead of blocking, IF the token is still
         the in-flight one (a quiescence flush racing the ticker may have
@@ -1484,7 +1497,10 @@ class DeviceFleetBackend:
         legal readback (one boxcar stale). Also closes the traced
         ``scan_consume`` spans and folds the dispatch→readback wall into
         ``pump_busy_s`` (the device-idle-fraction instrument)."""
-        if self._scan_token is None:
+        # Read once: a caller on another thread (a test's read, the
+        # ticker) may consume the same token while this one waits.
+        token = self._scan_token
+        if token is None:
             return
         for t in self._trace_inflight:
             tracing.stamp(t, tracing.STAGE_SCAN_CONSUME, "start")
@@ -1494,12 +1510,13 @@ class DeviceFleetBackend:
             if self._scan_prefetch is not None:
                 tok, pre = self._scan_prefetch
                 self._scan_prefetch = None
-                if tok is self._scan_token:
+                if tok is token:
                     # The ticker already ran this token's blocking
                     # transfer off-loop; only the slot-generation
                     # masking runs here.
                     host = pre
-            scans = self.fleet.finish_scan(self._scan_token, host=host)
+            scans = self.fleet.finish_scan(token, host=host)
+            self.flush_totals["scan_slots"] += self.fleet.scan_size(token)
             self._scan_token = None
         now = consumed.t1
         if self._scan_dispatch_t is not None:
@@ -1524,28 +1541,34 @@ class DeviceFleetBackend:
         self._consume_scan(scans, newly)
 
     def _consume_scan(
-        self, scans: Dict[int, np.ndarray],
+        self, scans: Dict[int, tuple],
         newly_errored: List[ChannelKey],
     ) -> None:
-        """Run the health consequences of one (count, err) pool scan:
-        tier promotion, sharded-overflow promotion, and sticky-err
-        collection."""
+        """Run the health consequences of one (count, err) scan — cap ->
+        the scanned slots with their counts and errs: tier promotion,
+        demotion, sharded-overflow promotion, and sticky-err collection.
+        Every pass walks the scanned slots, the boxcar's and on the
+        compaction cadence the dirty set's; none walks a pool."""
         if self._trace_inflight:
             # The scan covering the traced boxcars has been read back:
             # their device_commit span closes here.
             for t in self._trace_inflight:
                 tracing.stamp(t, tracing.STAGE_DEVICE_COMMIT, "end")
             self._trace_inflight = []
-        counts = {cap: s[0] for cap, s in scans.items()}
-        errs = {cap: s[1] for cap, s in scans.items()}
-        self.fleet.check_and_migrate(counts)
+        # Whose err lane the scan saw set, before a move below changes
+        # which document a scanned slot holds.
+        errored: List[int] = []
+        for cap, (slots, _counts, errs) in scans.items():
+            bad = np.sort(slots[errs != 0])
+            errored.extend(self.fleet.pools[cap].doc_of_slot[bad].tolist())
+        self.fleet.check_and_migrate(scans)
         # Demotion (r19) rides the SAME one-boxcar-stale scan counts the
         # promotion walk consumes — a cooling doc steps down tiers with
         # zero additional readbacks.
-        self.fleet.check_and_demote(counts)
+        self.fleet.check_and_demote(scans)
         if self.sharded_overflow:
             self._promote_overflow()
-        newly_errored.extend(self._collect_errors(errs))
+        newly_errored.extend(self._collect_errors(errored))
 
     def collect_now(self) -> List[ChannelKey]:
         """Barrier the in-flight health scan (the explicit flush_device
@@ -1585,25 +1608,15 @@ class DeviceFleetBackend:
             doc.load_single(state)
             self._sharded[idx] = doc
 
-    def _collect_errors(
-        self, errs: Optional[Dict[int, np.ndarray]] = None
-    ) -> List[ChannelKey]:
+    def _collect_errors(self, errored: List[int]) -> List[ChannelKey]:
+        """The channels to report of ``errored``, the fleet documents a
+        consumed scan saw with their sticky err lane set, and of the
+        sharded documents: each exactly once."""
         out: List[ChannelKey] = []
-        for cap, pool in self.fleet.pools.items():
-            err = errs.get(cap) if errs is not None else None
-            if err is None:
-                # graftlint: onloop(quiescence fallback only: the pump path always supplies the async scan's errs — this sync pull runs when a pool is missing from it, i.e. the explicit collect_now barrier after ingest went quiet)
-                err = np.asarray(pool.state.err)  # graftlint: readback(synchronous fallback when no async scan was supplied — collect_now contract)
-            if len(err) < pool.n_slots:
-                err = np.concatenate(
-                    [err, np.zeros(pool.n_slots - len(err), np.int32)]
-                )
-            live = pool.live_slots()
-            for slot in live[err[live] != 0]:
-                idx = int(pool.doc_of_slot[slot])
-                if idx not in self._errored:
-                    self._errored.add(idx)
-                    out.append(self._keys[idx])
+        for idx in errored:
+            if idx >= 0 and idx not in self._errored:
+                self._errored.add(idx)
+                out.append(self._keys[idx])
         for idx, doc in self._sharded.items():
             if doc.err != 0 and idx not in self._errored:
                 self._errored.add(idx)
@@ -1980,6 +1993,8 @@ class DeviceFleetBackend:
             ring_staged=len(self._ring),
             pump_dispatches=self.pump_dispatches,
             pump_backpressure=self.pump_backpressure,
+            compact_slots=self.flush_totals["compact_slots"],
+            scan_slots=self.flush_totals["scan_slots"],
             feed_size_triggers=self.feed_triggers["size"],
             feed_deadline_triggers=self.feed_triggers["deadline"],
             reads_served=self.reads_served,

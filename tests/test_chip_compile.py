@@ -14,6 +14,7 @@ import, in a ``skipif`` or in ``parametrize``.
 
 import os
 import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -170,16 +171,35 @@ def test_fused_sparse_step_compiles_at_base_tier(
         )
 
 
-@pytest.mark.parametrize("cap", [128, 256])
+# The dirty-set compaction (gather the slots written since the last one,
+# compact on [D, cap], scatter back into the donated pool) at the pool's
+# bucket: BASELINE config 5's fleet and a promoted tier of it.
+@pytest.mark.parametrize("n_slots,cap", [(131072, 128), (1024, 256)])
 def test_pallas_compact_compiles_at_its_tiers(
-    one_chip, no_persistent_cache, as_on_tpu, cap
+    one_chip, no_persistent_cache, as_on_tpu, n_slots, cap
 ):
     assert cap <= fleet._PALLAS_COMPACT_MAX_CAP
+    d = fleet._Pool.compact_bucket.fget(
+        types.SimpleNamespace(n_slots=n_slots, capacity=cap)
+    )
+    assert d == (1 << 17) // cap
     entry = fleet._compact_entry.__wrapped__(cap, "pallas", None)
-    compiled = entry.lower(_state(1024, cap, one_chip)).compile()
-    _assert_mosaic(compiled)
-    assert compiled.as_text().startswith("HloModule jit_fluid_compact")
-    assert "%compact_packed" in compiled.as_text()
+    compiled = entry.lower(
+        _state(n_slots, cap, one_chip), _i32((d,), one_chip)
+    ).compile()
+    text = _assert_mosaic(compiled)
+    assert text.startswith("HloModule jit_fluid_compact")
+    assert "%compact_packed" in text
+    for scope in ("gather", "compact", "scatter", "scan"):
+        assert f"jit(fluid_compact)/{scope}/" in text, scope
+    # The kernel runs over the bucket's D documents and nothing of the
+    # pool's size is made besides the donated state itself.
+    assert f"s32[{N_LANES},{d},{cap}]" in text
+    assert f"s32[{N_LANES},{n_slots},{cap}]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= N_LANES * n_slots * cap * 4
+    if n_slots > d:
+        assert mem.temp_size_in_bytes < n_slots * cap * 4
 
 
 def test_fused_apply_compact_compiles_at_headline_shape(
@@ -197,10 +217,14 @@ def test_fused_apply_compact_compiles_at_headline_shape(
 
 
 def test_xla_compact_compiles_at_a_big_tier(one_chip, no_persistent_cache):
-    """Past _PALLAS_COMPACT_MAX_CAP the fleet compacts with XLA's."""
-    cap = 16384
-    assert fleet._compact_entry(cap, "pallas", None) is fleet._jit_compact
-    compiled = fleet._jit_compact.lower(_state(64, cap, one_chip)).compile()
+    """Past _PALLAS_COMPACT_MAX_CAP the fleet compacts with XLA's, eight
+    documents a pass at the top tiers."""
+    cap, n_slots = 16384, 64
+    entry = fleet._compact_entry.__wrapped__(cap, "pallas", None)
+    compiled = entry.lower(
+        _state(n_slots, cap, one_chip), _i32((8,), one_chip)
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
@@ -231,3 +255,11 @@ def test_mesh_step_has_kernel_and_no_collective(
     )
     found = [c for c in _COLLECTIVES if c in text]
     assert not found, f"collectives in the fused mesh step: {found}"
+    # The dirty-set compaction, per shard as the step: the slot vector
+    # replicated, no collective.
+    compact = fleet._compact_entry.__wrapped__(128, "pallas", sharding)
+    text = _assert_mosaic(
+        compact.lower(state, _i32((1024,), rep)).compile()
+    )
+    found = [c for c in _COLLECTIVES if c in text]
+    assert not found, f"collectives in the mesh compaction: {found}"

@@ -291,3 +291,142 @@ def test_pump_promotion_reroutes_staged_rows():
     assert pump.ops_applied == oneshot.ops_applied == n_ch * k * rounds
     _assert_state_parity(pump, oneshot)
     assert len(pump.text("d0", "s")) == k * rounds
+
+
+# -- the step's own scan and the dirty-set compaction on the pump (S9) ---------
+
+
+def _spy_scans(be, monkeypatch):
+    """Every scan ``_consume_scan`` is handed, in order: cap -> the
+    scanned slots, counts and errs as lists."""
+    seen = []
+    consume = be._consume_scan
+
+    def spy(scans, newly):
+        seen.append(
+            {c: tuple(x.tolist() for x in s) for c, s in scans.items()}
+        )
+        consume(scans, newly)
+
+    monkeypatch.setattr(be, "_consume_scan", spy)
+    return seen
+
+
+def test_capacity_error_reaches_the_nack_path_one_boxcar_stale(monkeypatch):
+    """A channel overflows the only tier in its ninth boxcar: that
+    boxcar's dispatch reports nothing, the consume at the next dispatch
+    is handed the boxcar's one slot with the err bit on, and the channel
+    is reported once."""
+    from fluidframework_tpu.protocol.constants import ERR_CAPACITY
+
+    be = DeviceFleetBackend(capacity=16, max_capacity=16, pump_mode=True)
+    for i in range(64):
+        be.ensure(f"d{i}", "s")  # a pool far larger than a boxcar
+    seen = _spy_scans(be, monkeypatch)
+    reported = []
+    for r in range(10):  # two rows a boxcar: the tier's chunk limit
+        rows, texts = _round_frames(2, 2, r)
+        be.enqueue_frame("d9", SeqFrame("s", 0, 1, rows[0], texts, 0.0))
+        if r < 2:  # d40 stays healthy and is in the first two boxcars
+            be.enqueue_frame("d40", SeqFrame("s", 0, 1, rows[1], texts, 0.0))
+        be.pump_stage()
+        reported.append(be.pump_dispatch())
+    reported.append(be.pump_drain())
+    # Boxcar r's scan is consumed at boxcar r+1's dispatch.
+    assert reported == [[]] * 9 + [[("d9", "s")], []]
+    assert seen[0] == {16: ([9, 40], [2, 2], [0, 0])}
+    assert seen[1] == {16: ([9, 40], [4, 4], [0, 0])}
+    assert seen[2] == {16: ([9], [6], [0])}
+    # The eighth boxcar's cadence compacted slots 9 and 40: the pass's
+    # scan rides the ninth boxcar's token, the newer reading of 9 stands.
+    assert seen[8] == {16: ([40, 9], [4, 16], [0, ERR_CAPACITY])}
+    assert seen[9] == {16: ([9], [16], [ERR_CAPACITY])}
+    assert be.take_errors() == [("d9", "s")]
+    assert be.stats()["docs_with_errors"] == 1
+    bucket = be.fleet.pools[16].compact_bucket
+    assert be.flush_totals["compact_slots"] == bucket == 64
+    assert be.flush_totals["scan_slots"] == 2 + 2 + 8 * 1 + bucket
+
+
+def test_hot_document_promotes_off_the_boxcars_own_scan(monkeypatch):
+    """The promotion fires on the count the step returned for the
+    boxcar's slot, at the next dispatch, and nothing else is walked: the
+    other 63 documents of the pool are in no scan."""
+    be = DeviceFleetBackend(capacity=16, max_capacity=64, pump_mode=True)
+    for i in range(64):
+        be.ensure(f"d{i}", "s")
+    seen = _spy_scans(be, monkeypatch)
+    idx = be._index[("d5", "s")]
+    tiers = []
+    for r in range(8):
+        rows, texts = _round_frames(1, 2, r)
+        # Chunk limit: two rows a boxcar at this tier (half the headroom).
+        be.enqueue_frame("d5", SeqFrame("s", 0, 1, rows[0], texts, 0.0))
+        be.pump_stage()
+        be.pump_dispatch()
+        tiers.append(be.fleet.placement[idx][0])
+    be.pump_drain()
+    # 14 rows > 0.75 * 16 after boxcar 7 (r=6); consumed at boxcar 8's dispatch.
+    assert tiers == [16] * 7 + [32]
+    assert [list(s) for s in seen[:7]] == [[16]] * 7
+    assert seen[6][16] == ([5], [14], [0])
+    assert all(s[16][0] == [5] for s in seen[:7])
+    assert be.fleet.migrations == 1 and be.stats()["docs_with_errors"] == 0
+    assert len(be.text("d5", "s")) == 16
+
+
+def test_pump_and_oneshot_agree_through_compaction_and_demotion():
+    """Three compaction cadences with removes below the window, a
+    promotion and the demotion that follows it: the pump's dirty-set
+    compaction through the AOT entries and the one-shot flush's through
+    the jitted ones leave bit-identical pools, and the same counters."""
+    from fluidframework_tpu.ops import encode as E
+    from fluidframework_tpu.protocol.constants import F_MSN
+
+    def drive(be, continuous):
+        for i in range(32):
+            be.ensure(f"d{i}", "s")
+        seq = 0
+        for r in range(26):
+            rows = np.zeros((2, OP_WIDTH), np.int32)
+            if r < 9:  # 18 one-character inserts: past the 16-row tier
+                for j in range(2):
+                    seq += 1
+                    rows[j] = E.insert(0, seq, 1, seq=seq, ref=seq - 1,
+                                       client=1)
+            else:  # then the text shrinks to two characters
+                n = 18 - (r - 9)
+                seq += 1
+                rows[0] = E.remove(n - 1, n, seq=seq, ref=seq - 1,
+                                   client=1) if n > 2 else 0
+                if n <= 2:
+                    rows[0] = E.insert(0, seq, 1, seq=seq, ref=seq - 1,
+                                       client=1)
+                rows[0, F_MSN] = seq
+                rows = rows[:1]
+            texts = tuple("x" for _ in range(len(rows)))
+            be.enqueue_frame("d3", SeqFrame("s", 0, 1, rows, texts, 0.0))
+            if continuous:
+                be.pump_stage()
+                be.pump_dispatch()
+            else:
+                be.flush()
+        if continuous:
+            be.pump_drain()
+        else:
+            be.flush()
+            be.collect_now()
+
+    pump = DeviceFleetBackend(capacity=16, max_capacity=64, pump_mode=True)
+    oneshot = DeviceFleetBackend(capacity=16, max_capacity=64,
+                                 pump_mode=False)
+    drive(pump, True)
+    drive(oneshot, False)
+    for be in (pump, oneshot):
+        assert be.fleet.migrations == 1 and be.fleet.demotions == 1
+        assert be.stats()["docs_with_errors"] == 0
+    for name in ("compact_slots", "scan_slots", "step_docs"):
+        assert pump.flush_totals[name] == oneshot.flush_totals[name], name
+    assert pump.flush_totals["compact_slots"] >= 3 * 8
+    _assert_state_parity(pump, oneshot)
+    assert pump.text("d3", "s") == oneshot.text("d3", "s")

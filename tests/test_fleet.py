@@ -182,18 +182,19 @@ def test_stale_scan_dropped_for_reassigned_slots():
     ops = np.zeros((1, 8, OP_WIDTH), np.int32)
     for i in range(7):
         ops[0, i] = E.insert(0, i + 1, 1, seq=i + 1, ref=i, client=0)
-    fleet.apply(ops)
-    token = fleet.begin_scan()  # snapshot: slot 0 hot, gen G
+    fleet.apply_sparse([0], ops)
+    token = fleet.begin_scan()  # the step's scan: slot 0 hot, gen G
+    assert [d.shape for d in token[8][0]] == [(2, 1)]
     # Occupant changes: doc 0 promotes out, doc 1 lands in its slot.
     fleet.check_and_migrate()
     assert fleet.placement[0][0] == 16
     d1 = fleet.add_doc()
     assert fleet.placement[d1] == (8, 0)  # reused the vacated slot
     scans = fleet.finish_scan(token)
-    # The stale column (old occupant's count 7) is zeroed.
-    assert scans[8][0][0] == 0
+    # The stale column (old occupant's count 7) is left out.
+    assert [x.tolist() for x in scans[8]] == [[], [], []]
     # Consuming the stale scan must not re-promote the NEW occupant.
-    promoted = fleet.check_and_migrate({c: s[0] for c, s in scans.items()})
+    promoted = fleet.check_and_migrate(scans)
     assert d1 not in promoted
 
 
@@ -285,10 +286,17 @@ def test_busy_set_step_equals_dense_engine(kernel, n_slots, b, k):
     dense = np.zeros((n_slots, k, OP_WIDTH), np.int32)
     dense[busy] = rows_b[at]
     want = _host(pool._step(jax.device_put(seeded), jnp.asarray(dense)))
-    got = F._fused_sparse_step(kernel, None)(
+    got, scan = F._fused_sparse_step(kernel, None)(
         jax.device_put(seeded), jnp.asarray(rows_b), jnp.asarray(slots)
     )
     _assert_states_equal(got, want)
+    # The step's own [2, B] health scan: the busy rows' (count, err) as
+    # the pool now holds them, 0 in the columns the scatter dropped.
+    scan = np.asarray(scan)
+    assert scan.shape == (2, bucket)
+    assert np.array_equal(scan[0, at], want.count[busy])
+    assert np.array_equal(scan[1, at], want.err[busy])
+    assert not scan[:, slots == n_slots].any()
     untouched = np.setdiff1d(np.arange(n_slots), busy)
     _assert_states_equal(
         [np.asarray(x)[untouched] for x in got],

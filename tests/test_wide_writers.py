@@ -103,7 +103,7 @@ def test_upper_lane_writers_remove_overlapping_ranges(kernel, writers):
     got = _host(F._fused_sparse_step(kernel, None)(
         jax.device_put(seeded), jnp.asarray(rows_b),
         jnp.asarray([busy], np.int32),
-    ))
+    )[0])
     oracle = OracleDoc(NO_CLIENT)
     for row in list(_seed_rows()[busy]) + list(rows_b[0][:-1]):
         oracle.apply(row)
