@@ -586,6 +586,13 @@ class MultiNodeFluidService:
         for c in self.rooms.get(doc_id, []):
             c.signals.append(sig)
 
+    def pump(self) -> None:
+        """Bring every connection's inbox up to the shared op log. The
+        socket server pumps once a drain tick, ahead of a delivery sweep
+        that opens only the connections that hold something."""
+        for doc_id in self.rooms:
+            self._deliver(doc_id)
+
     def get_deltas(self, doc_id: str, from_seq: int = 0, to_seq=None):
         self._check_retained(doc_id, from_seq)
         return [

@@ -123,6 +123,11 @@ def _signal_text_frame(sig) -> bytes:
     })
 
 
+def _nack_text_frame(nk) -> bytes:
+    """A nack on the wire. It goes to one connection: nothing to share."""
+    return _text_frame({"type": "nack", "nack": to_jsonable(nk)})
+
+
 def _seq_frame_binary(frame) -> bytes:
     """A SeqFrame on the frame wire: n sequenced ops in ONE binary
     websocket frame."""
@@ -408,6 +413,12 @@ class FluidNetworkServer:
         # (each queued item's bytes are built once a sweep, not once a
         # socket).
         self.delivery_encodes = 0
+        # What the sweep's cost follows: writes that reached an op socket
+        # (one a session a sweep, whatever the messages it carries), and
+        # sessions a sweep passed over because their connection held
+        # nothing (one test each).
+        self.socket_writes = 0
+        self.sessions_passed = 0
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
         self._started = threading.Event()
@@ -1332,33 +1343,11 @@ class FluidNetworkServer:
 
     @inject_fault("ws.deliver")
     def _deliver(self, session: _Session, data: bytes) -> None:
-        """One op-stream delivery write — the ``ws.deliver`` injection
-        boundary (control-plane replies go through :meth:`_send` and are
-        not injected: their recovery is the client's reconnect)."""
+        """One op-stream delivery write, everything a sweep found queued
+        on the session: the ``ws.deliver`` injection boundary
+        (control-plane replies go through :meth:`_send` and are not
+        injected: their recovery is the client's reconnect)."""
         session.writer.write(data)
-
-    def _requeue(self, target: list, rest: list) -> None:
-        """Delivery-failure recovery: the unsent tail goes back to the
-        HEAD of its queue so order is preserved and the next drain tick
-        retries — watermarks only advance with a successful write, so the
-        client sees each message exactly once. A crash AFTER the final
-        write of a batch leaves nothing to requeue (the tail is empty):
-        that surfaces as ``fatal``, not a phantom requeue."""
-        if rest:
-            target[:0] = rest
-            retry.retry_counter().inc(site="ws.deliver", outcome="requeue")
-        else:
-            retry.retry_counter().inc(site="ws.deliver", outcome="fatal")
-
-    @staticmethod
-    def _unsent_tail(msgs: list, j: int, exc: BaseException) -> list:
-        """Which messages still need delivery after a failed write of
-        ``msgs[j]``: a crash AFTER the write (the ack-lost window) means
-        ``msgs[j]`` reached the socket — requeueing it would deliver it
-        twice; every other failure means it never left."""
-        if isinstance(exc, faults.InjectedCrash) and exc.completed:
-            return msgs[j + 1:]
-        return msgs[j:]
 
     # -- the encode-once push fan-out (r15) ----------------------------------
 
@@ -1773,70 +1762,80 @@ class FluidNetworkServer:
         # encodes ONCE per wire format (_PushEncodeCache, one per group
         # per sweep), and the same bytes write to every subscriber past
         # its watermark; per-subscriber state is a watermark + a requeue
-        # tail. Connected writers: the broadcasters queued the SAME
-        # object on every connection of a room, so one
-        # _SweepEncodeCache, alive for this call only, builds each
-        # queued item's bytes at most once per wire format and every
-        # session that holds the item is written those bytes. Each write
-        # is still one _deliver of one message, and the encode happens
-        # inside its try: the r11 exactly-once crash-after semantics
-        # per socket are unchanged (a failed k-th socket requeues its own
-        # tail; the bytes the others share are not touched).
+        # tail. Connected writers: the sweep's cost follows what is
+        # queued. The pump above has run, so every connection's three
+        # queues are whole: a session whose inbox, signals and nacks are
+        # all empty costs that one test. A session that holds something
+        # gets ONE write of everything it holds (_write_queued); the
+        # broadcasters queued the SAME object on every connection of a
+        # room, so one _SweepEncodeCache, alive for this call only,
+        # builds each queued item's bytes at most once per wire format
+        # and every session that holds the item is written those bytes.
         with profiler.span("socket_out"):
             self._push_sweep()
             cache = _SweepEncodeCache()
+            held = unbound = 0
             for s in self._sessions:
-                if s.conn is None:
-                    continue
-                nopump = getattr(s.conn, "supports_nopump", False)
-                take_raw = getattr(s.conn, "take_inbox_raw", None)
-                if take_raw is not None:
-                    msgs = take_raw(pump=False) if nopump else take_raw()
-                    if not s.frames_ok:
-                        # JSON wire: a frame goes out as its per-op texts.
-                        msgs = cache.expanded(msgs)
-                else:
-                    msgs = (
-                        s.conn.take_inbox(pump=False)
-                        if nopump else s.conn.take_inbox()
-                    )
-                for j, m in enumerate(msgs):
-                    try:
-                        if hasattr(m, "sequence_number"):
-                            self._deliver(s, cache.encoded(m, _op_text_frame))
-                            self.ops_delivered += 1
-                        else:
-                            self._deliver(
-                                s, cache.encoded(m, _seq_frame_binary)
-                            )
-                            self.frames_delivered += 1
-                    except Exception as e:
-                        self._requeue(
-                            s.conn.inbox, self._unsent_tail(msgs, j, e)
-                        )
-                        break
-                sigs, s.conn.signals[:] = list(s.conn.signals), []
-                for j, sig in enumerate(sigs):
-                    try:
-                        self._deliver(
-                            s, cache.encoded(sig, _signal_text_frame)
-                        )
-                        self.signals_delivered += 1
-                    except Exception as e:
-                        self._requeue(
-                            s.conn.signals, self._unsent_tail(sigs, j, e)
-                        )
-                        break
-                nacks, s.conn.nacks[:] = list(s.conn.nacks), []
-                for j, nk in enumerate(nacks):
-                    try:
-                        # A nack goes to one connection: nothing to share.
-                        self._deliver(s, _text_frame(
-                            {"type": "nack", "nack": to_jsonable(nk)}
-                        ))
-                    except Exception as e:
-                        self._requeue(
-                            s.conn.nacks, self._unsent_tail(nacks, j, e)
-                        )
-                        break
+                conn = s.conn
+                if conn is None:
+                    unbound += 1
+                elif conn.inbox or conn.signals or conn.nacks:
+                    held += 1
+                    self._write_queued(s, cache)
+            self.sessions_passed += len(self._sessions) - unbound - held
             self.delivery_encodes += cache.encodes
+
+    def _write_queued(self, s: _Session, cache: _SweepEncodeCache) -> None:
+        """One session's turn in the delivery sweep: everything its
+        connection has queued (sequenced ops and frames in inbox order,
+        then signals, then nacks) leaves as ONE ``_deliver`` of the
+        joined bytes, one ``send``; websocket frames delimit themselves,
+        so the client reads what a write a message gave it. The write is
+        the unit of the r11 exactly-once contract: a failure before it
+        reached the socket (an encode that raises included: it stays
+        inside the ``try``) left nothing of this sweep on this socket,
+        and every item goes back to the HEAD of its own queue, in order,
+        for the next sweep; a crash AFTER it means all of it reached the
+        socket, so nothing goes back and the counts advance. Either way
+        one ``retry_attempts_total{ws.deliver}`` count a faulted write.
+        A failed socket requeues its own items alone; the bytes the
+        others share are not touched."""
+        conn = s.conn
+        nopump = getattr(conn, "supports_nopump", False)
+        take_raw = getattr(conn, "take_inbox_raw", None)
+        if take_raw is not None:
+            msgs = take_raw(pump=False) if nopump else take_raw()
+            if not s.frames_ok:
+                # JSON wire: a frame goes out as its per-op texts.
+                msgs = cache.expanded(msgs)
+        else:
+            msgs = conn.take_inbox(pump=False) if nopump else conn.take_inbox()
+        sigs, conn.signals[:] = list(conn.signals), []
+        nacks, conn.nacks[:] = list(conn.nacks), []
+        frames = 0
+        try:
+            parts = []
+            for m in msgs:
+                if hasattr(m, "sequence_number"):
+                    parts.append(cache.encoded(m, _op_text_frame))
+                else:
+                    parts.append(cache.encoded(m, _seq_frame_binary))
+                    frames += 1
+            for sig in sigs:
+                parts.append(cache.encoded(sig, _signal_text_frame))
+            for nk in nacks:
+                parts.append(_nack_text_frame(nk))
+            self._deliver(s, b"".join(parts))
+        except Exception as e:
+            if not (isinstance(e, faults.InjectedCrash) and e.completed):
+                conn.inbox[:0] = msgs
+                conn.signals[:0] = sigs
+                conn.nacks[:0] = nacks
+                retry.retry_counter().inc(site="ws.deliver", outcome="requeue")
+                return
+            # The ack-lost window: the crash is counted, never silent.
+            retry.retry_counter().inc(site="ws.deliver", outcome="fatal")
+        self.socket_writes += 1
+        self.frames_delivered += frames
+        self.ops_delivered += len(msgs) - frames
+        self.signals_delivered += len(sigs)

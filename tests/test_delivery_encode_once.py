@@ -7,11 +7,15 @@ built at most once per wire format and every socket that has it queued is
 written the same bytes. Contracts under test:
 
 - what each socket receives is byte for byte the per-socket encoding (the
-  parent's expressions, written out here as the reference);
+  parent's expressions, written out here as the reference), laid end to
+  end in ONE write a socket a sweep (PR 45: a sweep's unit of delivery is
+  everything it found queued on the connection);
 - ``delivery_encodes`` is flat over 1 / 10 / 120 connections of a room
   while the deliveries grow with them;
-- a ``ws.deliver`` fault on the k-th socket requeues that connection's
-  tail and nobody else's, and every message still arrives exactly once;
+- a ``ws.deliver`` fault on the k-th socket puts every item of that
+  connection's write back in its own queue and nobody else's (a crash
+  after the write: none of them), and every message still arrives
+  exactly once;
 - two documents whose signals carry the same ``(client_id, num)`` get
   their own bytes; a room mixing wires builds each format once; a
   rejoined connection gets the expanded suffix of a frame only.
@@ -31,6 +35,7 @@ from fluidframework_tpu.service.network_server import (
     _Session,
 )
 from fluidframework_tpu.service.pipeline import PipelineFluidService
+from fluidframework_tpu.telemetry import metrics
 from fluidframework_tpu.testing import faults
 
 MINT = 1 << 14  # shared_string._MINT_STRIDE (content-id scoping)
@@ -143,6 +148,11 @@ def _reference_chunks(s: _Session) -> list:
     return out
 
 
+def _one_write(s: _Session, chunks: list) -> bool:
+    """The socket got ONE write: the reference encodings end to end."""
+    return s.writer.chunks == [b"".join(chunks)]
+
+
 def _decoded(writer: _Writer) -> list:
     """A socket's stream as comparable items: ("op", seq), ("signal",
     client, num) — a binary frame counts as its ops."""
@@ -180,14 +190,14 @@ def test_every_socket_gets_the_per_socket_encoding(n, kind):
     _offer(kind, sender, server.service, csn)  # two items: order shows
     owed = [_reference_chunks(s) for s in sessions]
     assert all(len(chunks) == 2 for chunks in owed)
-    before = server.delivery_encodes
+    before, writes = server.delivery_encodes, server.socket_writes
     server._drain_all()
     for s, chunks in zip(sessions, owed):
-        assert s.writer.chunks == chunks  # one write a message, these bytes
+        assert _one_write(s, chunks)  # one write a socket, these bytes
         assert not s.conn.inbox and not s.conn.signals
-    # Two items, each built once and the same object on every socket.
-    assert len({id(c) for s in sessions for c in s.writer.chunks}) == 2
+    # Two items, each built once whatever the sockets; one write a socket.
     assert server.delivery_encodes == before + 2
+    assert server.socket_writes == writes + n
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -201,7 +211,7 @@ def test_json_wire_sessions_get_a_frame_as_its_ops(kind):
     owed = [_reference_chunks(s) for s in sessions]
     server._drain_all()
     for s, chunks in zip(sessions, owed):
-        assert len(chunks) >= 4 and s.writer.chunks == chunks
+        assert len(chunks) >= 4 and _one_write(s, chunks)
 
 
 # -- (b) the counter ---------------------------------------------------------
@@ -243,6 +253,17 @@ def test_an_idle_sweep_encodes_nothing():
 # -- (c) a fault on the k-th socket ------------------------------------------
 
 
+def _ws_deliver_outcomes() -> dict:
+    """``retry_attempts_total{site="ws.deliver"}`` by outcome."""
+    c = metrics.REGISTRY.get("retry_attempts_total")
+    out: dict = {}
+    for key, _suffix, value in c.samples() if c is not None else ():
+        d = dict(key)
+        if d.get("site") == "ws.deliver":
+            out[d["outcome"]] = out.get(d["outcome"], 0) + value
+    return out
+
+
 class _OnNth(faults.FaultPolicy):
     """Pass every invocation of the site but the ``nth`` (from 1)."""
 
@@ -255,16 +276,19 @@ class _OnNth(faults.FaultPolicy):
 
 
 @pytest.mark.parametrize(
-    "action,landed", [(("fail",), 1), (("crash", "after"), 2)]
+    "action,landed", [(("fail",), 0), (("crash", "after"), 4)]
 )
 @pytest.mark.parametrize("k", [1, 5, 8])
-def test_a_fault_on_the_kth_socket_requeues_its_tail_alone(k, action, landed):
-    """Three ops and a signal queued on a room of 8; the write of the
-    k-th connection's SECOND op faults. ``fail``: ops 2 and 3 go back
-    to that inbox (one landed); crash-after: op 2 reached the socket, op 3
-    goes back (two landed). Everybody else is written once, in this sweep,
-    and the next sweep completes the k-th: every item exactly once, the
-    bytes on all eight sockets those of the reference."""
+def test_a_fault_on_the_kth_sockets_write_requeues_its_whole_batch_alone(
+    k, action, landed
+):
+    """Three ops and a signal queued on a room of 8; the ONE write of the
+    k-th connection faults. ``fail``: nothing landed, the three ops go
+    back to that inbox and the signal to its signals, in order;
+    crash-after: all four reached the socket, none goes back. Everybody
+    else is written once, in this sweep, and the next sweep completes the
+    k-th: every item exactly once, the bytes on all eight sockets those
+    of the reference."""
     server = _server()
     sessions = _room(server, "doc", 8, frames_ok=False)
     svc, sender = server.service, sessions[2].conn
@@ -273,29 +297,39 @@ def test_a_fault_on_the_kth_socket_requeues_its_tail_alone(k, action, landed):
     _offer("signal", sender, svc)
     owed = [_reference_chunks(s) for s in sessions]
     assert all(len(chunks) == 4 for chunks in owed)
-    # Each earlier connection is 4 writes (3 ops + the signal).
-    faults.arm("ws.deliver", _OnNth(4 * (k - 1) + 2, action))
+    queued = [(list(s.conn.inbox), list(s.conn.signals)) for s in sessions]
+    # Each earlier connection is one write (3 ops + the signal).
+    faults.arm("ws.deliver", _OnNth(k, action))
+    writes, counted = server.socket_writes, _ws_deliver_outcomes()
     server._drain_all()
     assert faults.REGISTRY.injected_total("ws.deliver") == 1
+    # One count a faulted write, whatever it carried (two queues here).
+    outcome = "fatal" if landed else "requeue"
+    now = _ws_deliver_outcomes()
+    assert now.get(outcome, 0) == counted.get(outcome, 0) + 1
+    assert sum(now.values()) == sum(counted.values()) + 1
+    assert server.socket_writes == writes + (8 if landed else 7)
     hit = sessions[k - 1]
-    for s, chunks in zip(sessions, owed):
-        if s is hit:
-            # What landed of the ops, then the signal (its own queue).
-            assert s.writer.chunks == chunks[:landed] + chunks[3:]
-            assert len(s.conn.inbox) == 3 - landed
+    for s, chunks, (ops, sigs) in zip(sessions, owed, queued):
+        if s is hit and not landed:
+            # Nothing of this sweep on this socket; every item back in
+            # its own queue, in order.
+            assert s.writer.chunks == []
+            assert s.conn.inbox == ops and s.conn.signals == sigs
         else:
-            assert s.writer.chunks == chunks and not s.conn.inbox
-        assert not s.conn.signals
+            assert _one_write(s, chunks)
+            assert not s.conn.inbox and not s.conn.signals
     faults.disarm()
     server._drain_all()
     want = _decoded(sessions[3].writer)  # never the k-th
     assert len(want) == 4 and len(set(want)) == 4
     for s, chunks in zip(sessions, owed):
-        assert sorted(s.writer.chunks) == sorted(chunks)  # nothing twice
+        assert _one_write(s, chunks)  # nothing twice
         assert [x for x in _decoded(s.writer) if x[0] == "op"] == want[:3]
-        assert not s.conn.inbox
+        assert not s.conn.inbox and not s.conn.signals
     server._drain_all()  # nothing left to redeliver
-    assert all(len(s.writer.chunks) == 4 for s in sessions)
+    assert all(len(s.writer.chunks) == 1 for s in sessions)
+    assert server.socket_writes == writes + 8
 
 
 def test_an_item_that_cannot_be_encoded_is_requeued(monkeypatch):
@@ -315,7 +349,7 @@ def test_an_item_that_cannot_be_encoded_is_requeued(monkeypatch):
     monkeypatch.setattr(ns_mod, "to_jsonable", real)
     owed = [_reference_chunks(s) for s in sessions]
     server._drain_all()
-    assert [s.writer.chunks for s in sessions] == owed
+    assert all(_one_write(s, chunks) for s, chunks in zip(sessions, owed))
 
 
 # -- (d) two documents, the same (client_id, num) ----------------------------
@@ -385,7 +419,7 @@ def test_a_mixed_room_builds_each_format_once(monkeypatch):
     assert calls == {"jsonable": 4, "encode": 1, "messages": 1}
     assert server.delivery_encodes == before + 5
     for s, chunks in zip(sessions, owed):
-        assert s.writer.chunks == chunks
+        assert _one_write(s, chunks)
         opcodes = [
             op for c in s.writer.chunks
             for op, _ in wsproto.FrameDecoder().feed(c)
@@ -419,7 +453,7 @@ def test_a_straddling_connection_gets_the_expanded_suffix_only(frames_ok):
     owed = [_reference_chunks(s) for s in sessions]
     server._drain_all()
     for s, chunks in zip(sessions, owed):
-        assert s.writer.chunks == chunks
+        assert _one_write(s, chunks)
     assert _decoded(late.writer) == [("op", head + 3)]
     assert _decoded(sessions[1].writer) == [
         ("op", head + 1), ("op", head + 2), ("op", head + 3)

@@ -11,6 +11,8 @@ its ``retry_attempts_total{site,outcome}`` /
 ``faults_injected_total{site,kind}`` increments.
 """
 
+import time
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -739,7 +741,6 @@ class TestResidencyChaos:
 
 class TestWsDeliveryChaos:
     def _converged(self, runtimes, text, timeout=10.0):
-        import time
 
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
@@ -754,10 +755,12 @@ class TestWsDeliveryChaos:
 
     @pytest.mark.parametrize("kind", ["fail", "crash_before", "crash_after"])
     def test_delivery_failure_exactly_once(self, kind):
-        """A failed delivery write requeues the unsent tail (watermarks
-        only advance on success), a crash-after write does NOT requeue
-        the op that reached the socket — either way every client sees
-        each op exactly once."""
+        """A sweep's write of everything queued on a socket (PR 45: two
+        flushes and a signal found by one sweep leave together) that
+        fails requeues the whole batch (watermarks only advance on
+        success), a crash-after write requeues NOTHING of what reached
+        the socket — either way every client sees each op exactly
+        once."""
         from fluidframework_tpu.drivers.network_driver import (
             NetworkFluidService,
         )
@@ -781,13 +784,32 @@ class TestWsDeliveryChaos:
             assert self._converged([a, b], "")  # settle the handshakes
             pre = _recovery_total("ws.deliver")
             faults.arm("ws.deliver", _policy(kind))
+            writes, sent = srv.socket_writes, srv.ops_delivered
+
+            # The loop stands still while two one-op flushes and a signal
+            # arrive: one read, so ONE sweep finds all three queued.
+            srv._loop.call_soon_threadsafe(time.sleep, 0.3)
             a.get_channel("text").insert_text(0, "hello")
             a.flush()
-            assert self._converged([a, b], "hello"), (
+            a.get_channel("text").insert_text(5, " world")
+            a.flush()
+            a.connection.submit_signal({"cursor": 11})
+            assert self._converged([a, b], "hello world"), (
                 faults.stats(), kind,
             )
             assert faults.REGISTRY.injected_total("ws.deliver") == 1
             assert _recovery_total("ws.deliver") > pre
+            # A requeued batch leaves with the next sweep, and a sweep
+            # follows inbound traffic: any message brings it.
+            b.connection.submit_signal({"cursor": 0})
+            deadline = time.monotonic() + 5.0
+            while srv.ops_delivered - sent < 4 and time.monotonic() < deadline:
+                time.sleep(0.02)
+            # Two ops to two sockets, each exactly once, in fewer writes
+            # than the messages they carried: the faulted write was a batch.
+            assert srv.ops_delivered - sent == 4
+            assert srv.socket_writes - writes < 4 + 2 + 2
+            assert self._converged([a, b], "hello world")
         finally:
             faults.disarm()
             srv.stop()
